@@ -70,6 +70,8 @@ def _write_json(bench: str, status: str, rows, elapsed_s: float) -> None:
 
 
 def main() -> None:
+    from repro.jaxcache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     ap.add_argument("--no-json", action="store_true",

@@ -37,6 +37,8 @@ STATES = ["CA", "TX", "NY"]
 
 
 def main() -> None:
+    from repro.jaxcache import enable_compile_cache
+    enable_compile_cache()
     print("== multi-region fleet: CA + TX + NY on one coordinator ==")
     p = synthetic_regional_fleet(9, STATES, hours=48, seed=0,
                                  utc_offsets="auto")

@@ -7,6 +7,8 @@ import sys
 
 
 def main() -> None:
+    from repro.jaxcache import enable_compile_cache
+    enable_compile_cache()
     sys.path.insert(0, ".")
     from benchmarks.common import get_problem, policy_sweeps
     sweep = policy_sweeps()

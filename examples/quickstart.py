@@ -24,6 +24,8 @@ from repro.core.scenario import DuckPerturb, RenewableDrought
 
 
 def main() -> None:
+    from repro.jaxcache import enable_compile_cache
+    enable_compile_cache()
     print("== Carbon Responder quickstart ==")
     print("building fleet (4 services, EDD-simulated batch penalty models;"
           " cached after first run)...")

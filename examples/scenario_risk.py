@@ -23,6 +23,8 @@ from repro.core.scenario import (CambiumMix, DuckPerturb, EveningRampSpike,
 
 
 def main() -> None:
+    from repro.jaxcache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scenarios", type=int, default=16,
                     help="scenarios per generator family")

@@ -13,6 +13,8 @@ from repro.models import transformer as tf
 
 
 def main() -> None:
+    from repro.jaxcache import enable_compile_cache
+    enable_compile_cache()
     cfg = reduced(get_config("qwen3-32b"), layers=2, d_model=128, vocab=2048)
     params = tf.init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)

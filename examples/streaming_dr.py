@@ -45,6 +45,8 @@ from repro.obs import TelemetryConfig
 
 
 def main() -> None:
+    from repro.jaxcache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--ticks", type=int, default=12)
     ap.add_argument("--workloads", type=int, default=16)

@@ -18,6 +18,8 @@ from repro.power.model import JobPowerModel
 
 
 def main() -> None:
+    from repro.jaxcache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-3b")
     ap.add_argument("--steps", type=int, default=200)

@@ -19,7 +19,7 @@ cd "$(dirname "$0")/.."
 
 echo "== drlint (JAX invariants as an AST pass) =="
 # Millisecond static pass, so it runs first and fails fast: host leaks in
-# jit-reachable code, donation twins, check_rep justifications, tuple
+# jit-reachable code, donation twins, check_vma justifications, tuple
 # seeding, np-on-traced, deprecated shims, ad-hoc PartitionSpecs. Exits
 # nonzero with path:line:col output on any unsuppressed violation.
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \

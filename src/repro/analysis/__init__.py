@@ -4,7 +4,7 @@ Two halves:
 
   * **drlint** (`repro.analysis.lint` / `.rules`): an AST static-
     analysis pass encoding the repo's JAX invariants — jit twins,
-    check_rep justifications, tuple seeding, host-leak bans — as a
+    check_vma justifications, tuple seeding, host-leak bans — as a
     rule registry with per-rule suppression comments. Run it with
     ``python -m repro.analysis.lint``; `scripts/ci.sh` fails on
     violations.

@@ -29,12 +29,12 @@ nested traces, the useful contract is ``max_compiles=0`` — "this
 region must be compile-free" — which is exactly the warm/one-dispatch
 claim. For diagnostics, read the counts off the yielded stats object.
 
-Implementation note: the counters wrap two private-but-stable jax
-hooks (`jax._src.pjit._create_pjit_jaxpr`, re-wrapped in `lu.cache`
-so cache semantics are preserved, and
-`jax._src.interpreters.mlir.lower_jaxpr_to_module`) — the same
-technique `jax._src.test_util`'s counting helpers use. If a jax
-upgrade moves both hooks, the guard raises at entry rather than
+Implementation note: the counters listen to jax's internal test
+events (`jax._src.util.test_event_listener`): ``trace_to_jaxpr`` fires
+on every jit trace-cache miss and ``lower_jaxpr_to_module`` on every
+lowering — the events `jax._src.test_util`'s counting helpers read. A
+listener already installed keeps receiving every event. If a jax
+upgrade removes the hook, the guard raises at entry rather than
 silently counting nothing.
 """
 from __future__ import annotations
@@ -74,36 +74,28 @@ def recompile_guard(max_compiles: int | None = 0, *, label: str = ""):
 
     Yields a `RecompileStats` whose counters update live.
     """
-    from jax._src import linear_util as lu
-    from jax._src import pjit as _pjit
-    from jax._src.interpreters import mlir as _mlir
+    from jax._src import util as _util
 
-    stats = RecompileStats()
-    orig_trace = getattr(_pjit, "_create_pjit_jaxpr", None)
-    orig_lower = getattr(_mlir, "lower_jaxpr_to_module", None)
-    if orig_trace is None and orig_lower is None:
+    if not hasattr(_util, "test_event_listener"):
         raise RecompileError(
-            "recompile_guard found neither jax hook it counts with "
+            "recompile_guard found no jax test-event hook to count with "
             "(jax internals moved?) — refusing to guard nothing")
+    stats = RecompileStats()
+    prev = _util.test_event_listener
 
-    if orig_trace is not None:
-        @lu.cache   # preserve the hook's memoization contract
-        def trace_and_count(*args, **kwargs):
+    def count(name, *args):
+        if name == "trace_to_jaxpr":
             stats.traces += 1
-            return orig_trace(*args, **kwargs)
-        _pjit._create_pjit_jaxpr = trace_and_count
-    if orig_lower is not None:
-        def lower_and_count(*args, **kwargs):
+        elif name == "lower_jaxpr_to_module":
             stats.lowerings += 1
-            return orig_lower(*args, **kwargs)
-        _mlir.lower_jaxpr_to_module = lower_and_count
+        if prev is not None:
+            prev(name, *args)
+
+    _util.test_event_listener = count
     try:
         yield stats
     finally:
-        if orig_trace is not None:
-            _pjit._create_pjit_jaxpr = orig_trace
-        if orig_lower is not None:
-            _mlir.lower_jaxpr_to_module = orig_lower
+        _util.test_event_listener = prev
     if max_compiles is not None and (stats.traces > max_compiles
                                      or stats.lowerings > max_compiles):
         where = f"{label}: " if label else ""

@@ -9,7 +9,7 @@ full list with history):
   * ``donation-twin``        — every ``jit(donate_argnums=...)`` twin
                                mirrors a non-donated sibling (api.py's
                                twin pattern).
-  * ``check-rep-justification`` — ``shard_map(..., check_rep=False)``
+  * ``check-vma-justification`` — ``shard_map(..., check_vma=False)``
                                must carry a comment naming the
                                pallas_call that requires it.
   * ``tuple-seed``           — ``default_rng((seed, ...))`` tuple
@@ -429,18 +429,18 @@ def _check_donation_twin(mod: Module) -> list[Violation]:
 
 
 # ---------------------------------------------------------------------------
-# Rule 3: check-rep-justification
+# Rule 3: check-vma-justification
 # ---------------------------------------------------------------------------
-@rule("check-rep-justification",
-      "shard_map(check_rep=False) must name its pallas_call in a comment")
-def _check_check_rep(mod: Module) -> list[Violation]:
+@rule("check-vma-justification",
+      "shard_map(check_vma=False) must name its pallas_call in a comment")
+def _check_check_vma(mod: Module) -> list[Violation]:
     out = []
     for node in ast.walk(mod.tree):
         if not isinstance(node, ast.Call) \
                 or "shard_map" not in _dotted(node.func):
             continue
         for kw in node.keywords:
-            if kw.arg == "check_rep" and isinstance(kw.value, ast.Constant) \
+            if kw.arg == "check_vma" and isinstance(kw.value, ast.Constant) \
                     and kw.value.value is False:
                 lines = range(max(1, node.lineno - 6), kw.value.lineno + 1)
                 justified = any(
@@ -448,11 +448,11 @@ def _check_check_rep(mod: Module) -> list[Violation]:
                     for ln in lines)
                 if not justified:
                     out.append(Violation(
-                        "check-rep-justification", mod.path,
+                        "check-vma-justification", mod.path,
                         kw.value.lineno, kw.value.col_offset,
-                        "check_rep=False without a nearby comment naming "
+                        "check_vma=False without a nearby comment naming "
                         "the pallas_call that requires it (pallas kernels "
-                        "have no shard_map replication rule — say which "
+                        "have no shard_map varying-axes rule — say which "
                         "one, or drop the flag)"))
     return out
 

@@ -662,6 +662,21 @@ def _al_fused_inner(p: FleetProblem, mode: str, cfg: EngineConfig, *,
         day_hours=p.day_hours)
 
 
+def _sharded_penalties(p: FleetProblem, D: Array, mesh,
+                       use_kernel: bool) -> Array:
+    """`fleet_penalties` of a plan sharded over `mesh`, row block by row
+    block: the TPU cannot partition a Pallas kernel on its own, so the
+    dr_features kernel runs inside a shard_map like the solve itself."""
+    axis = fleet_axes(mesh)
+    # check_vma=False: `fleet_penalties` may dispatch the dr_features
+    # pallas_call (use_kernel), which has no shard_map replication rule;
+    # the one output is explicitly spec'd.
+    return jax.shard_map(
+        lambda pb, d: fleet_penalties(pb, d, use_kernel), mesh=mesh,
+        in_specs=(_fleet_specs(p, axis), P(axis)), out_specs=P(axis),
+        check_vma=False)(p, D)
+
+
 # ---------------------------------------------------------------------------
 # CR1 — Efficient DR (unconstrained trade-off objective)
 # ---------------------------------------------------------------------------
@@ -750,7 +765,7 @@ def _cr1_impl_sharded(p: FleetProblem, lam, norms, state0: EngineState,
         build, (p, lam, norms), mesh=mesh, axis_name=axis,
         data_specs=(_fleet_specs(p, axis), P(), _norm_specs(p, axis)),
         init=state0, cfg=cfg)
-    out = (D, fleet_penalties(p, D, use_kernel), aux["state"])
+    out = (D, _sharded_penalties(p, D, mesh, use_kernel), aux["state"])
     return out + (aux["telemetry"],) if telemetry_every else out
 
 
@@ -786,7 +801,6 @@ def _cr1_sweep_sharded(p: FleetProblem, lams, norms, mesh, steps: int,
                        use_kernel: bool):
     """The λ grid vmapped INSIDE the W-axis shard_map: every device solves
     its row block for all grid points in one call (sharded Pareto lane)."""
-    from jax.experimental.shard_map import shard_map
     axis = fleet_axes(mesh)
 
     def body(pb, lams_b, norms_b):
@@ -806,14 +820,14 @@ def _cr1_sweep_sharded(p: FleetProblem, lams, norms, mesh, steps: int,
 
         return jax.vmap(solve_one)(lams_b)
 
-    # check_rep=False: `body` may dispatch the fused al_step pallas_call
+    # check_vma=False: `body` may dispatch the fused al_step pallas_call
     # (use_kernel), which has no shard_map replication rule; every output
     # is explicitly spec'd above.
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(_fleet_specs(p, axis), P(), _norm_specs(p, axis)),
         out_specs=(P(None, axis), P(None, axis)),
-        check_rep=False)(p, lams, norms)
+        check_vma=False)(p, lams, norms)
 
 
 @_register
@@ -999,7 +1013,7 @@ def _cr2_impl_sharded(p: FleetProblem, refs, norms, state0: EngineState,
         build, (p, refs, norms), mesh=mesh, axis_name=axis,
         data_specs=(_fleet_specs(p, axis), P(axis), _norm_specs(p, axis)),
         init=state0, cfg=cfg)
-    out = (D, fleet_penalties(p, D, use_kernel), aux["state"])
+    out = (D, _sharded_penalties(p, D, mesh, use_kernel), aux["state"])
     return out + (aux["telemetry"],) if telemetry_every else out
 
 
@@ -1035,7 +1049,6 @@ def _cr2_sweep_run(p: FleetProblem, refs_stack, init: EngineState,
                    static_argnames=("mesh", "steps", "outer", "use_kernel"))
 def _cr2_sweep_sharded(p: FleetProblem, refs_stack, norms_stack, mesh,
                        steps: int, outer: int, use_kernel: bool):
-    from jax.experimental.shard_map import shard_map
     axis = fleet_axes(mesh)
 
     def body(pb, refs_b, norms_b):
@@ -1055,15 +1068,15 @@ def _cr2_sweep_sharded(p: FleetProblem, refs_stack, norms_stack, mesh,
         return jax.vmap(solve_one)(refs_b, norms_b)
 
     nspec = P() if np.ndim(p.mci) == 1 else P(None, axis)
-    # check_rep=False: `body` may dispatch the fused al_step pallas_call
+    # check_vma=False: `body` may dispatch the fused al_step pallas_call
     # (use_kernel), which has no shard_map replication rule; every output
     # is explicitly spec'd above.
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(_fleet_specs(p, axis), P(None, axis),
                   (nspec, nspec, nspec)),
         out_specs=(P(None, axis), P(None, axis)),
-        check_rep=False)(p, refs_stack, norms_stack)
+        check_vma=False)(p, refs_stack, norms_stack)
 
 
 @_register
@@ -1234,7 +1247,10 @@ def _cr3_pieces(p: FleetProblem, use_kernel: bool, reg_scale):
 
         def ineq(D: Array, hyper) -> Array:
             rho_, tax_ = hyper
-            rebate = rho_ * (D @ mci)
+            # A row-wise reduction, not a matvec: XLA:CPU's dot rounds
+            # each row differently depending on how many rows it holds,
+            # so a sharded solve would not match the single-device one.
+            rebate = rho_ * (D * mci).sum(1)
             peak = tau * jax.nn.logsumexp((usage - D) / tau[:, None],
                                           axis=1)
             return ((1.0 - tax_) * E + rebate - peak) / E
@@ -1311,7 +1327,7 @@ def _cr3_impl_sharded(p: FleetProblem, rho, tax_frac, reg_scale,
         build, (p, (rho, tax_frac), reg_scale), mesh=mesh, axis_name=axis,
         data_specs=(_fleet_specs(p, axis), (P(), P()), reg_spec),
         init=state0, cfg=_cr3_cfg(steps, outer))
-    return D, fleet_penalties(p, D, use_kernel), aux["state"]
+    return D, _sharded_penalties(p, D, mesh, use_kernel), aux["state"]
 
 
 _CR3_STATIC_SH = ("mesh", "steps", "outer", "use_kernel", "shift",
@@ -1774,7 +1790,6 @@ def _day_cr1_impl_sharded(p: FleetProblem, lam, mci_stack, norms_stack,
     fleet-global norms ride in as a replicated (n, ...) stack computed
     host-side from the TRUE fleet (the in-scan twin of the solo path's
     per-tick `_cr1_norms`)."""
-    from jax.experimental.shard_map import shard_map
     axis = fleet_axes(mesh)
 
     def body(pb, lam_b, mci_s, norms_s, st0):
@@ -1789,15 +1804,15 @@ def _day_cr1_impl_sharded(p: FleetProblem, lam, mci_stack, norms_stack,
 
     state_specs = EngineState(x=P(axis), lam_eq=P(axis), lam_in=P(axis),
                               mu=P())
-    # check_rep=False: the day scan's tick solves may dispatch the fused
+    # check_vma=False: the day scan's tick solves may dispatch the fused
     # al_step pallas_call (use_kernel), which has no shard_map
     # replication rule; every output is explicitly spec'd above.
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(_fleet_specs(p, axis), P(), P(),
                   _norm_specs(p, axis, stacked=True), state_specs),
         out_specs=(P(None, axis), P(axis), P(axis), state_specs),
-        check_rep=False)(p, lam, mci_stack, norms_stack, state0)
+        check_vma=False)(p, lam, mci_stack, norms_stack, state0)
 
 
 _DAY_CR1_STATIC_SH = ("mesh", "warm_steps", "first_steps", "first_shift",
@@ -1818,7 +1833,6 @@ def _day_cr2_impl_sharded(p: FleetProblem, cap_frac, mci_stack,
     in-scan from the local row block (row-separable), while the fleet-
     global norms (carbon normalizer, residual scale, step scale) ride in
     per tick from the TRUE fleet."""
-    from jax.experimental.shard_map import shard_map
     axis = fleet_axes(mesh)
 
     def body(pb, cap_b, mci_s, norms_s, st0):
@@ -1837,15 +1851,15 @@ def _day_cr2_impl_sharded(p: FleetProblem, cap_frac, mci_stack,
 
     state_specs = EngineState(x=P(axis), lam_eq=P(axis), lam_in=P(axis),
                               mu=P())
-    # check_rep=False: the day scan's tick solves may dispatch the fused
+    # check_vma=False: the day scan's tick solves may dispatch the fused
     # al_step pallas_call (use_kernel), which has no shard_map
     # replication rule; every output is explicitly spec'd above.
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(_fleet_specs(p, axis), P(), P(),
                   _norm_specs(p, axis, stacked=True), state_specs),
         out_specs=(P(None, axis), P(axis), P(axis), state_specs),
-        check_rep=False)(p, cap_frac, mci_stack, norms_stack, state0)
+        check_vma=False)(p, cap_frac, mci_stack, norms_stack, state0)
 
 
 _DAY_CR2_STATIC_SH = ("mesh", "warm_steps", "first_steps", "first_shift",

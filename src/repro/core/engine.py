@@ -432,7 +432,6 @@ def al_minimize_sharded(build_pieces: Callable[[Any], dict], data: Any, *,
     Returns (x, aux) exactly like `al_minimize`, with global arrays
     (sharded jax.Arrays over `mesh`).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if axis_name is None:
@@ -465,9 +464,10 @@ def al_minimize_sharded(build_pieces: Callable[[Any], dict], data: Any, *,
                                                     axis_name)
         return x, aux
 
-    # check_rep=False: the body may invoke a pallas_call (the fused
+    # check_vma=False: the body may invoke a pallas_call (the fused
     # al_step kernel), which has no shard_map replication rule; all
     # outputs here are explicitly spec'd, so the check adds nothing.
-    return shard_map(body, mesh=mesh, in_specs=(data_specs, state_specs),
-                     out_specs=(P(axis_name), aux_specs),
-                     check_rep=False)(data, init)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(data_specs, state_specs),
+                         out_specs=(P(axis_name), aux_specs),
+                         check_vma=False)(data, init)

@@ -136,7 +136,6 @@ def _cr1_ens_sharded(p: FleetProblem, vals, keys, lam, norms,
     `api.sweep` sharded-grid pattern): every device solves its row block
     for all S scenarios in one call. Per-scenario global normalizers come
     from the TRUE fleets (computed outside, stacked, replicated)."""
-    from jax.experimental.shard_map import shard_map
     axis = fleet_axes(mesh)
 
     def body(pb, vals_b, norms_b, states_b):
@@ -153,12 +152,15 @@ def _cr1_ens_sharded(p: FleetProblem, vals, keys, lam, norms,
         return jax.vmap(one)(vals_b, norms_b, states_b)
 
     specs = _ens_state_specs(axis)
-    return shard_map(
+    # check_vma=False: `fleet_penalties` may dispatch the dr_features
+    # pallas_call (use_kernel), which has no shard_map replication rule;
+    # every output is explicitly spec'd above.
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(_fleet_specs(p, axis), _overlay_specs(keys, axis),
                   norm_specs(p, axis, stacked=True), specs),
         out_specs=(P(None, axis), P(None, axis), specs),
-    )(p, vals, norms, states)
+        check_vma=False)(p, vals, norms, states)
 
 
 @functools.partial(jax.jit, static_argnames=("keys", "mesh", "steps",
@@ -166,7 +168,6 @@ def _cr1_ens_sharded(p: FleetProblem, vals, keys, lam, norms,
 def _cr2_ens_sharded(p: FleetProblem, vals, keys, refs, norms,
                      states: EngineState, mesh, steps: int, outer: int,
                      use_kernel: bool):
-    from jax.experimental.shard_map import shard_map
     axis = fleet_axes(mesh)
 
     def body(pb, vals_b, refs_b, norms_b, states_b):
@@ -183,12 +184,15 @@ def _cr2_ens_sharded(p: FleetProblem, vals, keys, refs, norms,
         return jax.vmap(one)(vals_b, refs_b, norms_b, states_b)
 
     specs = _ens_state_specs(axis)
-    return shard_map(
+    # check_vma=False: `fleet_penalties` may dispatch the dr_features
+    # pallas_call (use_kernel), which has no shard_map replication rule;
+    # every output is explicitly spec'd above.
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(_fleet_specs(p, axis), _overlay_specs(keys, axis),
                   P(None, axis), norm_specs(p, axis, stacked=True), specs),
         out_specs=(P(None, axis), P(None, axis), specs),
-    )(p, vals, refs, norms, states)
+        check_vma=False)(p, vals, refs, norms, states)
 
 
 def _pad_overlays(keys: tuple[str, ...], vals: tuple, W: int, W_pad: int):

@@ -24,6 +24,14 @@ import jax.numpy as jnp
 Array = jax.Array
 
 
+#: A running backlog within this fraction of its row's total |rate| mass
+#: counts as empty. A day-preserving plan drains the queue to exactly zero
+#: at every day end, so the running sum there is pure rounding noise; left
+#: to its sign, the hinge's subgradient would hang on summation order (XLA
+#: fusion, kernel vs jnp, device count) and the solver's path with it.
+QUEUE_RTOL = 1e-5
+
+
 def _pos(x: Array, smooth: float = 0.0) -> Array:
     """Positive part; softplus-smoothed when smooth > 0."""
     if smooth > 0.0:
@@ -31,17 +39,37 @@ def _pos(x: Array, smooth: float = 0.0) -> Array:
     return jnp.maximum(x, 0.0)
 
 
+def backlog_active(cum: Array, rate: Array) -> Array:
+    """Whether a running backlog `cum` (a prefix sum of `rate` along the
+    last axis) is positive beyond rounding: `cum > QUEUE_RTOL·Σ|rate|`.
+    This is the hinge subgradient the kernels use."""
+    return cum > QUEUE_RTOL * jnp.abs(rate).sum(axis=-1, keepdims=True)
+
+
+def backlog_pos(cum: Array, rate: Array) -> Array:
+    """Positive part of a running backlog, read as zero within rounding
+    of zero; its autodiff is `backlog_active`."""
+    return jnp.where(backlog_active(cum, rate), cum, 0.0)
+
+
+def _queue(rate: Array, smooth: float, lag: int = 0) -> Array:
+    """Σ_t (Σ_{t'<=t-lag} rate_{t'})⁺ over the trailing time axis."""
+    cum = jnp.cumsum(rate, axis=-1)[..., : rate.shape[-1] - lag]
+    if smooth > 0.0:
+        return _pos(cum, smooth).sum(axis=-1)
+    return backlog_pos(cum, rate).sum(axis=-1)
+
+
 def waiting_time_jobs(d: Array, usage: Array, jobs: Array,
                       smooth: float = 0.0) -> Array:
     """Σ_t ( Σ_{t'<=t} J_{t'} · d_{t'}/U_{t'} )⁺   [job·hour]."""
-    rate = jobs * d / usage
-    return _pos(jnp.cumsum(rate, axis=-1), smooth).sum(axis=-1)
+    return _queue(jobs * d / usage, smooth)
 
 
 def waiting_time_power(d: Array, smooth: float = 0.0) -> Array:
     """Σ_t ( Σ_{t'<=t} d_{t'} )⁺   [NP·hour] — selected as x1 for both
     AI training and data pipeline."""
-    return _pos(jnp.cumsum(d, axis=-1), smooth).sum(axis=-1)
+    return _queue(d, smooth)
 
 
 def waiting_time_squared(d: Array, usage: Array, jobs: Array,
@@ -54,8 +82,7 @@ def waiting_time_squared(d: Array, usage: Array, jobs: Array,
     semantics of the other features (a pure square would make boosting
     *increase* the penalty, which the paper's fitted model does not do).
     """
-    rate = jobs * d * jnp.abs(d) / usage
-    return _pos(jnp.cumsum(rate, axis=-1), smooth).sum(axis=-1)
+    return _queue(jobs * d * jnp.abs(d) / usage, smooth)
 
 
 def num_jobs_delayed(d: Array, usage: Array, jobs: Array,
@@ -73,12 +100,9 @@ def total_tardiness(d: Array, usage: Array, jobs: Array, slo_hours: int,
     only becomes tardy once it has waited SLO hours.
     """
     rate = jobs * d / usage
-    cum = jnp.cumsum(rate, axis=-1)
-    T = cum.shape[-1]
-    if slo_hours >= T:
-        return jnp.zeros(cum.shape[:-1], cum.dtype)
-    lagged = cum[..., : T - slo_hours]
-    return _pos(lagged, smooth).sum(axis=-1)
+    if slo_hours >= rate.shape[-1]:
+        return jnp.zeros(rate.shape[:-1], rate.dtype)
+    return _queue(rate, smooth, lag=slo_hours)
 
 
 FEATURE_NAMES = (

@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.core import features as feat
 from repro.core.engine import EngineState
 from repro.core.penalty import PenaltyModel
 
@@ -396,13 +397,10 @@ def _features(d: Array, usage: Array, jobs: Array, use_kernel: bool) -> Array:
     if use_kernel:
         from repro.kernels.dr_features.ops import dr_features
         return dr_features(d, usage, jobs)
-    rate = jobs * d / usage
-    wait_jobs = jnp.maximum(jnp.cumsum(rate, axis=1), 0).sum(1)
-    wait_power = jnp.maximum(jnp.cumsum(d, axis=1), 0).sum(1)
-    rate_sq = jobs * d * jnp.abs(d) / usage
-    wait_sq = jnp.maximum(jnp.cumsum(rate_sq, axis=1), 0).sum(1)
-    njobs = (jobs * jnp.maximum(d, 0) / usage).sum(1)
-    return jnp.stack([wait_jobs, wait_power, wait_sq, njobs], axis=1)
+    return jnp.stack([feat.waiting_time_jobs(d, usage, jobs),
+                      feat.waiting_time_power(d),
+                      feat.waiting_time_squared(d, usage, jobs),
+                      feat.num_jobs_delayed(d, usage, jobs)], axis=1)
 
 
 def fleet_penalties(p: FleetProblem, D: Array,

@@ -26,6 +26,7 @@ the models.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Callable, Mapping, Sequence
 
 import jax
@@ -239,7 +240,8 @@ def build_paper_fleet(hours: int = 48, total_power: float = 100.0,
                           ("DataPipeline", "batch_slo", 162)):
         jobs = make_job_trace(kind, hours=hours,
                               total_power=1.05 * float(np.mean(traces[name].usage)),
-                              num_jobs=num_jobs, seed=seed + hash(name) % 97)
+                              num_jobs=num_jobs,
+                              seed=(seed, zlib.crc32(name.encode())))
         samples = min(num_samples, n)
         model, _, _ = build_batch_model(name, traces[name], jobs,
                                         num_samples=samples, seed=seed)

@@ -30,7 +30,6 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core.engine import EngineConfig, al_minimize
 from repro.core.policies import DRProblem, PolicySpec
@@ -128,7 +127,7 @@ def solve_slsqp(spec: PolicySpec, x0: np.ndarray | None = None,
                 "fun": lambda x: np.asarray(f(jnp.asarray(x))),
                 "jac": lambda x: np.asarray(j(jnp.asarray(x)))}
 
-    with enable_x64(True):
+    with jax.enable_x64(True):
         obj_grad = jax.jit(jax.value_and_grad(
             lambda x: spec.objective(x.reshape(W, T))))
 

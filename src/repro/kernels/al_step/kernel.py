@@ -32,10 +32,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.al_step.ref import _pen_and_grad, _project
-from repro.kernels.dispatch import tpu_compiler_params
 
 
 def _al_step_kernel(x_ref, m_ref, v_ref, u_ref, j_ref, lo_ref, hi_ref,
@@ -134,7 +133,7 @@ def al_step_pallas(x, m, v, usage, jobs, lo, hi, rowp, cvec, scal, *,
         out_shape=[jax.ShapeDtypeStruct((W + pw, T), jnp.float32),
                    jax.ShapeDtypeStruct((W + pw, T), m.dtype),
                    jax.ShapeDtypeStruct((W + pw, T), v.dtype)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(pad(x), pad(m), pad(v), pad(usage, 1.0), pad(jobs), pad(lo),

@@ -20,9 +20,11 @@ tolerance.
 
 Gradient convention: hinge boundaries use the strict `>` subgradient
 (zero at the tie), matching the analytic custom VJP in
-`kernels/dr_features/ops.py` — NOT jnp autodiff of `max`, which emits
-0.5 at exact ties (this only differs on measure-zero inputs like the
-all-zeros cold start).
+`kernels/dr_features/ops.py`. The queue-integral hinges read a backlog
+within rounding of zero as empty (`features.backlog_pos`, whose
+autodiff gives the same indicator); the `max(z, 0)` and `max(x, 0)`
+hinges differ from jnp autodiff of `max` (0.5 at exact ties) only on
+measure-zero inputs like the all-zeros cold start.
 
 Row-parameter packing (see `ops.pack_rows`) — `rowp` is (W, 12) f32:
 
@@ -60,9 +62,27 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.features import backlog_active
 
-def _revcum(x):
-    return jnp.flip(jnp.cumsum(jnp.flip(x, axis=1), axis=1), axis=1)
+
+def _tri_mask(T, reverse):
+    """Static (T, T) triangular mask: m[s, t] = 1 iff s <= t (s >= t
+    when `reverse`), built with `broadcasted_iota` like `_day_mask`."""
+    s = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
+    t = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
+    keep = s >= t if reverse else s <= t
+    return jnp.where(keep, jnp.float32(1.0), jnp.float32(0.0))
+
+
+def prefix_sum(x, reverse: bool = False):
+    """Running sum of a (W, T) f32 array along T, from the left (from
+    the right when `reverse`), as one matmul against a triangular mask:
+    Pallas TPU has no cumsum lowering, and T <= 48 makes the T×T mask
+    tiny. HIGHEST precision keeps the MXU at full f32."""
+    return jax.lax.dot_general(
+        x, _tri_mask(x.shape[1], reverse), (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def _pen_and_grad(x, inv_u, ju, rowp):
@@ -83,17 +103,19 @@ def _pen_and_grad(x, inv_u, ju, rowp):
     rts_g = kk * (3.0 * a3 * delta ** 2 + 2.0 * a2 * delta + a1) * inv_u
 
     # Batch: pen = k·max(b0 + b1·wait_power + b2·x2, 0).
-    c1 = jnp.cumsum(x, axis=1)
-    x1 = jnp.maximum(c1, 0.0).sum(axis=1, keepdims=True)
-    c2 = jnp.cumsum(ju * x * jnp.abs(x), axis=1)
-    x2s = jnp.maximum(c2, 0.0).sum(axis=1, keepdims=True)
+    r2 = ju * x * jnp.abs(x)
+    c1, c2 = prefix_sum(x), prefix_sum(r2)
+    a1, a2 = backlog_active(c1, x), backlog_active(c2, r2)
+    x1 = jnp.where(a1, c1, 0.0).sum(axis=1, keepdims=True)
+    x2s = jnp.where(a2, c2, 0.0).sum(axis=1, keepdims=True)
     nj = (ju * jnp.maximum(x, 0.0)).sum(axis=1, keepdims=True)
     x2 = jnp.where(x2k > 0.5, x2s, nj)
     z = b0 + b1 * x1 + b2 * x2
     batch_pen = kk * jnp.maximum(z, 0.0)
 
-    g1 = _revcum((c1 > 0).astype(x.dtype))
-    g2s = 2.0 * ju * jnp.abs(x) * _revcum((c2 > 0).astype(x.dtype))
+    g1 = prefix_sum(a1.astype(x.dtype), reverse=True)
+    g2s = 2.0 * ju * jnp.abs(x) * prefix_sum(a2.astype(x.dtype),
+                                             reverse=True)
     g2n = ju * (x > 0).astype(x.dtype)
     gx2 = jnp.where(x2k > 0.5, g2s, g2n)
     batch_g = kk * (z > 0).astype(x.dtype) * (b1 * g1 + b2 * gx2)
@@ -128,9 +150,11 @@ def _project(x, lo, hi, isb, day_hours):
     for _ in range(3):
         mean = jax.lax.dot_general(
             x, mask, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=f32) * (1.0 / day_hours)
         sub = jax.lax.dot_general(
             mean, mask, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=f32)
         x = jnp.clip(jnp.where(batch_rows, x - sub, x), lo, hi)
     return x

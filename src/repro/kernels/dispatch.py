@@ -1,9 +1,10 @@
 """Backend-aware Pallas dispatch shared by all kernel wrappers.
 
-Pallas kernels compile for real on TPU and fall back to interpret mode
+Pallas kernels compile for real on TPU and run in interpret mode
 elsewhere (CPU containers, CI). `REPRO_PALLAS_INTERPRET` overrides the
 auto-detection in both directions: truthy forces interpret mode even on
-TPU (debugging), falsy forces the compiled path.
+TPU (debugging), falsy forces the compiled path. A backend that fails to
+initialize raises; it is never read as "not a TPU".
 """
 from __future__ import annotations
 
@@ -15,10 +16,7 @@ _FALSY = ("0", "false", "no", "off")
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def interpret_default(interpret: bool | None = None) -> bool:
@@ -29,11 +27,3 @@ def interpret_default(interpret: bool | None = None) -> bool:
     if env is not None:
         return env.strip().lower() not in _FALSY
     return not on_tpu()
-
-
-def tpu_compiler_params(**kwargs):
-    """`pltpu.CompilerParams` across jax versions (older: TPUCompilerParams)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)

@@ -8,8 +8,9 @@ of workloads resident in VMEM and emits all four features in one pass
 (arithmetic intensity: ~10 flops/byte on a (128, T=48→128-padded) tile,
 bound by the single HBM read of d/usage/jobs).
 
-Hours are padded to the 128-lane width; cumulative sums run along the lane
-axis inside the tile.
+Hours sit on the lane axis of the tile; the running sums are matmuls
+against a triangular mask (`al_step.ref.prefix_sum`), the form Mosaic
+lowers.
 """
 from __future__ import annotations
 
@@ -18,22 +19,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import tpu_compiler_params
+from repro.core.features import backlog_pos
+from repro.kernels.al_step.ref import prefix_sum
+
+
+def _queue(rate):
+    return backlog_pos(prefix_sum(rate), rate).sum(axis=1, keepdims=True)
 
 
 def _features_kernel(d_ref, u_ref, j_ref, o_ref):
     d = d_ref[...].astype(jnp.float32)
     u = u_ref[...].astype(jnp.float32)
     j = j_ref[...].astype(jnp.float32)
-    rate = j * d / u
-    wait_jobs = jnp.maximum(jnp.cumsum(rate, axis=1), 0.0).sum(axis=1)
-    wait_power = jnp.maximum(jnp.cumsum(d, axis=1), 0.0).sum(axis=1)
-    rate_sq = j * d * jnp.abs(d) / u
-    wait_sq = jnp.maximum(jnp.cumsum(rate_sq, axis=1), 0.0).sum(axis=1)
-    njobs = (j * jnp.maximum(d, 0.0) / u).sum(axis=1)
-    o_ref[...] = jnp.stack([wait_jobs, wait_power, wait_sq, njobs], axis=1)
+    o_ref[:, 0:1] = _queue(j * d / u)
+    o_ref[:, 1:2] = _queue(d)
+    o_ref[:, 2:3] = _queue(j * d * jnp.abs(d) / u)
+    o_ref[:, 3:4] = (j * jnp.maximum(d, 0.0) / u).sum(axis=1, keepdims=True)
 
 
 def dr_features_pallas(d, usage, jobs, block_w: int = 128,
@@ -58,7 +61,7 @@ def dr_features_pallas(d, usage, jobs, block_w: int = 128,
         in_specs=[pl.BlockSpec((block_w, T), lambda i: (i, 0))] * 3,
         out_specs=pl.BlockSpec((block_w, 4), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((dp.shape[0], 4), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(dp, up, jp)

@@ -4,9 +4,10 @@ The wrapper carries an analytic custom VJP: the solver hot loop
 differentiates penalties through these features every Adam step, and
 `pallas_call` has no registered transpose. The backward pass is closed
 form — each feature is Σ_t max(cumsum(r), 0) for a per-hour rate r, so
-∂/∂d is a reversed cumulative sum of the active-hinge indicator times
-∂r/∂d. Gradients flow to `d` only (usage/jobs are problem constants in
-every solver path).
+∂/∂d is a reversed cumulative sum of the active-hinge indicator (with
+the rounding floor of `features.backlog_pos`) times ∂r/∂d. Gradients
+flow to `d` only (usage/jobs are problem constants in every solver
+path).
 """
 from __future__ import annotations
 
@@ -15,6 +16,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.features import backlog_active
+from repro.kernels.al_step.ref import prefix_sum
 from repro.kernels.dispatch import interpret_default
 from repro.kernels.dr_features.kernel import dr_features_pallas
 
@@ -28,20 +31,20 @@ def _fwd(interpret, d, usage, jobs):
     return _dr_features(interpret, d, usage, jobs), (d, usage, jobs)
 
 
-def _revcum(x):
-    return jnp.flip(jnp.cumsum(jnp.flip(x, axis=1), axis=1), axis=1)
+def _revcum_active(rate):
+    """Reversed running sum of the active-hinge indicator of rate's
+    backlog — ∂/∂rate of Σ_t backlog_pos(cumsum(rate))_t."""
+    active = backlog_active(prefix_sum(rate), rate).astype(rate.dtype)
+    return prefix_sum(active, reverse=True)
 
 
 def _bwd(interpret, res, ct):
     d, usage, jobs = res
     ju = jobs / usage
-    # Active-hinge indicators for the three cumulative features.
-    a0 = (jnp.cumsum(ju * d, axis=1) > 0).astype(d.dtype)          # wait_jobs
-    a1 = (jnp.cumsum(d, axis=1) > 0).astype(d.dtype)               # wait_power
-    a2 = (jnp.cumsum(ju * d * jnp.abs(d), axis=1) > 0).astype(d.dtype)
-    d_ct = (ct[:, 0:1] * ju * _revcum(a0)
-            + ct[:, 1:2] * _revcum(a1)
-            + ct[:, 2:3] * 2.0 * ju * jnp.abs(d) * _revcum(a2)
+    d_ct = (ct[:, 0:1] * ju * _revcum_active(ju * d)             # wait_jobs
+            + ct[:, 1:2] * _revcum_active(d)                    # wait_power
+            + ct[:, 2:3] * 2.0 * ju * jnp.abs(d)
+            * _revcum_active(ju * d * jnp.abs(d))               # wait_sq
             + ct[:, 3:4] * ju * (d > 0).astype(d.dtype))
     return d_ct, jnp.zeros_like(usage), jnp.zeros_like(jobs)
 

@@ -46,7 +46,7 @@ def compressed_psum_pod(grads: Any, error: Any, axis: str = "pod",
     Returns (mean-reduced grads, new error residuals). Must run inside a
     shard_map where `axis` is a manual axis.
     """
-    n = jax.lax.psum(1, axis)  # axis size (jax.lax.axis_size is newer-jax)
+    n = jax.lax.axis_size(axis)
 
     def one(g, e):
         target = g.astype(jnp.float32) + e.astype(jnp.float32)

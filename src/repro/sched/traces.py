@@ -118,7 +118,8 @@ def fleet_power_traces(hours: int = 48, total_power: float = 100.0,
 
 
 def make_job_trace(kind: str, hours: int = 48, num_jobs: int = 10_000,
-                   total_power: float = 20.0, seed: int = 0) -> JobTrace:
+                   total_power: float = 20.0,
+                   seed: int | tuple[int, ...] = 0) -> JobTrace:
     """Job-level trace for a batch service (paper: 10,000 jobs / 2 days).
 
     Args:

@@ -37,7 +37,7 @@ def _rules(violations):
 # ---------------------------------------------------------------------------
 def test_rule_registry_complete():
     assert set(RULES) == {
-        "jit-host-leak", "donation-twin", "check-rep-justification",
+        "jit-host-leak", "donation-twin", "check-vma-justification",
         "tuple-seed", "np-on-traced", "deprecated-shim",
         "adhoc-partition-spec", "host-sync-in-jit"}
 
@@ -137,21 +137,21 @@ def test_donation_twin_ok_and_static_donation_flagged():
 
 def test_check_rep_needs_pallas_comment():
     bad = _lint("""
-        from jax.experimental.shard_map import shard_map
+        import jax
 
         def build(mesh, body, specs):
-            return shard_map(body, mesh=mesh, in_specs=specs,
-                             out_specs=specs, check_rep=False)
+            return jax.shard_map(body, mesh=mesh, in_specs=specs,
+                                 out_specs=specs, check_vma=False)
     """)
-    assert _rules(bad) == ["check-rep-justification"]
+    assert _rules(bad) == ["check-vma-justification"]
     ok = _lint("""
-        from jax.experimental.shard_map import shard_map
+        import jax
 
         def build(mesh, body, specs):
-            # check_rep=False: body dispatches the al_step pallas_call,
-            # which has no shard_map replication rule.
-            return shard_map(body, mesh=mesh, in_specs=specs,
-                             out_specs=specs, check_rep=False)
+            # check_vma=False: body dispatches the al_step pallas_call,
+            # which has no shard_map varying-axes rule.
+            return jax.shard_map(body, mesh=mesh, in_specs=specs,
+                                 out_specs=specs, check_vma=False)
     """)
     assert ok == []
 
@@ -447,7 +447,7 @@ def test_recompile_guard_measures_and_fires():
 
     with recompile_guard(None) as stats:
         f(jnp.ones(5))
-    assert stats.compiled   # fresh trace measured
+    assert stats.traces > 0 and stats.lowerings > 0   # both hooks count
 
     with recompile_guard(0):
         f(jnp.ones(5))      # warm: cache hit, no trace

@@ -176,8 +176,7 @@ def test_engine_batched_returns_stacked_aux_and_warm_starts():
 # fleet_penalties is the single penalty path — its gradients must be exact
 # ---------------------------------------------------------------------------
 def test_fleet_penalties_grad_matches_finite_differences(fp4, rng):
-    from jax.experimental import enable_x64
-    with enable_x64(True):
+    with jax.enable_x64(True):
         D0 = jnp.asarray(rng.uniform(-0.5, 0.5, size=(fp4.W, fp4.T)))
 
         def f(D):

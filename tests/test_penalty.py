@@ -62,3 +62,29 @@ def test_fleet_composition(paper_fleet):
     assert kinds == {"realtime", "batch_slo", "batch_noslo"}
     for m in paper_fleet.values():
         assert m.entitlement > float(np.max(m.usage))
+
+
+def test_paper_fleet_build_independent_of_hash_seed():
+    """The calibrated fleet is a function of its arguments alone: the
+    per-service job-trace seeds must not come from Python's per-process
+    string hash (a differently calibrated fleet per run moved every
+    result built on it)."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    code = ("import json\n"
+            "from repro.core.penalty import build_paper_fleet\n"
+            "f = build_paper_fleet(hours=24, num_samples=8, num_jobs=300)\n"
+            "print(json.dumps({n: [float(m.k), *map(float, m.params)]"
+            " for n, m in f.items()}))\n")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    outs = []
+    for hash_seed in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=hash_seed, PYTHONPATH=src)
+        res = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert res.returncode == 0, res.stderr[-2000:]
+        outs.append(json.loads(res.stdout.strip().splitlines()[-1]))
+    assert outs[0] == outs[1]

@@ -135,18 +135,14 @@ def test_compressed_psum_matches_uncompressed(tmp_path):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.runtime.compression import compressed_psum_pod, init_error
-mesh = jax.make_mesh((2,), ("pod",))
+mesh = jax.make_mesh((2,), ("pod",), axis_types=(jax.sharding.AxisType.Auto,))
 g = {"w": jnp.asarray(np.random.default_rng(0).standard_normal((2, 64)).astype(np.float32))}
 e = init_error(g)
 def f(g, e):
     out, new_e = compressed_psum_pod(g, e, "pod")
     return out, new_e
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
-fn = shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-               out_specs=(P("pod"), P("pod")))
+fn = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                   out_specs=(P("pod"), P("pod")))
 out, new_e = fn(g, e)
 exact = (np.asarray(g["w"])[0] + np.asarray(g["w"])[1]) / 2
 got = np.asarray(out["w"])

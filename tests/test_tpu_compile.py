@@ -1,0 +1,147 @@
+"""Compile the main-path kernels for a described TPU v5e, at real widths.
+
+No chip is attached: `jax.experimental.topologies` describes a v5e:2x2
+and the TPU compiler runs ahead of time, refusing what the chip's
+compiler would refuse (unlowerable primitives, misaligned slices, VMEM
+overflow). The topology is described inside a module fixture, never at
+import, so every test worker collects the same tests and only the
+worker running this file loads the TPU library.
+
+Also pins the kernels' triangular-mask prefix sum against `jnp.cumsum`.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.al_step.kernel import al_step_pallas
+from repro.kernels.al_step.ref import prefix_sum
+from repro.kernels.dr_features.kernel import dr_features_pallas
+
+W, T = 100_000, 48
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(one_chip, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("reverse", [False, True])
+@pytest.mark.parametrize("hours", [24, 48])
+def test_prefix_sum_matches_cumsum(reverse, hours):
+    x = np.random.default_rng(hours).normal(size=(37, hours))
+    x = jnp.asarray(x, jnp.float32)
+    want = (jnp.flip(jnp.cumsum(jnp.flip(x, 1), 1), 1) if reverse
+            else jnp.cumsum(x, 1))
+    np.testing.assert_allclose(np.asarray(prefix_sum(x, reverse=reverse)),
+                               np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+def test_dr_features_compiles_for_v5e(one_chip):
+    s = _shape(one_chip, (W, T))
+    hlo = jax.jit(lambda d, u, j: dr_features_pallas(
+        d, u, j, interpret=False)).lower(s, s, s).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("moments", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("mode", ["cr1", "cr2"])
+def test_al_step_compiles_for_v5e(one_chip, mode, moments):
+    s = _shape(one_chip, (W, T))
+    mv = _shape(one_chip, (W, T), moments)
+
+    def step(x, m, v, u, j, lo, hi, rowp, cvec, scal):
+        return al_step_pallas(x, m, v, u, j, lo, hi, rowp, cvec, scal,
+                              mode=mode, k_steps=8, interpret=False)
+
+    hlo = jax.jit(step).lower(
+        s, mv, mv, s, s, s, s, _shape(one_chip, (W, 12)),
+        _shape(one_chip, (1, T)), _shape(one_chip, (1, 8))).compile()
+    assert "tpu_custom_call" in hlo.as_text()
+
+
+def _fleet_at_scale(sharding):
+    """A W-row synthetic fleet and a cold engine state as shapes on
+    `sharding` (built from a 16-row fleet: only shapes are compiled)."""
+    from repro.core.engine import EngineState
+    from repro.core.fleet_solver import _jit_view, synthetic_fleet
+
+    def at_scale(a):
+        a = np.asarray(a)
+        shape = (W,) + a.shape[1:] if a.ndim and a.shape[0] == 16 \
+            else a.shape
+        dtype = jnp.float32 if a.dtype == np.float64 else a.dtype
+        return _shape(sharding, shape, dtype)
+
+    fleet = jax.tree.map(at_scale, _jit_view(synthetic_fleet(16, hours=T)))
+    state = EngineState(x=_shape(sharding, (W, T)),
+                        lam_eq=_shape(sharding, (0,)),
+                        lam_in=_shape(sharding, (0,)),
+                        mu=_shape(sharding, ()))
+    return fleet, state
+
+
+def test_cr1_solve_compiles_for_v5e(one_chip, monkeypatch):
+    """The whole jitted CR1 solve on the kernel path, as the chip runs it
+    by default."""
+    from repro.core.api import CR1, _cr1_run
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    fleet, state = _fleet_at_scale(one_chip)
+    compiled = _cr1_run.lower(fleet, _shape(one_chip, ()), state,
+                              steps=CR1.default_steps,
+                              use_kernel=True).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+def test_sharded_cr1_solve_compiles_for_v5e_2x2(topo, monkeypatch):
+    """The CR1 solve sharded over four chips: every Pallas call must sit
+    inside a shard_map (the compiler cannot partition a kernel)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.core.api import CR1, _cr1_run_sharded
+    from repro.launch.mesh import FLEET_AXIS
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    mesh = Mesh(np.asarray(topo.devices), (FLEET_AXIS,))
+    rep = NamedSharding(mesh, PartitionSpec())
+    fleet, state = _fleet_at_scale(rep)
+    norms = (_shape(rep, ()),) * 3
+    compiled = _cr1_run_sharded.lower(
+        fleet, _shape(rep, ()), norms, state, mesh=mesh,
+        steps=CR1.default_steps, use_kernel=True).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_chip_smoke_refuses_cpu():
+    """No fallback: on a CPU backend the chip smoke test exits nonzero,
+    names the platform, and prints no result line."""
+    import subprocess
+    import sys
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    res = subprocess.run(
+        [sys.executable, os.path.join(root, "chip_smoke.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=300)
+    assert res.returncode != 0
+    assert "'cpu'" in res.stderr
+    assert '"ok"' not in res.stdout
