@@ -183,8 +183,13 @@ def main() -> None:
     # norm, max constraint violation, mu — as stacked scan outputs (no
     # host callbacks, no extra dispatches; the returned plan is bitwise
     # identical to a telemetry-off solve). obs.span times host-side
-    # work, synchronizing on device results before reading the clock.
-    # Streaming runs write a JSONL ledger instead
+    # work (`bind` synchronizes on device results before the clock is
+    # read). Every span — the program's own `dr.tick` / `dr.solve` /
+    # `dr.solve.dispatch` / `.wait` / `.pull` / `.report` ones too —
+    # records start_ns/end_ns (perf_counter), its parent and tick ids
+    # and its counts (h2d_bytes, d2h_bytes, dispatches) into a bounded,
+    # always-on ring (`obs.spans.recorded()`), and shows up by name in
+    # any jax.profiler trace. Streaming runs write a JSONL ledger
     # (RollingHorizonSolver(events=..., telemetry=...) or
     # `examples/streaming_dr.py --telemetry run.jsonl`), rendered by
     # `python -m repro.obs.report run.jsonl`.
@@ -201,6 +206,14 @@ def main() -> None:
           f" grad norm {trace.grad_norm[-1]:.2e} at step {trace.step[-1]}")
     print(f"  plan bitwise = untelemetered solve: "
           f"{bool(np.array_equal(rt.D, rs.D))}")
+    ring = obs.spans.recorded()
+    solve_span = next(r for r in ring if r.parent == sp.id)
+    print(f"  spans under {sp.name!r}: {solve_span.name} "
+          f"{solve_span.elapsed_ns * 1e-6:.1f} ms, of it")
+    for r in ring:
+        if r.parent == solve_span.id:
+            print(f"    {r.name:<20s} {r.elapsed_ns * 1e-6:8.2f} ms  "
+                  f"{r.counts or ''}")
 
 
 if __name__ == "__main__":

@@ -87,6 +87,7 @@ from repro.core.regional import (CR1_NORM_FILLS, CR2_NORM_FILLS,
                                  region_totals as _region_totals)
 from repro.launch.mesh import fleet_axes, fleet_device_count
 # repro.obs is import-light and never imports repro.core (no cycle).
+from repro.obs.spans import span
 from repro.obs.telemetry import ConvergenceTrace, TelemetryConfig
 
 Array = jax.Array
@@ -281,6 +282,41 @@ def _require_telemetry_ok(ctx: SolveContext, use_kernel: bool) -> None:
             "telemetry) for this solve")
 
 
+def _host_bytes(args) -> int:
+    """Bytes the device will hold for the host (non-`jax.Array`) leaves of
+    `args`: each leaf's size in its canonical dtype (float64 counts as
+    float32 where x64 is off)."""
+    n = 0
+    for leaf in jax.tree_util.tree_leaves(args):
+        if not isinstance(leaf, jax.Array):
+            a = np.asarray(leaf)
+            n += a.size * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
+    return n
+
+
+def _dispatch(run, *args, **static):
+    """`run(*args, **static)` — a jitted lane, dynamic arguments
+    positional — under a `dr.solve.dispatch` span: argument conversion,
+    upload and enqueue, until the call returns. Counts the dispatch and
+    the host bytes it uploads (`h2d_bytes`)."""
+    with span("dr.solve.dispatch") as sp:
+        sp.count("dispatches", 1)
+        sp.count("h2d_bytes", _host_bytes(args))
+        return run(*args, **static)
+
+
+def _fetch(*arrays) -> tuple:
+    """Wait for the device (`dr.solve.wait`, where the host blocks
+    anyway), then pull `arrays` to the host (`dr.solve.pull`, counting
+    `d2h_bytes`)."""
+    with span("dr.solve.wait"):
+        jax.block_until_ready(arrays[0])
+    with span("dr.solve.pull") as sp:
+        out = tuple(np.asarray(a) for a in arrays)
+        sp.count("d2h_bytes", sum(a.nbytes for a in out))
+    return out
+
+
 def solve(problem: FleetProblem, policy, *,
           ctx: SolveContext | None = None) -> FleetSolveResult:
     """Solve `problem` under `policy` — the single fleet entry point.
@@ -299,10 +335,13 @@ def solve(problem: FleetProblem, policy, *,
     policy = resolve_policy(policy)
     if ctx.sanitize:
         _require_sanitizable(policy, ctx)
-    res = policy.solve(problem, ctx)
-    if ctx.coupled_migration:
-        return _coupled_migrate(problem, policy, res, ctx)
-    return _maybe_migrate(problem, res)
+    with span("dr.solve", meta={"policy": policy.name,
+                                "lane": "solo" if ctx.mesh is None
+                                else "mesh"}):
+        res = policy.solve(problem, ctx)
+        if ctx.coupled_migration:
+            return _coupled_migrate(problem, policy, res, ctx)
+        return _maybe_migrate(problem, res)
 
 
 def sweep(problem: FleetProblem, policies: Sequence, *,
@@ -854,24 +893,24 @@ class CR1:
             if warm is None:
                 warm = EngineState.cold(jnp.zeros(p.usage.shape))
             if ctx.sanitize:
-                err, out = _cr1_run_checked(
-                    _jit_view(p), self.lam, warm, steps=steps,
-                    use_kernel=use_kernel, shift=ctx.shift,
+                err, out = _dispatch(
+                    _cr1_run_checked, _jit_view(p), self.lam, warm,
+                    steps=steps, use_kernel=use_kernel, shift=ctx.shift,
                     reset_mu=ctx.reset_mu, moment_dtype=ctx.moment_dtype,
                     sanitize=True, telemetry_every=tel)
                 err.throw()
             else:
                 run = _cr1_run_donated if ctx.donate else _cr1_run
-                out = run(_jit_view(p), self.lam, warm,
-                          steps=steps, use_kernel=use_kernel,
-                          shift=ctx.shift, reset_mu=ctx.reset_mu,
-                          moment_dtype=ctx.moment_dtype,
-                          telemetry_every=tel)
-            D, pens, state = out[:3]
+                out = _dispatch(run, _jit_view(p), self.lam, warm,
+                                steps=steps, use_kernel=use_kernel,
+                                shift=ctx.shift, reset_mu=ctx.reset_mu,
+                                moment_dtype=ctx.moment_dtype,
+                                telemetry_every=tel)
+            D, pens = _fetch(*out[:2])
             extras = {"telemetry": ConvergenceTrace.from_aux(out[3])} \
                 if tel else None
-            return _report(p, np.asarray(D), np.asarray(pens), iters=steps,
-                           state=state, extras=extras)
+            return _report(p, D, pens, iters=steps, state=out[2],
+                           extras=extras)
         pp, W = pad_fleet(p, fleet_device_count(ctx.mesh))
         norms = _cr1_norms(p)
         if p.is_multiregion:
@@ -879,15 +918,15 @@ class CR1:
         warm = _pad_state(warm, pp.W) if warm is not None \
             else EngineState.cold(jnp.zeros(pp.usage.shape))
         run = _cr1_run_sharded_donated if ctx.donate else _cr1_run_sharded
-        out = run(pp, self.lam, norms, warm, mesh=ctx.mesh,
-                  steps=steps, use_kernel=use_kernel,
-                  shift=ctx.shift, reset_mu=ctx.reset_mu,
-                  moment_dtype=ctx.moment_dtype, telemetry_every=tel)
-        D, pens, state = out[:3]
+        out = _dispatch(run, pp, self.lam, norms, warm, mesh=ctx.mesh,
+                        steps=steps, use_kernel=use_kernel,
+                        shift=ctx.shift, reset_mu=ctx.reset_mu,
+                        moment_dtype=ctx.moment_dtype, telemetry_every=tel)
+        D, pens = _fetch(*out[:2])
         extras = {"telemetry": ConvergenceTrace.from_aux(out[3])} \
             if tel else None
-        return _report(p, np.asarray(D)[:W], np.asarray(pens)[:W],
-                       iters=steps, state=state, extras=extras)
+        return _report(p, D[:W], pens[:W], iters=steps, state=out[2],
+                       extras=extras)
 
     # -- vmapped sweep lane -------------------------------------------------
     @classmethod
@@ -1106,8 +1145,8 @@ class CR2:
                 warm = EngineState.cold(jnp.zeros(p.usage.shape), n_eq=p.W,
                                         mu0=CR2_MU0)
             if ctx.sanitize:
-                err, out = _cr2_run_checked(
-                    _jit_view(p), refs, warm, steps=steps,
+                err, out = _dispatch(
+                    _cr2_run_checked, _jit_view(p), refs, warm, steps=steps,
                     outer=self.outer, use_kernel=use_kernel,
                     shift=ctx.shift, reset_mu=ctx.reset_mu,
                     moment_dtype=ctx.moment_dtype, sanitize=True,
@@ -1115,17 +1154,16 @@ class CR2:
                 err.throw()
             else:
                 run = _cr2_run_donated if ctx.donate else _cr2_run
-                out = run(_jit_view(p), refs, warm, steps=steps,
-                          outer=self.outer, use_kernel=use_kernel,
-                          shift=ctx.shift, reset_mu=ctx.reset_mu,
-                          moment_dtype=ctx.moment_dtype,
-                          telemetry_every=tel)
-            D, pens, state = out[:3]
+                out = _dispatch(run, _jit_view(p), refs, warm, steps=steps,
+                                outer=self.outer, use_kernel=use_kernel,
+                                shift=ctx.shift, reset_mu=ctx.reset_mu,
+                                moment_dtype=ctx.moment_dtype,
+                                telemetry_every=tel)
+            D, pens = _fetch(*out[:2])
             extras = {"telemetry": ConvergenceTrace.from_aux(out[3])} \
                 if tel else None
-            return _report(p, np.asarray(D), np.asarray(pens),
-                           iters=steps * self.outer, state=state,
-                           extras=extras)
+            return _report(p, D, pens, iters=steps * self.outer,
+                           state=out[2], extras=extras)
         pp, W = pad_fleet(p, fleet_device_count(ctx.mesh))
         norms = _cr2_norms(p, refs)
         if p.is_multiregion:
@@ -1135,17 +1173,16 @@ class CR2:
             else EngineState.cold(jnp.zeros(pp.usage.shape), n_eq=pp.W,
                                   mu0=CR2_MU0)
         run = _cr2_run_sharded_donated if ctx.donate else _cr2_run_sharded
-        out = run(pp, refs_p, norms, warm, mesh=ctx.mesh,
-                  steps=steps, outer=self.outer,
-                  use_kernel=use_kernel, shift=ctx.shift,
-                  reset_mu=ctx.reset_mu,
-                  moment_dtype=ctx.moment_dtype, telemetry_every=tel)
-        D, pens, state = out[:3]
+        out = _dispatch(run, pp, refs_p, norms, warm, mesh=ctx.mesh,
+                        steps=steps, outer=self.outer,
+                        use_kernel=use_kernel, shift=ctx.shift,
+                        reset_mu=ctx.reset_mu,
+                        moment_dtype=ctx.moment_dtype, telemetry_every=tel)
+        D, pens = _fetch(*out[:2])
         extras = {"telemetry": ConvergenceTrace.from_aux(out[3])} \
             if tel else None
-        return _report(p, np.asarray(D)[:W], np.asarray(pens)[:W],
-                       iters=steps * self.outer, state=state,
-                       extras=extras)
+        return _report(p, D[:W], pens[:W], iters=steps * self.outer,
+                       state=out[2], extras=extras)
 
     # -- vmapped sweep lane -------------------------------------------------
     @classmethod
@@ -1418,14 +1455,18 @@ class CR3:
         reg_scale = 1e-3 / (W * p.T)
 
         def best_response(st, shift_, reset_):
+            """One clearing round: dispatch, wait, and the pull of the
+            true-W plan and penalties."""
             kw = {} if ctx.mesh is None else {"mesh": ctx.mesh}
-            return twin(pj, rho_cur, self.tax_frac, reg_scale, st,
-                        steps=steps, outer=self.outer,
-                        use_kernel=use_kernel, shift=shift_,
-                        reset_mu=reset_, **kw)
+            with span("dr.solve.round"):
+                D, pens, st = _dispatch(
+                    twin, pj, rho_cur, self.tax_frac, reg_scale, st,
+                    steps=steps, outer=self.outer, use_kernel=use_kernel,
+                    shift=shift_, reset_mu=reset_, **kw)
+                D, pens = _fetch(D, pens)
+            return D[:W], pens[:W], st
 
         D, pens, state = best_response(state, ctx.shift, ctx.reset_mu)
-        D = np.asarray(D)[:W]
         rounds = 1
         paid = rho_cur * float((D @ mci).sum())
         for _ in range(self.clearing_iters):
@@ -1436,7 +1477,6 @@ class CR3:
             # so every round keeps the gentle wall the best response
             # relies on.
             D, pens, state = best_response(state, 0, True)
-            D = np.asarray(D)[:W]
             rounds += 1
             paid = rho_cur * float((D @ mci).sum())
         balanced = paid <= collected + 1e-9
@@ -1444,7 +1484,7 @@ class CR3:
         if not balanced:
             _cr3_unbalanced_warn(self.clearing_iters, deficit, rho_cur,
                                  "CR3.solve")
-        return _report(p, D, np.asarray(pens)[:W],
+        return _report(p, D, pens,
                        iters=steps * self.outer * rounds, state=state,
                        extras={"rho": rho_cur, "balanced": balanced,
                                "fiscal_deficit": deficit})
@@ -1485,17 +1525,22 @@ class CR3:
         region_pad = np.asarray(pj.region)
 
         def best_response(st, shift_, reset_):
+            """One clearing round: dispatch, wait, and the pull of the
+            true-W plan and penalties."""
             kw = {} if ctx.mesh is None else {"mesh": ctx.mesh}
-            return twin(pj, jnp.asarray(rho_cur, jnp.float32),
-                        self.tax_frac, reg_scale, st, steps=steps,
-                        outer=self.outer, use_kernel=use_kernel,
-                        shift=shift_, reset_mu=reset_, **kw)
+            with span("dr.solve.round"):
+                D, pens, st = _dispatch(
+                    twin, pj, jnp.asarray(rho_cur, jnp.float32),
+                    self.tax_frac, reg_scale, st, steps=steps,
+                    outer=self.outer, use_kernel=use_kernel, shift=shift_,
+                    reset_mu=reset_, **kw)
+                D, pens = _fetch(D, pens)
+            return D[:W], pens[:W], st
 
         def paid_of(D):
             return rho_cur * _region_totals(region, (D * wmci).sum(1), R)
 
         D, pens, state = best_response(state, ctx.shift, ctx.reset_mu)
-        D, pens = np.asarray(D)[:W], np.asarray(pens)[:W]
         rounds = 1
         paid = paid_of(D)
         for _ in range(self.clearing_iters):
@@ -1509,8 +1554,8 @@ class CR3:
                 rho_cur)
             Dn, pensn, staten = best_response(state, 0, True)
             row = active[region]
-            D = np.where(row[:, None], np.asarray(Dn)[:W], D)
-            pens = np.where(row, np.asarray(pensn)[:W], pens)
+            D = np.where(row[:, None], Dn, D)
+            pens = np.where(row, pensn, pens)
             # μ is reset every round so it is round-count independent;
             # lam_eq is empty for CR3 — only x and the allowance
             # multipliers need per-row freezing.
@@ -1944,6 +1989,16 @@ def solve_day(problem: FleetProblem, policy, mci_stack, *,
     """
     ctx = ctx or SolveContext()
     policy = resolve_policy(policy)
+    with span("dr.solve", meta={"policy": policy.name,
+                                "lane": "day" if ctx.mesh is None
+                                else "day_mesh"}):
+        return _solve_day(problem, policy, mci_stack, ctx, cold_steps,
+                          warm_steps)
+
+
+def _solve_day(problem: FleetProblem, policy, mci_stack,
+               ctx: SolveContext, cold_steps: int | None,
+               warm_steps: int | None) -> DayResult:
     if ctx.sanitize:
         # Day scans have checkify twins (`_day_cr1_checked` /
         # `_day_cr2_checked`) on the solo lane; the same CR1/CR2 +
@@ -1998,8 +2053,9 @@ def solve_day(problem: FleetProblem, policy, mci_stack, *,
         if isinstance(policy, CR1):
             run = _day_cr1_sharded_donated if ctx.donate \
                 else _day_cr1_sharded
-            committed, D, pens, state = run(
-                pp, policy.lam, stack, norms_stack, state0, mesh=ctx.mesh,
+            committed, D, pens, state = _dispatch(
+                run, pp, policy.lam, stack, norms_stack, state0,
+                mesh=ctx.mesh,
                 warm_steps=warm_steps, first_steps=first_steps,
                 first_shift=first_shift, first_reset=first_reset,
                 use_kernel=use_kernel, moment_dtype=ctx.moment_dtype)
@@ -2007,15 +2063,15 @@ def solve_day(problem: FleetProblem, policy, mci_stack, *,
         else:
             run = _day_cr2_sharded_donated if ctx.donate \
                 else _day_cr2_sharded
-            committed, D, pens, state = run(
-                pp, policy.cap_frac, stack, norms_stack, state0,
+            committed, D, pens, state = _dispatch(
+                run, pp, policy.cap_frac, stack, norms_stack, state0,
                 mesh=ctx.mesh, warm_steps=warm_steps,
                 first_steps=first_steps, first_shift=first_shift,
                 first_reset=first_reset, outer=policy.outer,
                 use_kernel=use_kernel, moment_dtype=ctx.moment_dtype)
             mult = policy.outer
-        committed = np.asarray(committed)[:, :W]
-        D, pens = np.asarray(D)[:W], np.asarray(pens)[:W]
+        D, pens, committed = _fetch(D, pens, committed)
+        committed, D, pens = committed[:, :W], D[:W], pens[:W]
     else:
         pj = _jit_view(problem)
         W = problem.W
@@ -2023,8 +2079,9 @@ def solve_day(problem: FleetProblem, policy, mci_stack, *,
             state0 = ctx.warm if ctx.warm is not None else EngineState.cold(
                 jnp.zeros(problem.usage.shape))
             if ctx.sanitize:
-                err, out = _day_cr1_checked(
-                    pj, policy.lam, stack, state0, warm_steps=warm_steps,
+                err, out = _dispatch(
+                    _day_cr1_checked, pj, policy.lam, stack, state0,
+                    warm_steps=warm_steps,
                     first_steps=first_steps, first_shift=first_shift,
                     first_reset=first_reset, use_kernel=use_kernel,
                     moment_dtype=ctx.moment_dtype, sanitize=True,
@@ -2032,8 +2089,9 @@ def solve_day(problem: FleetProblem, policy, mci_stack, *,
                 err.throw()
             else:
                 run = _day_cr1_donated if ctx.donate else _day_cr1
-                out = run(
-                    pj, policy.lam, stack, state0, warm_steps=warm_steps,
+                out = _dispatch(
+                    run, pj, policy.lam, stack, state0,
+                    warm_steps=warm_steps,
                     first_steps=first_steps, first_shift=first_shift,
                     first_reset=first_reset, use_kernel=use_kernel,
                     moment_dtype=ctx.moment_dtype, telemetry_every=tel)
@@ -2043,8 +2101,8 @@ def solve_day(problem: FleetProblem, policy, mci_stack, *,
                 jnp.zeros(problem.usage.shape), n_eq=problem.W,
                 mu0=CR2_MU0)
             if ctx.sanitize:
-                err, out = _day_cr2_checked(
-                    pj, policy.cap_frac, stack, state0,
+                err, out = _dispatch(
+                    _day_cr2_checked, pj, policy.cap_frac, stack, state0,
                     warm_steps=warm_steps, first_steps=first_steps,
                     first_shift=first_shift, first_reset=first_reset,
                     outer=policy.outer, use_kernel=use_kernel,
@@ -2053,8 +2111,8 @@ def solve_day(problem: FleetProblem, policy, mci_stack, *,
                 err.throw()
             else:
                 run = _day_cr2_donated if ctx.donate else _day_cr2
-                out = run(
-                    pj, policy.cap_frac, stack, state0,
+                out = _dispatch(
+                    run, pj, policy.cap_frac, stack, state0,
                     warm_steps=warm_steps, first_steps=first_steps,
                     first_shift=first_shift, first_reset=first_reset,
                     outer=policy.outer, use_kernel=use_kernel,
@@ -2068,8 +2126,7 @@ def solve_day(problem: FleetProblem, policy, mci_stack, *,
             traces = (ConvergenceTrace.from_aux(out[4]),)
             if out[5] is not None:
                 traces += ConvergenceTrace.split(out[5])
-        committed = np.asarray(committed)
-        D, pens = np.asarray(D), np.asarray(pens)
+        D, pens, committed = _fetch(D, pens, committed)
     iters = (first_steps * mult,) + (warm_steps * mult,) * (n - 1)
     # Reporting view: the final tick's rolled window.
     p_last = dataclasses.replace(
@@ -2078,11 +2135,9 @@ def solve_day(problem: FleetProblem, policy, mci_stack, *,
         jobs=np.roll(np.asarray(problem.jobs), -(n - 1), axis=1),
         upper=None if problem.upper is None
         else np.roll(np.asarray(problem.upper), -(n - 1), axis=1))
-    last = _report(p_last, np.asarray(D), np.asarray(pens),
-                   iters=iters[-1], state=state,
+    last = _report(p_last, D, pens, iters=iters[-1], state=state,
                    extras={"telemetry": traces} if tel else None)
-    return DayResult(committed=np.asarray(committed), last=last,
-                     inner_steps=iters)
+    return DayResult(committed=committed, last=last, inner_steps=iters)
 
 
 @_register

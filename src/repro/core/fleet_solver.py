@@ -62,6 +62,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import features as feat
 from repro.core.engine import EngineState
 from repro.core.penalty import PenaltyModel
+from repro.obs.spans import span
 
 Array = jax.Array
 
@@ -612,25 +613,30 @@ def _projection(p: FleetProblem, lo: Array, hi: Array):
 def _report(p: FleetProblem, D: np.ndarray, pens: np.ndarray,
             iters: int, state: EngineState | None = None,
             extras: dict | None = None) -> FleetSolveResult:
-    mci = np.asarray(p.mci)
-    if mci.ndim == 2:
-        wmci = mci[np.asarray(p.region)]
-        carbon_base = float((np.asarray(p.usage) * wmci).sum())
-        car = float((D * wmci).sum())
-    else:
-        carbon_base = float((np.asarray(p.usage).sum(0) * mci).sum())
-        car = float((D @ mci).sum())
-    n_days = max(1, p.T // p.day_hours)
-    span = n_days * p.day_hours
-    sums = D[:, :span].reshape(p.W, n_days, p.day_hours).sum(-1)
-    is_batch = np.asarray(p.is_batch)
-    viol = float(np.abs(sums[is_batch]).max()) if is_batch.any() else 0.0
-    return FleetSolveResult(
-        D=D, carbon_reduction_pct=100 * car / carbon_base,
-        total_penalty_pct=100 * float(pens.sum())
-        / float(np.asarray(p.entitlement).sum()),
-        iters=iters, preservation_violation=viol, state=state,
-        extras=extras or {})
+    """The result of a solve from its plan `D` and penalties `pens`, both
+    on the host: carbon, penalty and preservation sums (`dr.solve.report`
+    span)."""
+    with span("dr.solve.report"):
+        mci = np.asarray(p.mci)
+        if mci.ndim == 2:
+            wmci = mci[np.asarray(p.region)]
+            carbon_base = float((np.asarray(p.usage) * wmci).sum())
+            car = float((D * wmci).sum())
+        else:
+            carbon_base = float((np.asarray(p.usage).sum(0) * mci).sum())
+            car = float((D @ mci).sum())
+        n_days = max(1, p.T // p.day_hours)
+        hours = n_days * p.day_hours
+        sums = D[:, :hours].reshape(p.W, n_days, p.day_hours).sum(-1)
+        is_batch = np.asarray(p.is_batch)
+        viol = float(np.abs(sums[is_batch]).max()) if is_batch.any() \
+            else 0.0
+        return FleetSolveResult(
+            D=D, carbon_reduction_pct=100 * car / carbon_base,
+            total_penalty_pct=100 * float(pens.sum())
+            / float(np.asarray(p.entitlement).sum()),
+            iters=iters, preservation_violation=viol, state=state,
+            extras=extras or {})
 
 
 # ---------------------------------------------------------------------------

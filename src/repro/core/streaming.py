@@ -44,7 +44,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
 from typing import Callable, Sequence
 
 import numpy as np
@@ -57,6 +56,7 @@ from repro.core.fleet_solver import (FleetProblem, FleetSolveResult,
                                      _single_region_view)
 from repro.core.regional import region_totals
 from repro.obs.events import EventWriter, TelemetryEvent, TickEvent
+from repro.obs.spans import SpanScope, span
 from repro.obs.telemetry import TelemetryConfig
 
 
@@ -187,8 +187,10 @@ class RollingHorizonSolver:
       events: JSONL tick ledger — a path or an open
         `repro.obs.EventWriter`. Every `step()`/`run_scanned()` tick
         appends a typed `TickEvent` (forecast-revision magnitude, warm
-        budget spent, solve latency, per-region committed/realized
-        carbon, migration credit, recompile + dispatch counts), and any
+        budget spent, tick latency, per-region committed/realized
+        carbon, migration credit, recompile + dispatch counts, the self
+        time of each phase span and the bytes uploaded and pulled —
+        read from the tick's `dr.tick` span), and any
         in-solve convergence samples append as `TelemetryEvent`s. All
         emission is host-side AFTER the solve returns, so the
         one-dispatch contracts (warm tick, scanned day) are untouched;
@@ -360,10 +362,13 @@ class RollingHorizonSolver:
         return recompile_guard(None, label="tick ledger")
 
     def _emit_tick(self, out: TickResult, *, revision: float,
-                   latency_s: float, recompiles: int, dispatches: int,
-                   cold: bool, objective_proxy: float | None) -> None:
+                   scope: SpanScope | None, recompiles: int, cold: bool,
+                   objective_proxy: float | None) -> None:
         """Append one `TickEvent` (host-side, after the solve returned —
-        never inside the dispatch)."""
+        never inside the dispatch). `scope` is the tick's open `dr.tick`
+        span, which gives the latency so far, the phases closed under
+        it, and the dispatches and bytes they counted; None for a tick
+        that rode an earlier tick's dispatch."""
         if self.events is None:
             return
         if out.committed_by_region is not None:
@@ -379,13 +384,20 @@ class RollingHorizonSolver:
         credit = 0.0
         if plan is not None and "migration" in plan.extras:
             credit = float(plan.extras["migration"].net_saved)
+        if scope is None:
+            latency_s, phases, counts = 0.0, None, {}
+        else:
+            latency_s, phases = scope.elapsed_s, scope.phases_ms
+            counts = scope.totals
         self.events.write(TickEvent(
             tick=out.tick, revision=float(revision),
             warm_steps=int(out.inner_steps), cold=bool(cold),
             objective_proxy=objective_proxy, latency_s=float(latency_s),
             committed_carbon=committed, realized_carbon=realized,
             migration_credit=credit, recompiles=int(recompiles),
-            dispatches=int(dispatches)))
+            dispatches=int(counts.get("dispatches", 0)), phases_ms=phases,
+            h2d_bytes=int(counts.get("h2d_bytes", 0)),
+            d2h_bytes=int(counts.get("d2h_bytes", 0))))
         if plan is not None and self.telemetry is not None:
             trace = plan.extras.get("telemetry")
             if trace is not None and not isinstance(trace, tuple):
@@ -399,45 +411,59 @@ class RollingHorizonSolver:
             self.events.write(TelemetryEvent(tick=tick, **s))
 
     def step(self) -> TickResult:
-        """Ingest the next forecast revision, re-solve, commit hour 0."""
+        """Ingest the next forecast revision, re-solve, commit hour 0.
+
+        The tick runs under a `dr.tick` span (`repro.obs.spans`) whose
+        phases are `dr.tick.forecast`, `dr.tick.window`, `dr.solve`
+        (opened by `api.solve`), `dr.tick.commit` and, with `events`
+        set, `dr.tick.ledger`."""
         tick = self._tick
-        mci_hat = self._forecast(tick)
-        p_t = self._window_problem(tick, mci_hat)
         warm = self._state
-        rev = _rel_revision(self._prev_forecast, mci_hat)
-        # Warm ticks shift the plan one hour and restart the mu schedule at
-        # the policy's mu0 — without the reset, mu compounds by
-        # mu_growth^outer per tick and CR2/CR3's walls turn stiff within a
-        # handful of ticks (multipliers still carry the constraint prices).
-        # Both happen *inside* the solve's jitted call, so a tick is one
-        # XLA dispatch (donated when self.donate).
-        steps = self.cold_steps if warm is None else self._warm_budget(rev)
-        t0 = time.perf_counter()
-        with self._traceguard(("tick", steps, warm is not None)), \
-                self._measure() as stats:
-            plan = self._solve(p_t, warm, steps,
-                               shift=0 if warm is None else 1,
-                               reset_mu=warm is not None)
-        latency = time.perf_counter() - t0
-        self._state = plan.state
-        self._prev_forecast = mci_hat
-        self._tick = tick + 1
-        committed = np.asarray(plan.D[:, 0])
-        out = TickResult(
-            tick=tick, committed=committed,
-            forecast_mci=(float(mci_hat[0]) if mci_hat.ndim == 1
-                          else mci_hat[:, 0].copy()),
-            realized_mci=self._realized(tick),
-            inner_steps=plan.iters, plan=plan,
-            committed_by_region=self._by_region(committed))
-        self._emit_tick(out, revision=rev, latency_s=latency,
+        with span("dr.tick", tick=tick,
+                  meta={"cold": warm is None}) as tk:
+            with span("dr.tick.forecast"):
+                mci_hat = self._forecast(tick)
+                rev = _rel_revision(self._prev_forecast, mci_hat)
+            with span("dr.tick.window"):
+                p_t = self._window_problem(tick, mci_hat)
+            # Warm ticks shift the plan one hour and restart the mu
+            # schedule at the policy's mu0 — without the reset, mu
+            # compounds by mu_growth^outer per tick and CR2/CR3's walls
+            # turn stiff within a handful of ticks (multipliers still
+            # carry the constraint prices). Both happen *inside* the
+            # solve's jitted call, so a tick is one XLA dispatch (donated
+            # when self.donate).
+            steps = self.cold_steps if warm is None \
+                else self._warm_budget(rev)
+            tk.meta["steps"] = steps
+            with self._traceguard(("tick", steps, warm is not None)), \
+                    self._measure() as stats:
+                plan = self._solve(p_t, warm, steps,
+                                   shift=0 if warm is None else 1,
+                                   reset_mu=warm is not None)
+            with span("dr.tick.commit"):
+                self._state = plan.state
+                self._prev_forecast = mci_hat
+                self._tick = tick + 1
+                committed = np.asarray(plan.D[:, 0])
+                out = TickResult(
+                    tick=tick, committed=committed,
+                    forecast_mci=(float(mci_hat[0]) if mci_hat.ndim == 1
+                                  else mci_hat[:, 0].copy()),
+                    realized_mci=self._realized(tick),
+                    inner_steps=plan.iters, plan=plan,
+                    committed_by_region=self._by_region(committed))
+                if self._history:   # bound memory: full plans live on
+                    self._history[-1] = dataclasses.replace(  # the latest
+                        self._history[-1], plan=None)        # tick only
+                self._history.append(out)
+            if self.events is not None:
+                with span("dr.tick.ledger"):
+                    self._emit_tick(
+                        out, revision=rev, scope=tk,
                         recompiles=stats.traces if stats else 0,
-                        dispatches=1, cold=warm is None,
+                        cold=warm is None,
                         objective_proxy=float(plan.carbon_reduction_pct))
-        if self._history:   # bound memory: full plans live on the
-            self._history[-1] = dataclasses.replace(   # latest tick only
-                self._history[-1], plan=None)
-        self._history.append(out)
         return out
 
     def run(self, n_ticks: int | None = None,
@@ -471,6 +497,9 @@ class RollingHorizonSolver:
         static jit argument chosen from the revision magnitude at run
         time, which a fixed scan cannot express — use flat
         `warm_steps` here.
+
+        The whole day runs under one `dr.tick` span (tagged with its
+        first tick), with the same phases as `step`.
         """
         if self.adaptive_warm:
             raise ValueError(
@@ -483,61 +512,69 @@ class RollingHorizonSolver:
         if n <= 0:
             raise ValueError(f"n_ticks must be >= 1, got {n}")
         from repro.core.api import solve_day
-        mci_stack = np.stack([self._forecast(t0 + i) for i in range(n)])
-        p_win = self._window_problem(t0, mci_stack[0])
         was_cold = self._state is None
-        # Per-tick revision magnitudes, walked over the stack before
-        # _prev_forecast advances to the final horizon.
-        prev = self._prev_forecast
-        revs = []
-        for i in range(n):
-            revs.append(_rel_revision(prev, mci_stack[i]))
-            prev = mci_stack[i]
-        ctx = SolveContext(mesh=self.mesh, donate=self.donate,
-                           warm=self._state,
-                           use_kernel=self.use_kernel, shift=1,
-                           reset_mu=self._state is not None,
-                           telemetry=self.telemetry)
-        t_start = time.perf_counter()
-        with self._traceguard(("day", n, self._state is not None)), \
-                self._measure() as stats:
-            day = solve_day(p_win, self.policy, mci_stack, ctx=ctx,
-                            cold_steps=self.cold_steps,
-                            warm_steps=self.warm_steps)
-        latency = time.perf_counter() - t_start
-        self._state = day.last.state
-        self._prev_forecast = mci_stack[-1]
-        self._tick = t0 + n
-        outs = [TickResult(
-            tick=t0 + i, committed=day.committed[i],
-            forecast_mci=(float(mci_stack[i][0])
-                          if mci_stack[i].ndim == 1
-                          else mci_stack[i][:, 0].copy()),
-            realized_mci=self._realized(t0 + i),
-            inner_steps=day.inner_steps[i],
-            plan=day.last if i == n - 1 else None,
-            committed_by_region=self._by_region(day.committed[i]))
-            for i in range(n)]
-        if self.events is not None:
-            # One dispatch covered the whole day: latency, traces and
-            # the dispatch count land on tick 0, the objective proxy on
-            # the last tick (the only per-plan metric the scan keeps).
-            traces = day.last.extras.get("telemetry", ())
-            for i, out in enumerate(outs):
-                self._emit_tick(
-                    out, revision=revs[i],
-                    latency_s=latency if i == 0 else 0.0,
-                    recompiles=stats.traces if stats and i == 0 else 0,
-                    dispatches=1 if i == 0 else 0,
-                    cold=i == 0 and was_cold,
-                    objective_proxy=(float(day.last.carbon_reduction_pct)
-                                     if i == n - 1 else None))
-                if i < len(traces):
-                    self._emit_trace(out.tick, traces[i])
-        if self._history:   # same memory bound as step()
-            self._history[-1] = dataclasses.replace(
-                self._history[-1], plan=None)
-        self._history.extend(outs)
+        with span("dr.tick", tick=t0,
+                  meta={"cold": was_cold, "steps": self.warm_steps,
+                        "ticks": n}) as tk:
+            with span("dr.tick.forecast"):
+                mci_stack = np.stack([self._forecast(t0 + i)
+                                      for i in range(n)])
+                # Per-tick revision magnitudes, walked over the stack
+                # before _prev_forecast advances to the final horizon.
+                prev = self._prev_forecast
+                revs = []
+                for i in range(n):
+                    revs.append(_rel_revision(prev, mci_stack[i]))
+                    prev = mci_stack[i]
+            with span("dr.tick.window"):
+                p_win = self._window_problem(t0, mci_stack[0])
+            ctx = SolveContext(mesh=self.mesh, donate=self.donate,
+                               warm=self._state,
+                               use_kernel=self.use_kernel, shift=1,
+                               reset_mu=self._state is not None,
+                               telemetry=self.telemetry)
+            with self._traceguard(("day", n, self._state is not None)), \
+                    self._measure() as stats:
+                day = solve_day(p_win, self.policy, mci_stack, ctx=ctx,
+                                cold_steps=self.cold_steps,
+                                warm_steps=self.warm_steps)
+            with span("dr.tick.commit"):
+                self._state = day.last.state
+                self._prev_forecast = mci_stack[-1]
+                self._tick = t0 + n
+                outs = [TickResult(
+                    tick=t0 + i, committed=day.committed[i],
+                    forecast_mci=(float(mci_stack[i][0])
+                                  if mci_stack[i].ndim == 1
+                                  else mci_stack[i][:, 0].copy()),
+                    realized_mci=self._realized(t0 + i),
+                    inner_steps=day.inner_steps[i],
+                    plan=day.last if i == n - 1 else None,
+                    committed_by_region=self._by_region(day.committed[i]))
+                    for i in range(n)]
+                if self._history:   # same memory bound as step()
+                    self._history[-1] = dataclasses.replace(
+                        self._history[-1], plan=None)
+                self._history.extend(outs)
+            if self.events is not None:
+                with span("dr.tick.ledger"):
+                    # One dispatch covered the whole day: latency, phases,
+                    # traces and the counted dispatches and bytes land on
+                    # tick 0, the objective proxy on the last tick (the
+                    # only per-plan metric the scan keeps).
+                    traces = day.last.extras.get("telemetry", ())
+                    for i, out in enumerate(outs):
+                        self._emit_tick(
+                            out, revision=revs[i],
+                            scope=tk if i == 0 else None,
+                            recompiles=stats.traces if stats and i == 0
+                            else 0,
+                            cold=i == 0 and was_cold,
+                            objective_proxy=(
+                                float(day.last.carbon_reduction_pct)
+                                if i == n - 1 else None))
+                        if i < len(traces):
+                            self._emit_trace(out.tick, traces[i])
         return self.report()
 
     def report(self) -> StreamingReport:

@@ -26,7 +26,7 @@ from typing import Any, ClassVar, Optional
 __all__ = ["SCHEMA_VERSION", "host_meta", "TickEvent", "SpanEvent",
            "TelemetryEvent", "EventWriter", "read_events"]
 
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
 
 
 def host_meta() -> dict:
@@ -62,22 +62,31 @@ class TickEvent:
     warm_steps: int              # inner-step budget actually spent
     cold: bool                   # True on the cold (tick-0 / reset) solve
     objective_proxy: Optional[float]  # carbon_reduction_pct of the plan
-    latency_s: float             # wall-clock of the solve (0.0 when the
-                                 # tick rode a day-scan's single dispatch)
+    latency_s: float             # wall clock of the `dr.tick` span up to
+                                 # this record (0.0 on the later ticks
+                                 # of a day scan's single dispatch)
     committed_carbon: list       # per-region kgCO2 committed this tick
     realized_carbon: list        # per-region kgCO2 at realized MCI
     migration_credit: float      # net kgCO2 saved by cross-region moves
     recompiles: int              # jit traces attributed to this tick
-    dispatches: int              # device dispatches attributed to it
+    dispatches: int              # device dispatches counted in the tick
+    phases_ms: Optional[dict] = None  # self time of each span under
+                                      # `dr.tick`, by name
+    h2d_bytes: int = 0           # host bytes passed into the dispatches
+    d2h_bytes: int = 0           # bytes pulled back to the host
 
 
 @dataclasses.dataclass(frozen=True)
 class SpanEvent:
-    """One timed span (compute-synchronized; see `obs.spans.span`)."""
+    """One timed span (see `obs.spans.span`)."""
     kind: ClassVar[str] = "span"
     name: str
     elapsed_s: float
     meta: Optional[dict] = None
+    start_ns: Optional[int] = None    # time.perf_counter_ns() at entry
+    parent: Optional[int] = None      # id of the enclosing span
+    tick: Optional[int] = None        # tick index the span belongs to
+    counts: Optional[dict] = None     # e.g. {"h2d_bytes": n}
 
 
 @dataclasses.dataclass(frozen=True)

@@ -7,8 +7,9 @@ Sections (each rendered only when the ledger has matching events):
   * header      — schema, host fingerprint, tags
   * convergence — per-tick ASCII curves of the in-solve telemetry
                   (objective + grad norm sparklines, final violation)
-  * tick ledger — revision / budget / latency / carbon table with
-                  totals, committed vs realized drift, migration credit
+  * tick ledger — revision / budget / latency / bytes / carbon table
+                  with totals, committed vs realized drift, migration
+                  credit, and the self time of each phase span per tick
   * spans       — per-name count / total / mean wall time
   * recompiles  — dispatch + trace audit: which ticks compiled, which
                   rode the warm cache
@@ -20,6 +21,7 @@ lands.
 from __future__ import annotations
 
 import argparse
+import statistics
 import sys
 from collections import defaultdict
 
@@ -107,8 +109,8 @@ def _render_ticks(out, ticks: list[dict]) -> None:
     ticks = sorted(ticks, key=lambda e: e["tick"])
     out.append("")
     out.append(f"== tick ledger ({len(ticks)} ticks) ==")
-    out.append("  tick  mode  steps  revision  latency_s  committed  "
-               "realized  credit  recompiles")
+    out.append("  tick  mode  steps  revision  latency_s  up_MB  pull_MB"
+               "  committed  realized  credit  recompiles")
     tot_c = tot_r = tot_m = 0.0
     for ev in ticks:
         c = sum(ev.get("committed_carbon") or [0.0])
@@ -120,6 +122,8 @@ def _render_ticks(out, ticks: list[dict]) -> None:
             f"  {ev.get('warm_steps', 0):>5d}"
             f"  {_fmt(ev.get('revision'), 3):>8s}"
             f"  {_fmt(ev.get('latency_s'), 3):>9s}"
+            f"  {_fmt(ev.get('h2d_bytes', 0) * 1e-6, 2):>5s}"
+            f"  {_fmt(ev.get('d2h_bytes', 0) * 1e-6, 2):>7s}"
             f"  {_fmt(c, 1):>9s}  {_fmt(r, 1):>8s}"
             f"  {_fmt(m, 1):>6s}  {ev.get('recompiles', 0):>10d}")
     out.append(f"  total committed {_fmt(tot_c, 1)} kgCO2, realized "
@@ -133,6 +137,27 @@ def _render_ticks(out, ticks: list[dict]) -> None:
         out.append("  realized by region: "
                    + ", ".join(f"r{i}={_fmt(v, 1)}"
                                for i, v in enumerate(per)))
+    _render_phases(out, ticks)
+
+
+def _render_phases(out, ticks: list[dict]) -> None:
+    """Self time of each span under `dr.tick`, per tick: median, max and
+    the tick where the max fell (where a stalled tick spent its time)."""
+    by = defaultdict(list)
+    for ev in ticks:
+        for name, ms in (ev.get("phases_ms") or {}).items():
+            by[name].append((float(ms), ev["tick"]))
+    if not by:
+        return
+    out.append("  phase self time per tick (ms):")
+    out.append("    name                        n     median        max"
+               "  at tick")
+    for name in sorted(by, key=lambda n: -sum(v for v, _ in by[n])):
+        top, at = max(by[name])
+        mid = statistics.median(v for v, _ in by[name])
+        out.append(f"    {name:<26s} {len(by[name]):>3d}"
+                   f"  {_fmt(mid, 3):>9s}"
+                   f"  {_fmt(top, 3):>9s}  {at:>7d}")
 
 
 def _render_spans(out, spans: list[dict]) -> None:

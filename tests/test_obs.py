@@ -2,10 +2,18 @@
 bitwise-identical to pre-PR, telemetry-on plans bitwise-identical to
 telemetry-off), the trace surfaces through `solve`/`sweep`/`ensemble`/
 `solve_day`, the streaming tick ledger (one-dispatch contract intact
-with the ledger enabled), the JSONL schema pin, span timing, and the
-report CLI round trip."""
+with the ledger enabled), the JSONL schema pin, span timing, the span
+recorder (nesting, the bounded ring, profiler trace names) and the
+program's tick spans and byte counters, and the report CLI round
+trip."""
 import dataclasses
+import glob
 import json
+import os
+import pathlib
+import subprocess
+import sys
+import warnings
 
 import numpy as np
 import pytest
@@ -15,7 +23,7 @@ from repro.core.api import (CR1, CR2, CR3, SolveContext, solve, solve_day,
 from repro.core.fleet_solver import synthetic_fleet
 from repro.obs import (SCHEMA_VERSION, ConvergenceTrace, EventWriter,
                        SpanEvent, TelemetryConfig, TickEvent, host_meta,
-                       read_events, span)
+                       read_events, span, spans)
 from repro.obs.report import main as report_main
 
 from conftest import run_in_subprocess
@@ -299,3 +307,255 @@ def test_tick_event_dataclass_round_trip(tmp_path):
     rec = read_events(path)[1]
     assert rec["kind"] == "tick" and rec["tick"] == 3
     assert rec["committed_carbon"] == [1.0, 2.0]
+
+
+# ---------------------------------------------------------------------------
+# Span recorder: nesting, the ring, the profiler trace
+# ---------------------------------------------------------------------------
+def test_spans_nest_with_parent_and_tick_ids():
+    spans.clear()
+    with span("outer", tick=7) as outer:
+        with span("mid") as mid:
+            mid.count("h2d_bytes", 100)
+            with span("leaf") as leaf:
+                leaf.count("h2d_bytes", 20)
+                leaf.count("dispatches", 1)
+        with span("other", tick=9):
+            pass
+    recs = {r.name: r for r in spans.recorded()}
+    # appended at exit: children before their parent
+    assert [r.name for r in spans.recorded()] == ["leaf", "mid", "other",
+                                                  "outer"]
+    assert recs["outer"].parent is None and recs["outer"].tick == 7
+    assert recs["mid"].parent == outer.id == recs["outer"].id
+    assert recs["leaf"].parent == mid.id and recs["leaf"].tick == 7
+    assert recs["other"].tick == 9          # an explicit tick wins
+    assert len({r.id for r in recs.values()}) == 4
+    for r in recs.values():
+        assert r.end_ns >= r.start_ns
+    assert recs["outer"].start_ns <= recs["mid"].start_ns \
+        <= recs["leaf"].start_ns <= recs["leaf"].end_ns \
+        <= recs["mid"].end_ns <= recs["outer"].end_ns
+    # own counts on the record; the rollup on the live parent
+    assert recs["mid"].counts == {"h2d_bytes": 100}
+    assert recs["leaf"].counts == {"h2d_bytes": 20, "dispatches": 1}
+    assert outer.totals == {"h2d_bytes": 120, "dispatches": 1}
+    assert outer.counts == {}
+    # self times by name; together with the parent's own time they make
+    # up its whole span
+    ph = outer.phases_ns
+    assert set(ph) == {"mid", "leaf", "other"}
+    assert ph["mid"] == recs["mid"].elapsed_ns - recs["leaf"].elapsed_ns
+    own = outer.elapsed_s * 1e9 - recs["mid"].elapsed_ns \
+        - recs["other"].elapsed_ns
+    assert sum(ph.values()) + own == pytest.approx(
+        recs["outer"].elapsed_ns, abs=1)
+    spans.clear()
+    assert spans.recorded() == []
+
+
+def test_span_ring_is_bounded():
+    spans.clear()
+    n = spans.RING_SIZE + 25
+    for i in range(n):
+        with span("s", tick=i):
+            pass
+    recs = spans.recorded()
+    assert len(recs) == spans.RING_SIZE >= 400 * 12
+    assert recs[0].tick == 25 and recs[-1].tick == n - 1   # oldest gone
+    spans.clear()
+
+
+def test_span_records_on_exception_and_pops_the_stack():
+    spans.clear()
+    with pytest.raises(RuntimeError):
+        with span("fails"):
+            raise RuntimeError("boom")
+    with span("after"):
+        pass
+    a, b = spans.recorded()
+    assert a.name == "fails" and b.name == "after"
+    assert b.parent is None                  # "fails" left the stack
+
+
+def test_span_names_reach_the_profiler_trace(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        with span("dr.test.outer"):
+            with span("dr.test.inner"):
+                jnp.ones(8).block_until_ready()
+    files = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    assert files
+    pd = ProfileData.from_file(files[-1])
+    names = {e.name for plane in pd.planes for line in plane.lines
+             for e in line.events}
+    assert {"dr.test.outer", "dr.test.inner"} <= names
+
+
+# ---------------------------------------------------------------------------
+# The program's spans on the tick path
+# ---------------------------------------------------------------------------
+TICK_PHASES = ["dr.tick.forecast", "dr.tick.window", "dr.solve",
+               "dr.tick.commit"]
+SOLVE_PHASES = ["dr.solve.dispatch", "dr.solve.wait", "dr.solve.pull",
+                "dr.solve.report"]
+
+
+def _children(recs, parent):
+    return [r for r in recs if r.parent == parent.id]
+
+
+def _host_bytes(p) -> int:
+    """What a solve uploads: every array of the fleet in float32 (x64
+    off), the bool column as bytes, and the float λ."""
+    arrays = [p.usage, p.jobs, p.entitlement, p.k, p.rts_coeffs, p.betas,
+              p.x2_kind, p.mci]
+    return (sum(np.asarray(a).size * 4 for a in arrays)
+            + np.asarray(p.is_batch).size + 4)
+
+
+@pytest.fixture(scope="module")
+def cr1_stream(fp, tmp_path_factory):
+    """A 3-tick CR1 stream with the ledger on, and the spans it left."""
+    from repro.core.carbon import ForecastStream
+    from repro.core.streaming import RollingHorizonSolver
+
+    path = tmp_path_factory.mktemp("ledger") / "run.jsonl"
+    stream = ForecastStream.caiso(n_ticks=3, horizon=fp.T, seed=1)
+    solver = RollingHorizonSolver(fp, stream, policy="cr1", cold_steps=60,
+                                  warm_steps=15, events=str(path))
+    spans.clear()
+    report = solver.run(3)
+    return report, spans.recorded(), read_events(path)
+
+
+def test_cr1_stream_opens_one_tick_span_per_tick(cr1_stream):
+    _, recs, _ = cr1_stream
+    ticks = [r for r in recs if r.name == "dr.tick"]
+    assert [t.tick for t in ticks] == [0, 1, 2]
+    assert all(t.parent is None for t in ticks)
+    assert [t.meta["cold"] for t in ticks] == [True, False, False]
+    assert [t.meta["steps"] for t in ticks] == [60, 15, 15]
+    for t in ticks:
+        kids = _children(recs, t)
+        assert [k.name for k in kids] == TICK_PHASES + ["dr.tick.ledger"]
+        assert all(k.tick == t.tick for k in kids)
+        (solve_span,) = [k for k in kids if k.name == "dr.solve"]
+        assert solve_span.meta == {"policy": "cr1", "lane": "solo"}
+        assert [k.name for k in _children(recs, solve_span)] == \
+            SOLVE_PHASES
+
+
+def test_cr1_stream_counts_bytes_as_the_arrays_nbytes(fp, cr1_stream):
+    report, recs, _ = cr1_stream
+    up = [r.counts["h2d_bytes"] for r in recs
+          if r.name == "dr.solve.dispatch"]
+    # every tick uploads the whole windowed fleet; the warm state is
+    # already on the device
+    assert up == [_host_bytes(fp)] * 3
+    pulls = [r.counts["d2h_bytes"] for r in recs
+             if r.name == "dr.solve.pull"]
+    D = report.ticks[-1].plan.D
+    assert D.dtype == np.float32
+    assert pulls == [D.nbytes + fp.W * 4] * 3       # the plan and pens
+    assert [r.counts["dispatches"] for r in recs
+            if r.name == "dr.solve.dispatch"] == [1, 1, 1]
+
+
+def test_phases_cover_the_tick(cr1_stream):
+    _, recs, _ = cr1_stream
+    for t in (r for r in recs if r.name == "dr.tick"):
+        covered = sum(k.elapsed_ns for k in _children(recs, t))
+        assert covered >= 0.9 * t.elapsed_ns, (t.tick, covered,
+                                               t.elapsed_ns)
+
+
+def test_tick_event_carries_counted_dispatches_phases_and_bytes(
+        fp, cr1_stream):
+    _, recs, events = cr1_stream
+    ticks = [e for e in events if e["kind"] == "tick"]
+    spans_by_tick = {r.tick: r for r in recs if r.name == "dr.tick"}
+    for ev in ticks:
+        assert ev["dispatches"] == 1
+        assert ev["h2d_bytes"] == _host_bytes(fp)
+        assert ev["d2h_bytes"] == fp.W * fp.T * 4 + fp.W * 4
+        assert set(ev["phases_ms"]) == set(TICK_PHASES + SOLVE_PHASES)
+        # the latency is the tick span up to the ledger write
+        span_s = spans_by_tick[ev["tick"]].elapsed_ns * 1e-9
+        assert 0 < ev["latency_s"] <= span_s
+        assert sum(ev["phases_ms"].values()) * 1e-3 <= ev["latency_s"]
+
+
+def test_report_renders_phases_and_bytes(cr1_stream, tmp_path, capsys):
+    _, _, events = cr1_stream
+    path = tmp_path / "copy.jsonl"
+    path.write_text("".join(json.dumps(e) + "\n" for e in events))
+    assert report_main([str(path)]) == 0
+    out = capsys.readouterr().out
+    assert "up_MB" in out and "pull_MB" in out
+    assert "phase self time per tick (ms)" in out
+    for name in TICK_PHASES + SOLVE_PHASES:
+        assert name in out
+
+
+def test_cr3_tick_counts_every_clearing_round(tmp_path):
+    """A CR3 tick that clears in several rounds: one `dr.solve.round`
+    per round, each with its own dispatch, wait and pull, and the tick
+    ledger counts them all."""
+    from repro.core.carbon import ForecastStream
+    from repro.core.fleet_solver import synthetic_fleet
+    from repro.core.streaming import RollingHorizonSolver
+
+    p = synthetic_fleet(4)
+    # entitlements under peak usage and a huge ρ: rebates outrun taxes,
+    # so every clearing iteration runs (the clearing stays unbalanced)
+    tight = dataclasses.replace(p, entitlement=0.6 * p.usage.max(axis=1))
+    stream = ForecastStream.caiso(n_ticks=1, horizon=tight.T, seed=1)
+    policy = CR3(rho=1e4, tax_frac=0.1, outer=2, clearing_iters=2)
+    path = tmp_path / "cr3.jsonl"
+    spans.clear()
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        solver = RollingHorizonSolver(tight, stream, policy=policy,
+                                      cold_steps=40, warm_steps=10,
+                                      events=str(path))
+        out = solver.step()
+    (ev,) = [e for e in read_events(path) if e["kind"] == "tick"]
+    rounds = out.inner_steps // (40 * policy.outer)
+    assert rounds == 3 == 1 + policy.clearing_iters
+    recs = spans.recorded()
+    round_spans = [r for r in recs if r.name == "dr.solve.round"]
+    assert len(round_spans) == rounds
+    for r in round_spans:
+        assert [k.name for k in _children(recs, r)] == SOLVE_PHASES[:3]
+    assert ev["dispatches"] == rounds
+    assert ev["h2d_bytes"] == sum(r.counts.get("h2d_bytes", 0)
+                                  for r in recs)
+    assert ev["d2h_bytes"] == rounds * (tight.W * tight.T + tight.W) * 4
+
+
+def test_solve_day_spans_one_dispatch(fp):
+    spans.clear()
+    base = np.asarray(fp.mci, float)
+    stack = np.stack([np.roll(base, -i) for i in range(3)])
+    solve_day(fp, CR1(lam=1.4), stack, cold_steps=30, warm_steps=10)
+    recs = spans.recorded()
+    (root,) = [r for r in recs if r.name == "dr.solve"]
+    assert root.meta == {"policy": "cr1", "lane": "day"}
+    assert [k.name for k in _children(recs, root)] == SOLVE_PHASES
+    (pull,) = [r for r in recs if r.name == "dr.solve.pull"]
+    # the plan and pens of the last tick, and the (n, W) commitments
+    assert pull.counts["d2h_bytes"] == (fp.W * fp.T + fp.W + 3 * fp.W) * 4
+
+
+def test_importing_obs_loads_neither_jax_nor_core():
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    code = ("import sys, repro.obs, repro.obs.report; "
+            "bad = [m for m in sys.modules if m == 'jax' "
+            "or m.startswith('repro.core')]; "
+            "assert not bad, bad")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120,
+                   env={**os.environ, "PYTHONPATH": str(src)})
