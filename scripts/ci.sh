@@ -120,7 +120,7 @@ from repro.core.carbon import ForecastStream
 from repro.core.fleet_solver import _bounds, synthetic_fleet
 from repro.core.streaming import RollingHorizonSolver
 from repro.kernels.al_step.kernel import al_step_pallas
-from repro.kernels.al_step.ops import pack_rows
+from repro.kernels.al_step.ops import kernel_layout, pack_rows, time_major
 from repro.kernels.al_step.ref import al_step_ref
 
 # kernel vs oracle, hinge-free rows (see kernels/al_step/ref.py)
@@ -140,7 +140,12 @@ cvec = rng.normal(-.5, .2, (1, p.T)).astype(np.float32)
 scal = np.array([[1.45, 10., 0., .02, 0., 0, 0, 0]], np.float32)
 args = [jnp.asarray(a) for a in
         (x, m, v, p.usage, p.jobs, lo, hi, rowp, cvec, scal)]
-out = al_step_pallas(*args, mode="cr1", k_steps=4, interpret=True)
+# the kernel on the time-major layout make_fused_inner gives it
+consts, bw, wp = kernel_layout(*args[3:9], block_w=None)
+out = al_step_pallas(*(time_major(a, wp) for a in args[:3]), *consts,
+                     args[9], mode="cr1", k_steps=4, block_w=bw,
+                     interpret=True)
+out = tuple(o[:, :8].T for o in out)
 ref = al_step_ref(*args, mode="cr1", k_steps=4)
 err = max(float(jnp.abs(o - r).max()) for o, r in zip(out, ref))
 assert err <= 1e-5, f"al_step kernel-vs-oracle err {err}"

@@ -667,10 +667,10 @@ def _al_fused_inner(p: FleetProblem, mode: str, cfg: EngineConfig, *,
     `regional.cr1_norms`/`cr2_norms`) by *folding* instead of changing
     the kernel's scalar slots: the carbon term becomes a (W, T) cvec
     over each row's region trace; CR1's per-row penalty weight
-    `lam·pen_w` folds into col-6 `k` (gradient is linear in k) with
+    `lam·pen_w` folds into row-6 `k` (gradient is linear in k) with
     `coef0 = 1`; CR2's per-row residual scale folds `1/scale_w` into
     both `k` and `refs` (h and coef·dpen are algebraically unchanged)
-    with `scale = 1`; the per-row step scale rides rowp col 11. The
+    with `scale = 1`; the per-row step scale rides rowp row 11. The
     kernel itself stays region-blind, and the single-region path packs
     the exact same arrays as before (bitwise-identical)."""
     from repro.kernels.al_step.ops import make_fused_inner, pack_rows

@@ -39,11 +39,12 @@ def _pos(x: Array, smooth: float = 0.0) -> Array:
     return jnp.maximum(x, 0.0)
 
 
-def backlog_active(cum: Array, rate: Array) -> Array:
-    """Whether a running backlog `cum` (a prefix sum of `rate` along the
-    last axis) is positive beyond rounding: `cum > QUEUE_RTOL·Σ|rate|`.
-    This is the hinge subgradient the kernels use."""
-    return cum > QUEUE_RTOL * jnp.abs(rate).sum(axis=-1, keepdims=True)
+def backlog_active(cum: Array, rate: Array, axis: int = -1) -> Array:
+    """Whether a running backlog `cum` (a prefix sum of `rate` along
+    `axis`, the time axis) is positive beyond rounding:
+    `cum > QUEUE_RTOL·Σ|rate|`. This is the hinge subgradient the kernels
+    use."""
+    return cum > QUEUE_RTOL * jnp.abs(rate).sum(axis=axis, keepdims=True)
 
 
 def backlog_pos(cum: Array, rate: Array) -> Array:

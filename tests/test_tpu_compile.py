@@ -7,7 +7,9 @@ overflow). The topology is described inside a module fixture, never at
 import, so every test worker collects the same tests and only the
 worker running this file loads the TPU library.
 
-Also pins the kernels' triangular-mask prefix sum against `jnp.cumsum`.
+Also pins the `dr_features` kernel's triangular-mask prefix sum against
+`jnp.cumsum` (the al_step kernel's time-major running sum is pinned in
+`tests/test_al_step.py`).
 """
 import os
 
@@ -18,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.al_step.kernel import al_step_pallas
+from repro.kernels.al_step.ops import kernel_layout, time_major
 from repro.kernels.al_step.ref import prefix_sum
 from repro.kernels.dr_features.kernel import dr_features_pallas
 
@@ -62,20 +65,46 @@ def test_dr_features_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("moments", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("mode", ["cr1", "cr2"])
-def test_al_step_compiles_for_v5e(one_chip, mode, moments):
-    s = _shape(one_chip, (W, T))
-    mv = _shape(one_chip, (W, T), moments)
+def _compile_al_step(one_chip, mode, moments, cvec_rows, hours=T):
+    """The time-major kernel on operands that `kernel_layout` lays out
+    from (W, hours) inputs, with a (`cvec_rows`, hours) carbon term."""
+    s = _shape(one_chip, (W, hours))
+    mv = _shape(one_chip, (W, hours), moments)
 
-    def step(x, m, v, u, j, lo, hi, rowp, cvec, scal):
-        return al_step_pallas(x, m, v, u, j, lo, hi, rowp, cvec, scal,
-                              mode=mode, k_steps=8, interpret=False)
+    def step(x, m, v, u, j, lo, hi, rows, cvec, scal):
+        consts, block_w, wp = kernel_layout(u, j, lo, hi, rows, cvec)
+        return al_step_pallas(*(time_major(a, wp) for a in (x, m, v)),
+                              *consts, scal, mode=mode, k_steps=8,
+                              block_w=block_w, interpret=False)
 
     hlo = jax.jit(step).lower(
         s, mv, mv, s, s, s, s, _shape(one_chip, (W, 12)),
-        _shape(one_chip, (1, T)), _shape(one_chip, (1, 8))).compile()
+        _shape(one_chip, (cvec_rows, hours)),
+        _shape(one_chip, (1, 8))).compile()
     assert "tpu_custom_call" in hlo.as_text()
+
+
+@pytest.mark.parametrize("moments", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("mode", ["cr1", "cr2"])
+def test_al_step_compiles_for_v5e(one_chip, mode, moments):
+    """The fleet-global (T, 1) carbon term of a single-region fleet."""
+    _compile_al_step(one_chip, mode, moments, 1)
+
+
+@pytest.mark.parametrize("moments", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("mode", ["cr1", "cr2"])
+def test_al_step_per_row_cvec_compiles_for_v5e(one_chip, mode, moments):
+    """The per-workload (T, Wp) carbon term of a multi-region fleet."""
+    _compile_al_step(one_chip, mode, moments, W)
+
+
+@pytest.mark.parametrize("cvec_rows", [1, W], ids=["fleet", "per_row"])
+@pytest.mark.parametrize("hours", [30, 47])
+def test_al_step_odd_horizon_compiles_for_v5e(one_chip, hours, cvec_rows):
+    """Horizons that are not whole 8-row vregs: 47 leaves the running
+    sums a 7-row last group, and 30 (a day tail) a 6-row one plus hours
+    past the last whole day."""
+    _compile_al_step(one_chip, "cr2", jnp.float32, cvec_rows, hours)
 
 
 def _fleet_at_scale(sharding):
