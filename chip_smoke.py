@@ -103,6 +103,77 @@ def streaming(p, use_kernel):
     return ticks, times, day, s_day
 
 
+STATES = ["CA", "WA", "AZ", "CO", "TX", "IL", "NY", "FL"]
+
+
+def cr3_market() -> None:
+    """CR3 streaming ticks (one cold, one warm) on the 8-region Cambium
+    fleet: every region's Eq.-6 market cleared in one on-device loop a
+    tick, kernel path against the jnp path."""
+    from repro.core.api import CR3
+    from repro.core.carbon import ForecastStream, regional_traces
+    from repro.core.fleet_solver import synthetic_regional_fleet
+    from repro.core.streaming import RollingHorizonSolver
+
+    p = synthetic_regional_fleet(W, STATES, hours=T, seed=0,
+                                 utc_offsets="auto")
+    actual, _ = regional_traces(STATES, 2050, hours=8 + T, seed=0,
+                                utc_offsets="auto")
+
+    def ticks(use_kernel):
+        rhs = RollingHorizonSolver(
+            p, ForecastStream.regional(actual, horizon=T, seed=0),
+            policy=CR3(), use_kernel=use_kernel)
+        return [(*timed(lambda: rhs.step().plan),
+                 rhs.cold_steps if i == 0 else rhs.warm_steps)
+                for i in range(2)]
+
+    kernel, jnp_path = ticks(None), ticks(False)
+    for i, ((a, s, steps), (b, s_j, _)) in enumerate(zip(kernel, jnp_path)):
+        label = f"CR3 market tick {i} ({'cold' if i == 0 else 'warm'})"
+        for path, r in (("kernel", a), ("jnp", b)):
+            log(f"{label} ({path}): rounds="
+                f"{r.iters // (steps * CR3().outer)} rho="
+                f"{np.round(r.extras['rho'], 6).tolist()} balanced="
+                f"{r.balanced} fiscal_deficit={r.fiscal_deficit:.6g}")
+        check_pair(label, a, b, KERNEL_TOL)
+        log(f"first chip reading (not a benchmark metric): {label} "
+            f"kernel_s={s:.3f} jnp_s={s_j:.3f}")
+    fiscal_sums(p, a)
+
+
+def fiscal_sums(p, r) -> None:
+    """How far the rebates the market's deficit test reads on the device
+    (`api._cr3_rebates`: double-float sums from float32 operations) sit
+    from exact float64 host sums of the same plan, in parts of each
+    region's taxes collected, beside plain float32 sums of the same."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    from repro.core import api, dfloat
+
+    order, offsets = api._region_layout(p.region, p.R)
+    region = np.asarray(p.region)
+    mci = np.asarray(p.mci, np.float64)
+    rho = np.asarray(r.extras["rho"], np.float64)
+    D = np.asarray(r.D, np.float32)
+    collected = api.CR3().tax_frac * api._cr3_taxes(p)
+    dev = jax.jit(api._cr3_rebates, static_argnums=(3, 4))(
+        D, dfloat.split(rho), dfloat.split(mci), order, offsets)
+    dev = np.asarray(dev[0], np.float64) + np.asarray(dev[1], np.float64)
+    f32 = np.asarray(jax.jit(lambda D, m: jnp.stack(
+        [(D[region == k] * m[k]).sum() for k in range(p.R)]))(
+            D, jnp.asarray(mci, jnp.float32)), np.float64) * rho
+    D64 = D.astype(np.float64)
+    want = rho * np.array([math.fsum((D64[region == k] * mci[k]).ravel())
+                           for k in range(p.R)])
+    log(f"CR3 rebates paid, device vs exact float64, in parts of "
+        f"collected_r: double-float "
+        f"{(np.abs(dev - want) / collected).max():.3e}, plain float32 "
+        f"{(np.abs(f32 - want) / collected).max():.3e}")
+
+
 def single_chip(jax) -> None:
     from repro.core.api import CR1, CR2, CR3, SolveContext, _cr1_run, solve
     from repro.core.engine import EngineState
@@ -229,6 +300,7 @@ def main() -> None:
         four_chips(jax)
     else:
         single_chip(jax)
+        cr3_market()
     log(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}))

@@ -16,8 +16,9 @@ policies — Efficient (CR1), Fair-Centralized (CR2), Fair-Decentralized
     the fleet-global normalizers, and the `EngineConfig` it feeds the
     shared projected-Adam + augmented-Lagrangian loop
     (`repro.core.engine.al_minimize`). CR3 additionally owns its Eq.-6
-    fiscal-clearing outer loop (the coordinator lowering the carbon
-    price ρ until taxes cover rebates).
+    fiscal clearing (the coordinator lowering each region's carbon
+    price ρ until taxes cover rebates), an on-device loop around its
+    best responses, one dispatch a solve.
 
   * `solve(problem, policy, ctx=SolveContext(...))` is the single entry
     point. `SolveContext` bundles everything orthogonal to policy
@@ -68,6 +69,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis.sanitize import checked_jit
+from repro.core import dfloat as _df
 from repro.core.engine import (EngineConfig, EngineState, al_minimize,
                                al_minimize_sharded)
 from repro.core.fleet_solver import (CR1_MU0, CR2_MU0, CR3_MU0,
@@ -83,11 +85,12 @@ from repro.core.regional import (CR1_NORM_FILLS, CR2_NORM_FILLS,
                                  cr3_reg_scale as _cr3_reg_scale,
                                  norm_specs as _norm_specs,
                                  pad_row_norms as _pad_row_norms,
-                                 region_sum as _rsum,
+                                 region_layout as _region_layout,
+                                 region_sums as _region_sums,
                                  region_totals as _region_totals)
 from repro.launch.mesh import fleet_axes, fleet_device_count
 # repro.obs is import-light and never imports repro.core (no cycle).
-from repro.obs.spans import span
+from repro.obs.spans import count as _count, span
 from repro.obs.telemetry import ConvergenceTrace, TelemetryConfig
 
 Array = jax.Array
@@ -1312,8 +1315,7 @@ def _cr3_cfg(steps: int, outer: int) -> EngineConfig:
 
 
 def _cr3_impl(p: FleetProblem, rho, tax_frac, reg_scale,
-              state0: EngineState, steps: int, outer: int, use_kernel: bool,
-              shift: int = 0, reset_mu: bool = False):
+              state0: EngineState, steps: int, outer: int, use_kernel: bool):
     """All W selfish problems in one AL solve. Each workload i minimizes its
     own penalty s.t. the peak-allowance inequality (Eq. 5/8)
 
@@ -1321,9 +1323,8 @@ def _cr3_impl(p: FleetProblem, rho, tax_frac, reg_scale,
 
     (smooth max as in `policies.cr3_workload_spec`). Objective, residual and
     projection are all row-separable, so this single (W, T) engine call IS
-    the vmapped per-workload best response — one XLA call per round.
+    the vmapped per-workload best response: one clearing round.
     """
-    state0 = _enter_tick(state0, shift, reset_mu, CR3_MU0)
     objective, ineq, project, step_scale, day_tangent = _cr3_pieces(
         p, use_kernel, reg_scale)
     D, aux = al_minimize(objective, project, state0.x,
@@ -1333,22 +1334,14 @@ def _cr3_impl(p: FleetProblem, rho, tax_frac, reg_scale,
     return D, fleet_penalties(p, D, use_kernel), aux["state"]
 
 
-_CR3_STATIC = ("steps", "outer", "use_kernel", "shift", "reset_mu")
-_cr3_best_response = jax.jit(_cr3_impl, static_argnames=_CR3_STATIC)
-_cr3_best_response_donated = jax.jit(_cr3_impl, static_argnames=_CR3_STATIC,
-                                     donate_argnums=(4,))
-
-
 def _cr3_impl_sharded(p: FleetProblem, rho, tax_frac, reg_scale,
                       state0: EngineState, mesh, steps: int, outer: int,
-                      use_kernel: bool, shift: int = 0,
-                      reset_mu: bool = False):
+                      use_kernel: bool):
     """Sharded best response: the allowance inequality, its multipliers and
     the per-row step scale all live with their rows; only ρ/tax/reg_scale
     are replicated (multi-region: ρ stays a replicated (R,) vector and
     reg_scale shards with its rows). The Eq.-6 fiscal sums live in
-    `CR3.solve`."""
-    state0 = _enter_tick(state0, shift, reset_mu, CR3_MU0)
+    `_cr3_market`, outside the shard_map."""
     axis = fleet_axes(mesh)
 
     def build(blk):
@@ -1367,26 +1360,126 @@ def _cr3_impl_sharded(p: FleetProblem, rho, tax_frac, reg_scale,
     return D, _sharded_penalties(p, D, mesh, use_kernel), aux["state"]
 
 
-_CR3_STATIC_SH = ("mesh", "steps", "outer", "use_kernel", "shift",
-                  "reset_mu")
-_cr3_sharded = jax.jit(_cr3_impl_sharded, static_argnames=_CR3_STATIC_SH)
-_cr3_sharded_donated = jax.jit(_cr3_impl_sharded,
-                               static_argnames=_CR3_STATIC_SH,
+def _cr3_rebates(D, rho, mci, order, offsets: tuple):
+    """Rebates paid per region, ρ_r·Σ_{i∈r}⟨d_i, mci_r⟩, as a `dfloat`
+    pair of (R,) arrays: ρ_r and mci_r (R, T) are pairs, each region's
+    hour totals of D are `regional.region_sums`, and the products and
+    the sum over hours stay in double-float."""
+    cols = _region_sums(D, order, offsets)
+    return _df.mul(rho, _df.tree_sum(_df.mul(mci, cols), axis=1))
+
+
+def _cr3_price_cut(c9, paid, live):
+    """max(0.5, 0.9·collected_r / paid_r) as a pair, `c9` = 0.9·collected
+    (1 where not `live`)."""
+    one, zero = jnp.ones_like(paid[0]), jnp.zeros_like(paid[0])
+    q = _df.div(c9, (jnp.where(live, paid[0], one),
+                     jnp.where(live, paid[1], zero)))
+    keep = _df.gt(q, (0.5 * one, zero))
+    return jnp.where(keep, q[0], 0.5), jnp.where(keep, q[1], 0.0)
+
+
+def _cr3_market(p: FleetProblem, rho, tax_frac, reg_scale,
+                state0: EngineState, iters, fiscal, order, *,
+                offsets: tuple, steps: int, outer: int, use_kernel: bool,
+                shift: int = 0, reset_mu: bool = False, mesh=None):
+    """One CR3 decision in one XLA program: the selfish best responses
+    and the coordinator's Eq.-6 clearing rounds, in a `lax.while_loop`
+    over the (R,) price vector `rho` (R = 1 for a single-region fleet).
+
+    Round 1 answers the given prices from `state0` (the tick's
+    `shift`/`reset_mu` applied first). Each later round, for every region
+    r still in deficit (paid_r = ρ_r·Σ_{i∈r}⟨d_i, mci_r⟩ above collected_r
+    + 1e-9, collected_r = tax_frac·Σ_{i∈r} E_i), lowers ρ_r ← ρ_r·max(0.5,
+    0.9·collected_r / paid_r) and re-solves from the previous round's
+    state with the μ schedule restarted; the rows of regions that already
+    cleared keep their plan, penalties, primal and allowance multipliers,
+    so each region follows exactly its standalone market. The loop stops
+    when no region is in deficit or after `iters` (traced: one program
+    serves every `clearing_iters`) rounds past the first.
+
+    The fiscal test and the price rule run in double-float (`dfloat`), so
+    they decide as float64 host arithmetic does: `rho` is a pair of (R,)
+    arrays (the best response answers its float32 `hi`), and `fiscal` is
+    (mci_r (R, T), collected_r + 1e-9, 0.9·collected_r) as pairs computed
+    in float64 on the host. The rebates are `_cr3_rebates` over
+    `order`/`offsets`, global sums under `mesh` (pad rows count nowhere).
+
+    Returns (D, penalties, state, rho, rounds)."""
+    mci, bar, c9 = fiscal
+    state = _enter_tick(state0, shift, reset_mu, CR3_MU0)
+    multi = np.ndim(p.mci) == 2
+    W = p.usage.shape[0]
+    rid = jnp.asarray(p.region) if multi else jnp.zeros(W, jnp.int32)
+
+    def best_response(rho_, st):
+        r = rho_[0] if multi else rho_[0][0]
+        if mesh is None:
+            return _cr3_impl(p, r, tax_frac, reg_scale, st, steps, outer,
+                             use_kernel)
+        return _cr3_impl_sharded(p, r, tax_frac, reg_scale, st, mesh,
+                                 steps, outer, use_kernel)
+
+    def cond(c):
+        k, paid = c[0], c[5]
+        return (k == 0) | ((k <= iters) & jnp.any(_df.gt(paid, bar)))
+
+    def body(c):
+        k, D, pens, st, rho_, paid = c
+        first = k == 0
+        active = first | _df.gt(paid, bar)
+        cut = active & ~first
+        lowered = _df.mul(rho_, _cr3_price_cut(c9, paid, cut))
+        rho_ = tuple(jnp.where(cut, n, o) for n, o in zip(lowered, rho_))
+        # Carry primal + allowance multipliers; restart the μ schedule
+        # after the first round so every round keeps the gentle wall the
+        # best response relies on.
+        Dn, pn, sn = best_response(rho_, dataclasses.replace(
+            st, mu=jnp.where(first, st.mu, CR3_MU0)))
+        row = active[rid]
+        D = jnp.where(row[:, None], Dn, D)
+        st = EngineState(x=jnp.where(row[:, None], sn.x, st.x),
+                         lam_eq=sn.lam_eq,
+                         lam_in=jnp.where(row, sn.lam_in, st.lam_in),
+                         mu=sn.mu)
+        paid = _cr3_rebates(D, rho_, mci, order, offsets)
+        return k + 1, D, jnp.where(row, pn, pens), st, rho_, paid
+
+    x = state.x
+    state = EngineState(x=x, lam_eq=state.lam_eq.astype(x.dtype),
+                        lam_in=state.lam_in.astype(x.dtype),
+                        mu=jnp.asarray(state.mu, x.dtype))
+    rho = tuple(jnp.asarray(a, x.dtype) for a in rho)
+    k, D, pens, state, rho, _paid = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), jnp.zeros_like(x), jnp.zeros(W, x.dtype),
+                     state, rho, tuple(jnp.zeros_like(a) for a in rho)))
+    return D, pens, state, rho, k
+
+
+_CR3_STATIC = ("offsets", "steps", "outer", "use_kernel", "shift",
+               "reset_mu", "mesh")
+_cr3_cleared = jax.jit(_cr3_market, static_argnames=_CR3_STATIC)
+_cr3_cleared_donated = jax.jit(_cr3_market, static_argnames=_CR3_STATIC,
                                donate_argnums=(4,))
 
 
-@functools.partial(jax.jit, static_argnames=("steps", "outer", "use_kernel",
-                                             "reset_mu"))
-def _cr3_sweep_round(p: FleetProblem, rhos, taxes, reg_scale, states,
-                     steps: int, outer: int, use_kernel: bool,
-                     reset_mu: bool):
-    """One clearing round for every sweep lane: the (ρ, tax) hyper axis
-    rides vmap through the same best-response impl the solo solve jits."""
-    def one(rho, tax, st):
-        return _cr3_impl(p, rho, tax, reg_scale, st, steps, outer,
-                         use_kernel, 0, reset_mu)
+@functools.partial(jax.jit, static_argnames=("offsets", "steps", "outer",
+                                             "use_kernel"))
+def _cr3_cleared_lanes(p: FleetProblem, rhos, taxes, reg_scale, states,
+                       iters, fiscal, *, offsets: tuple, steps: int,
+                       outer: int, use_kernel: bool):
+    """Every sweep lane's market in one program: the (ρ, tax,
+    clearing_iters, taxes collected) lane axis rides vmap through
+    `_cr3_market`; a lane whose market has cleared keeps its carry while
+    the others go on."""
+    mci, bars, c9s = fiscal
 
-    return jax.vmap(one)(rhos, taxes, states)
+    def one(rho, tax, st, n, bar, c9):
+        return _cr3_market(p, rho, tax, reg_scale, st, n, (mci, bar, c9),
+                           None, offsets=offsets, steps=steps, outer=outer,
+                           use_kernel=use_kernel)
+
+    return jax.vmap(one)(rhos, taxes, states, iters, bars, c9s)
 
 
 def _cr3_unbalanced_warn(clearing_iters: int, deficit: float, rho: float,
@@ -1396,6 +1489,36 @@ def _cr3_unbalanced_warn(clearing_iters: int, deficit: float, rho: float,
         f"{clearing_iters} iterations — rebates exceed taxes by "
         f"{deficit:.4g} at rho={rho:.4g} (Eq. 6 unmet)",
         RuntimeWarning, stacklevel=3)
+
+
+def _cr3_taxes(p: FleetProblem) -> np.ndarray:
+    """Σ_{i∈r} E_i per region, float64 on the host ((1,) for one region)."""
+    E = np.asarray(p.entitlement, float)
+    if p.is_multiregion:
+        return _region_totals(p.region, E, p.R)
+    return np.array([E.sum()])
+
+
+def _cr3_fiscal(p: FleetProblem, collected: np.ndarray):
+    """The market's Eq.-6 constants as `dfloat` pairs: mci_r (R, T), the
+    deficit bar collected_r + 1e-9 and 0.9·collected_r (`collected` may
+    carry a leading lane axis)."""
+    return (_df.split(np.atleast_2d(np.asarray(p.mci, float))),
+            _df.split(collected + 1e-9), _df.split(0.9 * collected))
+
+
+def _cr3_balance(p: FleetProblem, D: np.ndarray, rho: np.ndarray,
+                 collected: np.ndarray, order, offsets: tuple):
+    """Per-region (balanced, deficit) of the pulled plan in float64: the
+    rebates ρ_r·Σ_{i∈r}⟨d_i, mci_r⟩ at the prices the market stopped at
+    against collected_r + 1e-9. This, not the device's test, is what
+    `balanced` and `fiscal_deficit` report."""
+    mci = np.atleast_2d(np.asarray(p.mci, float))
+    rows = D if order is None else D[order]
+    paid = rho * np.array([(rows[a:b] @ m).sum() for a, b, m in
+                           zip(offsets[:-1], offsets[1:], mci)])
+    balanced = paid <= collected + 1e-9
+    return balanced, np.where(balanced, 0.0, paid - collected)
 
 
 @_register
@@ -1410,17 +1533,27 @@ class CR3:
     allowance multipliers track the shrinking ρ smoothly); `ctx.warm`
     seeds round 0 the same way for rolling-horizon re-solves.
 
-    With `ctx.mesh`, each best response runs sharded over the fleet axis;
-    the Eq.-6 sums (rebates paid vs taxes collected) are the only
-    cross-device reductions and happen here, on the gathered true-W
-    solution between rounds (rounds after the first always re-enter with
-    the μ schedule restarted).
+    A solve is one dispatch and one pull whatever its round count: the
+    best responses and the clearing rounds run in `_cr3_market`'s
+    on-device loop. A multi-region fleet runs one market per region at
+    its own price ρ_r, so `extras["rho"]` is an (R,) vector (a float for
+    one region); regions that already cleared keep their rows frozen, so
+    each region's trajectory is exactly its standalone single-region
+    market (the zero-bandwidth decomposition tests rely on this). With
+    `ctx.mesh`, each best response runs sharded over the fleet axis and
+    the Eq.-6 sums are global sums over the true rows. The loop tests
+    the balance in double-float, so it stops where float64 would; the
+    reported `balanced` and `fiscal_deficit` are then recomputed from
+    the pulled plan in float64: `balanced` means each region's rebates
+    ρ_r·Σ⟨d_i, mci_r⟩ are at most its taxes + 1e-9, in float64.
 
     If `clearing_iters` is exhausted with rebates still exceeding taxes,
     `result.extras` carries `balanced=False` and the remaining
-    `fiscal_deficit` (rebates − taxes, NP·kgCO2/MWh), and a
-    `RuntimeWarning` is emitted — callers must not treat
-    `extras["rho"]` as market-clearing then."""
+    `fiscal_deficit` (rebates − taxes summed over the regions in deficit,
+    NP·kgCO2/MWh), and a `RuntimeWarning` is emitted — callers must not
+    treat `extras["rho"]` as market-clearing then. The solve's `dr.solve`
+    span counts `clear_rounds` (best responses run) and
+    `unbalanced_regions`."""
 
     rho: float = 0.02
     tax_frac: float = 0.2
@@ -1433,237 +1566,101 @@ class CR3:
 
     def solve(self, p: FleetProblem,
               ctx: SolveContext = SolveContext()) -> FleetSolveResult:
+        use_kernel = resolve_use_kernel(ctx.use_kernel)
+        steps = ctx.resolved_steps(self)
+        R = p.R if p.is_multiregion else 1
+        if ctx.mesh is None:
+            pj, W = _jit_view(p), p.W
+            state = ctx.warm if ctx.warm is not None else EngineState.cold(
+                jnp.zeros(p.usage.shape), n_in=p.W, mu0=CR3_MU0)
+        else:
+            pj, W = pad_fleet(p, fleet_device_count(ctx.mesh))
+            state = _pad_state(ctx.warm, pj.W) if ctx.warm is not None \
+                else EngineState.cold(jnp.zeros(pj.usage.shape), n_in=pj.W,
+                                      mu0=CR3_MU0)
         if p.is_multiregion:
-            return self._solve_multiregion(p, ctx)
-        use_kernel = resolve_use_kernel(ctx.use_kernel)
-        steps = ctx.resolved_steps(self)
-        mci = np.asarray(p.mci)
-        collected = self.tax_frac * float(np.asarray(p.entitlement).sum())
-        rho_cur = float(self.rho)
-        if ctx.mesh is None:
-            pj, W = _jit_view(p), p.W
-            state = ctx.warm if ctx.warm is not None else EngineState.cold(
-                jnp.zeros(p.usage.shape), n_in=p.W, mu0=CR3_MU0)
-            twin = _cr3_best_response_donated if ctx.donate \
-                else _cr3_best_response
+            reg_scale = _cr3_reg_scale(p)
+            if pj.W > W:
+                reg_scale = jnp.concatenate(
+                    [reg_scale, jnp.ones((pj.W - W, 1), reg_scale.dtype)])
+            order, offsets = _region_layout(p.region, R)
         else:
-            pj, W = pad_fleet(p, fleet_device_count(ctx.mesh))
-            state = _pad_state(ctx.warm, pj.W) if ctx.warm is not None \
-                else EngineState.cold(jnp.zeros(pj.usage.shape), n_in=pj.W,
-                                      mu0=CR3_MU0)
-            twin = _cr3_sharded_donated if ctx.donate else _cr3_sharded
-        reg_scale = 1e-3 / (W * p.T)
-
-        def best_response(st, shift_, reset_):
-            """One clearing round: dispatch, wait, and the pull of the
-            true-W plan and penalties."""
-            kw = {} if ctx.mesh is None else {"mesh": ctx.mesh}
-            with span("dr.solve.round"):
-                D, pens, st = _dispatch(
-                    twin, pj, rho_cur, self.tax_frac, reg_scale, st,
-                    steps=steps, outer=self.outer, use_kernel=use_kernel,
-                    shift=shift_, reset_mu=reset_, **kw)
-                D, pens = _fetch(D, pens)
-            return D[:W], pens[:W], st
-
-        D, pens, state = best_response(state, ctx.shift, ctx.reset_mu)
-        rounds = 1
-        paid = rho_cur * float((D @ mci).sum())
-        for _ in range(self.clearing_iters):
-            if paid <= collected + 1e-9:
-                break
-            rho_cur *= max(0.5, 0.9 * collected / max(paid, 1e-9))
-            # Carry primal + allowance multipliers; restart the μ schedule
-            # so every round keeps the gentle wall the best response
-            # relies on.
-            D, pens, state = best_response(state, 0, True)
-            rounds += 1
-            paid = rho_cur * float((D @ mci).sum())
-        balanced = paid <= collected + 1e-9
-        deficit = 0.0 if balanced else paid - collected
-        if not balanced:
-            _cr3_unbalanced_warn(self.clearing_iters, deficit, rho_cur,
-                                 "CR3.solve")
-        return _report(p, D, pens,
-                       iters=steps * self.outer * rounds, state=state,
-                       extras={"rho": rho_cur, "balanced": balanced,
-                               "fiscal_deficit": deficit})
-
-    def _solve_multiregion(self, p: FleetProblem,
-                           ctx: SolveContext) -> FleetSolveResult:
-        """Per-region fiscal clearing: each region runs its own Eq.-6
-        market (its taxes cover its rebates at its own clearing price
-        ρ_r), so `extras["rho"]` is an (R,) vector. Every clearing round
-        re-solves the whole fleet in one engine call, but regions that
-        already cleared keep their frozen plan/state — each region's
-        trajectory is exactly what its standalone single-region solve
-        would produce (the zero-bandwidth decomposition tests rely on
-        this)."""
-        use_kernel = resolve_use_kernel(ctx.use_kernel)
-        steps = ctx.resolved_steps(self)
-        mci = np.asarray(p.mci)
-        region = np.asarray(p.region)
-        R = p.R
-        wmci = mci[region]
-        collected = self.tax_frac * _region_totals(region, p.entitlement, R)
-        rho_cur = np.full(R, float(self.rho))
-        reg_scale = _cr3_reg_scale(p)
-        if ctx.mesh is None:
-            pj, W = _jit_view(p), p.W
-            state = ctx.warm if ctx.warm is not None else EngineState.cold(
-                jnp.zeros(p.usage.shape), n_in=p.W, mu0=CR3_MU0)
-            twin = _cr3_best_response_donated if ctx.donate \
-                else _cr3_best_response
-        else:
-            pj, W = pad_fleet(p, fleet_device_count(ctx.mesh))
-            reg_scale = jnp.concatenate(
-                [reg_scale, jnp.ones((pj.W - W, 1), reg_scale.dtype)])
-            state = _pad_state(ctx.warm, pj.W) if ctx.warm is not None \
-                else EngineState.cold(jnp.zeros(pj.usage.shape), n_in=pj.W,
-                                      mu0=CR3_MU0)
-            twin = _cr3_sharded_donated if ctx.donate else _cr3_sharded
-        region_pad = np.asarray(pj.region)
-
-        def best_response(st, shift_, reset_):
-            """One clearing round: dispatch, wait, and the pull of the
-            true-W plan and penalties."""
-            kw = {} if ctx.mesh is None else {"mesh": ctx.mesh}
-            with span("dr.solve.round"):
-                D, pens, st = _dispatch(
-                    twin, pj, jnp.asarray(rho_cur, jnp.float32),
-                    self.tax_frac, reg_scale, st, steps=steps,
-                    outer=self.outer, use_kernel=use_kernel, shift=shift_,
-                    reset_mu=reset_, **kw)
-                D, pens = _fetch(D, pens)
-            return D[:W], pens[:W], st
-
-        def paid_of(D):
-            return rho_cur * _region_totals(region, (D * wmci).sum(1), R)
-
-        D, pens, state = best_response(state, ctx.shift, ctx.reset_mu)
-        rounds = 1
-        paid = paid_of(D)
-        for _ in range(self.clearing_iters):
-            active = paid > collected + 1e-9
-            if not active.any():
-                break
-            rho_cur = np.where(
-                active,
-                rho_cur * np.maximum(0.5, 0.9 * collected
-                                     / np.maximum(paid, 1e-9)),
-                rho_cur)
-            Dn, pensn, staten = best_response(state, 0, True)
-            row = active[region]
-            D = np.where(row[:, None], Dn, D)
-            pens = np.where(row, pensn, pens)
-            # μ is reset every round so it is round-count independent;
-            # lam_eq is empty for CR3 — only x and the allowance
-            # multipliers need per-row freezing.
-            mask = jnp.asarray(active[region_pad])
-            state = EngineState(
-                x=jnp.where(mask[:, None], staten.x, state.x),
-                lam_eq=staten.lam_eq,
-                lam_in=jnp.where(mask, staten.lam_in, state.lam_in),
-                mu=staten.mu)
-            rounds += 1
-            paid = paid_of(D)
-        balanced = paid <= collected + 1e-9
-        deficit = np.where(balanced, 0.0, paid - collected)
+            reg_scale = 1e-3 / (W * p.T)
+            order, offsets = None, (0, W)
+        collected = self.tax_frac * _cr3_taxes(p)
+        twin = _cr3_cleared_donated if ctx.donate else _cr3_cleared
+        D, pens, state, rho, rounds = _dispatch(
+            twin, pj, _df.split(np.full(R, float(self.rho))), self.tax_frac,
+            reg_scale, state, self.clearing_iters, _cr3_fiscal(p, collected),
+            order, offsets=offsets, steps=steps, outer=self.outer,
+            use_kernel=use_kernel, shift=ctx.shift, reset_mu=ctx.reset_mu,
+            mesh=ctx.mesh)
+        D, pens, rho, rounds = _fetch(D, pens, rho, rounds)
+        D, rho = D[:W], rho[0].astype(float) + rho[1]
+        balanced, deficit = _cr3_balance(p, D, rho, collected, order,
+                                         offsets)
+        _count(clear_rounds=int(rounds),
+               unbalanced_regions=int((~balanced).sum()))
         if not balanced.all():
             worst = int(np.argmax(deficit))
-            _cr3_unbalanced_warn(self.clearing_iters,
-                                 float(deficit.sum()),
-                                 float(rho_cur[worst]),
-                                 "CR3.solve (multi-region)")
-        return _report(p, D, pens,
-                       iters=steps * self.outer * rounds, state=state,
-                       extras={"rho": rho_cur,
+            _cr3_unbalanced_warn(
+                self.clearing_iters, float(deficit.sum()),
+                float(rho[worst]), "CR3.solve (multi-region)"
+                if p.is_multiregion else "CR3.solve")
+        return _report(p, D, pens[:W],
+                       iters=steps * self.outer * int(rounds), state=state,
+                       extras={"rho": rho if p.is_multiregion
+                               else float(rho[0]),
                                "balanced": bool(balanced.all()),
                                "fiscal_deficit": float(deficit.sum())})
 
     # -- vmapped sweep lane -------------------------------------------------
     @classmethod
     def _sweep_uniform(cls, policies: Sequence["CR3"]) -> bool:
-        # `outer` is static (one compile); per-lane ρ/tax are traced and
-        # per-lane clearing_iters ride the host-side lockstep loop.
+        # `outer` is static (one compile); per-lane ρ/tax/clearing_iters
+        # are traced.
         return len({pl.outer for pl in policies}) == 1
 
     @classmethod
     def _sweep_family(cls, p: FleetProblem, policies: Sequence["CR3"],
                       ctx: SolveContext) -> list[FleetSolveResult]:
         if ctx.mesh is not None or p.is_multiregion:
-            # vmap-of-shard_map best responses with per-lane host clearing
-            # is a ROADMAP follow-up, and multi-region clearing tracks an
-            # (R,) price vector per lane; both solve per policy.
+            # vmap-of-shard_map markets, and per-lane (R,) price vectors,
+            # are a ROADMAP follow-up; both solve per policy.
             return [pl.solve(p, ctx) for pl in policies]
         use_kernel = resolve_use_kernel(ctx.use_kernel)
         steps = ctx.steps if ctx.steps is not None else cls.default_steps
         outer = policies[0].outer
         N = len(policies)
-        mci = np.asarray(p.mci)
-        pj = _jit_view(p)
-        reg_scale = 1e-3 / (p.W * p.T)
         states = EngineState(
             x=jnp.zeros((N,) + p.usage.shape),
             lam_eq=jnp.zeros((N, 0)), lam_in=jnp.zeros((N, p.W)),
             mu=jnp.full((N,), CR3_MU0))
-        rho_cur = np.asarray([pl.rho for pl in policies], float)
-        taxes = np.asarray([pl.tax_frac for pl in policies], float)
-        iters_cap = np.asarray([pl.clearing_iters for pl in policies])
-        collected = taxes * float(np.asarray(p.entitlement).sum())
-
-        def rounds_all(reset_mu):
-            return _cr3_sweep_round(
-                pj, jnp.asarray(rho_cur, jnp.float32),
-                jnp.asarray(taxes, jnp.float32), reg_scale, states,
-                steps=steps, outer=outer, use_kernel=use_kernel,
-                reset_mu=reset_mu)
-
-        Ds, pens, states = rounds_all(False)
-        D_out, pens_out = np.asarray(Ds), np.asarray(pens)
-        rounds = np.ones(N, int)
-        used = np.zeros(N, int)
-        paid = rho_cur * np.einsum("nwt,t->n", D_out, mci)
-        while True:
-            active = (paid > collected + 1e-9) & (used < iters_cap)
-            if not active.any():
-                break
-            rho_cur = np.where(
-                active,
-                rho_cur * np.maximum(0.5, 0.9 * collected
-                                     / np.maximum(paid, 1e-9)),
-                rho_cur)
-            # Every lane re-solves in lockstep (one XLA call), but lanes
-            # that already cleared keep their frozen solution/state so each
-            # lane's trajectory is exactly its solo-`solve()` trajectory.
-            Ds, pens, new_states = rounds_all(True)
-            sel = active[:, None, None]
-            D_out = np.where(sel, np.asarray(Ds), D_out)
-            pens_out = np.where(active[:, None], np.asarray(pens), pens_out)
-            states = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(
-                    jnp.asarray(active).reshape((N,) + (1,) * (new.ndim - 1)),
-                    new, old),
-                new_states, states)
-            rounds = rounds + active
-            used = used + active
-            paid = np.where(active,
-                            rho_cur * np.einsum("nwt,t->n", D_out, mci),
-                            paid)
-        balanced = paid <= collected + 1e-9
-        deficit = np.where(balanced, 0.0, paid - collected)
+        collected = np.asarray([pl.tax_frac for pl in policies],
+                               float)[:, None] * _cr3_taxes(p)
+        Ds, pens, states, rho, rounds = _dispatch(
+            _cr3_cleared_lanes, _jit_view(p),
+            _df.split([[pl.rho] for pl in policies]),
+            np.asarray([pl.tax_frac for pl in policies], float),
+            1e-3 / (p.W * p.T), states,
+            np.asarray([pl.clearing_iters for pl in policies], np.int32),
+            _cr3_fiscal(p, collected), offsets=(0, p.W), steps=steps,
+            outer=outer, use_kernel=use_kernel)
+        Ds, pens, rho, rounds = _fetch(Ds, pens, rho, rounds)
+        rho = rho[0].astype(float) + rho[1]
         out = []
         for i, pl in enumerate(policies):
-            if not balanced[i]:
-                _cr3_unbalanced_warn(pl.clearing_iters, float(deficit[i]),
-                                     float(rho_cur[i]), "CR3 sweep")
-            state_i = jax.tree_util.tree_map(lambda a: a[i], states)
+            balanced, deficit = _cr3_balance(p, Ds[i], rho[i],
+                                             collected[i], None, (0, p.W))
+            if not balanced.all():
+                _cr3_unbalanced_warn(pl.clearing_iters, float(deficit[0]),
+                                     float(rho[i, 0]), "CR3 sweep")
             out.append(_report(
-                p, D_out[i], pens_out[i],
-                iters=steps * outer * int(rounds[i]), state=state_i,
-                extras={"rho": float(rho_cur[i]),
-                        "balanced": bool(balanced[i]),
-                        "fiscal_deficit": float(deficit[i])}))
+                p, Ds[i], pens[i], iters=steps * outer * int(rounds[i]),
+                state=jax.tree_util.tree_map(lambda a: a[i], states),
+                extras={"rho": float(rho[i, 0]),
+                        "balanced": bool(balanced[0]),
+                        "fiscal_deficit": float(deficit[0])}))
         return out
 
 
@@ -1964,16 +1961,16 @@ def solve_day(problem: FleetProblem, policy, mci_stack, *,
     `lax.scan`. Matches the per-tick `RollingHorizonSolver.step()` loop
     to <0.01 pp realized carbon while issuing a single dispatch.
 
-    Supports CR1/CR2 — the policies whose backends are pure traceable
-    engine calls. CR3 clears its fiscal balance in a host-side loop and
-    B1/B3 are closed-form per-tick evaluations; both keep the per-tick
-    path. With `ctx.mesh` the whole day scan nests INSIDE the W-axis
-    shard_map (per-tick norms ride in from the true fleet — replicated
-    scalars for single-region, row-sharded `regional` vectors for
-    multi-region), so a sharded day is still one dispatch under both
-    1-D and 2-D fleet meshes. Multi-region rows of `mci_stack` are
-    (R, T) forecast stacks. Migration is not applied per tick — run
-    the committed plan through `solve()` for migration credit.
+    Supports CR1/CR2 — the policies whose day scan is built. CR3's
+    market (a `while_loop` per tick) has no day scan yet and B1/B3 are
+    closed-form per-tick evaluations; both keep the per-tick path. With
+    `ctx.mesh` the whole day scan nests INSIDE the W-axis shard_map
+    (per-tick norms ride in from the true fleet — replicated scalars for
+    single-region, row-sharded `regional` vectors for multi-region), so
+    a sharded day is still one dispatch under both 1-D and 2-D fleet
+    meshes. Multi-region rows of `mci_stack` are (R, T) forecast
+    stacks. Migration is not applied per tick — run the committed plan
+    through `solve()` for migration credit.
 
     Debug/observability lanes (solo path only): `ctx.sanitize` routes
     the whole day through a checkify twin — a NaN/inf in ANY tick's

@@ -12,8 +12,8 @@ evaluates a `DRPolicy` across the whole stack:
     Python loop over scenarios. With `ctx.mesh`, the scenario vmap nests
     *inside* the W-axis shard_map exactly like `api.sweep`'s policy-grid
     vmap does, so fleet-scale ensembles run sharded too. Every other
-    policy (CR3's host-side clearing loop, closed-form baselines, warm/
-    donated contexts) falls back to an equivalent sequential loop of
+    policy (CR3's market, closed-form baselines, warm/donated
+    contexts) falls back to an equivalent sequential loop of
     `api.solve` over the materialized scenarios — `evaluate_ensemble` is
     always safe to call.
 

@@ -21,11 +21,18 @@ Two flavors live here, matching the two places reductions run:
     holds — per-region totals are scattered back to rows *before* the
     solve and shard with their rows.
 
+  * **Traced per-region totals** (``region_layout`` on the host,
+    ``region_sums`` in the trace): CR3's Eq.-6 rebates paid per region,
+    summed on the device inside the clearing loop in double-float
+    (``dfloat``), so the loop's balance test agrees with a float64 one.
+    Each region's rows are summed by one fixed pairwise tree, so a
+    region's total is the same, bit for bit, alone or inside a
+    multi-region fleet.
+
   * **Host-side, exact numpy** (``region_totals``): per-region
-    accumulation of (W,) or (W, T) row weights — CR3's Eq.-6 fiscal
-    sums (taxes collected / rebates paid per region), the migration
-    stage's movable/headroom aggregates, and streaming's per-region
-    carbon ledgers.
+    accumulation of (W,) or (W, T) row weights — CR3's taxes
+    collected per region, the migration stage's movable/headroom
+    aggregates and streaming's per-region carbon ledgers.
 
 Single-region problems flow through the same functions and get the
 fleet-global scalar forms, so callers never branch on region-ness
@@ -41,11 +48,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.core import dfloat
 from repro.core.fleet_solver import FleetProblem, _bounds
 
 __all__ = ["CR1_NORM_FILLS", "CR2_NORM_FILLS", "RegionReductions",
            "cr1_norms", "cr2_norms", "cr3_reg_scale", "norm_specs",
-           "pad_row_norms", "region_rows", "region_sum", "region_totals"]
+           "pad_row_norms", "region_layout", "region_rows", "region_sum",
+           "region_sums", "region_totals"]
 
 #: Pad fills for `pad_row_norms` keeping device-pad rows inert:
 #: weights 0 (pad rows contribute nothing), step/scale divisors 1
@@ -81,13 +90,47 @@ def region_sum(x, region, R: int):
     return jax.ops.segment_sum(x, region, num_segments=R)[region]
 
 
+def region_layout(region, R: int):
+    """Host-side layout of a fleet's rows for `region_sums`: `(order,
+    offsets)`, where `order` is the stable permutation that sorts the
+    rows by region (None when they already are, as `regional_fleet`
+    keeps them) and `offsets` the R + 1 bounds of each region's run of
+    rows in that order. A single-region fleet of W rows is `(None, (0,
+    W))`."""
+    region = np.asarray(region)
+    counts = np.bincount(region, minlength=R)
+    offsets = (0,) + tuple(int(c) for c in np.cumsum(counts))
+    if np.all(region[1:] >= region[:-1]):
+        return None, offsets
+    return np.argsort(region, kind="stable").astype(np.int32), offsets
+
+
+def region_sums(x, order, offsets: tuple):
+    """Per-region totals of the per-row values `x` ((W,) or (W, T)),
+    traced, as a `dfloat` pair of (R,) or (R, T) arrays: each region's
+    rows (in `order`, bounded by `offsets`; see `region_layout`) are
+    summed by one fixed pairwise tree (`dfloat.tree_sum`), with float64
+    accuracy from float32 operations. Padding a run with zeros leaves
+    every sum of the tree as it was, so a region's total is the same
+    reduction of the same values, bit for bit, whether the region is
+    solved alone or inside a multi-region fleet. Rows past
+    `offsets[-1]` (device pad rows) count nowhere."""
+    if order is not None:
+        x = x[order]
+    width = max(b - a for a, b in zip(offsets[:-1], offsets[1:]))
+    tail = ((0, 0),) * (x.ndim - 1)
+    runs = jnp.stack([jnp.pad(x[a:b], ((0, width - (b - a)),) + tail)
+                      for a, b in zip(offsets[:-1], offsets[1:])])
+    return dfloat.tree_sum((runs, jnp.zeros_like(runs)), axis=1)
+
+
 def region_totals(region, weights, R: int) -> np.ndarray:
     """Exact host-side per-region totals of per-row weights: (W,) weights
     give an (R,) total, (W, T) weights an (R, T) total. `region` may be
     a masked row subset as long as it is index-aligned with `weights`
     (e.g. `region[is_batch]` with `residual[is_batch]`). The one numpy
-    accumulation primitive behind CR3's Eq.-6 fiscal sums, migration's
-    movable/headroom aggregates, and streaming's per-region ledgers."""
+    accumulation primitive behind CR3's taxes collected, migration's
+    movable/headroom aggregates and streaming's per-region ledgers."""
     region = np.asarray(region)
     w = np.asarray(weights, float)
     if w.ndim == 1:

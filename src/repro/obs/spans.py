@@ -15,9 +15,10 @@
     off a span costs a few microseconds.
 
 The program opens these spans on its tick path (`dr.tick`, its phases,
-`dr.solve` and the dispatch / wait / pull / report under it), so a
-caller — a benchmark, an operator's dashboard — can read where a tick's
-time went without a profiler::
+`dr.solve` and the dispatch / wait / pull / report under it; a CR3
+solve also counts `clear_rounds` and `unbalanced_regions` on
+`dr.solve` with `count`), so a caller — a benchmark, an operator's
+dashboard — can read where a tick's time went without a profiler::
 
     with obs.span("upload") as sp:
         sp.count("h2d_bytes", x.nbytes)
@@ -53,11 +54,10 @@ from typing import Any, NamedTuple, Optional
 
 from repro.obs.events import EventWriter, SpanEvent
 
-__all__ = ["RING_SIZE", "SpanRecord", "SpanScope", "clear", "recorded",
-           "span"]
+__all__ = ["RING_SIZE", "SpanRecord", "SpanScope", "clear", "count",
+           "recorded", "span"]
 
-#: Records the ring keeps: over 400 ticks of a dozen spans each, with
-#: room for CR3's clearing rounds.
+#: Records the ring keeps: over 1,000 ticks of a dozen spans each.
 RING_SIZE = 16384
 
 
@@ -214,6 +214,16 @@ class SpanScope:
                 name=self.name, elapsed_s=dur * 1e-9, meta=self.meta,
                 start_ns=self.start_ns, parent=self.parent, tick=self.tick,
                 counts=dict(self.counts)))
+
+
+def count(**counts: int) -> None:
+    """Add `counts` to the innermost span open in this thread (nothing
+    when none is open): numbers a block learns only once its results are
+    on the host, such as CR3's `clear_rounds` under `dr.solve`."""
+    stack = _stack()
+    if stack:
+        for key, n in counts.items():
+            stack[-1].count(key, n)
 
 
 def span(name: str, *, tick: int | None = None,

@@ -502,9 +502,10 @@ def test_report_renders_phases_and_bytes(cr1_stream, tmp_path, capsys):
 
 
 def test_cr3_tick_counts_every_clearing_round(tmp_path):
-    """A CR3 tick that clears in several rounds: one `dr.solve.round`
-    per round, each with its own dispatch, wait and pull, and the tick
-    ledger counts them all."""
+    """A CR3 tick that clears in several rounds runs them all in one
+    dispatch, wait and pull under `dr.solve`, which counts the rounds
+    (`clear_rounds`) and the regions left in deficit
+    (`unbalanced_regions`); the tick ledger carries both counts."""
     from repro.core.carbon import ForecastStream
     from repro.core.fleet_solver import synthetic_fleet
     from repro.core.streaming import RollingHorizonSolver
@@ -527,14 +528,16 @@ def test_cr3_tick_counts_every_clearing_round(tmp_path):
     rounds = out.inner_steps // (40 * policy.outer)
     assert rounds == 3 == 1 + policy.clearing_iters
     recs = spans.recorded()
-    round_spans = [r for r in recs if r.name == "dr.solve.round"]
-    assert len(round_spans) == rounds
-    for r in round_spans:
-        assert [k.name for k in _children(recs, r)] == SOLVE_PHASES[:3]
-    assert ev["dispatches"] == rounds
+    assert not [r for r in recs if r.name == "dr.solve.round"]
+    (root,) = [r for r in recs if r.name == "dr.solve"]
+    assert [k.name for k in _children(recs, root)] == SOLVE_PHASES
+    assert root.counts == {"clear_rounds": rounds, "unbalanced_regions": 1}
+    assert ev["dispatches"] == 1
     assert ev["h2d_bytes"] == sum(r.counts.get("h2d_bytes", 0)
                                   for r in recs)
-    assert ev["d2h_bytes"] == rounds * (tight.W * tight.T + tight.W) * 4
+    # the plan and penalties, then ρ as a float32 pair (one region) and
+    # the round count
+    assert ev["d2h_bytes"] == (tight.W * tight.T + tight.W + 2 + 1) * 4
 
 
 def test_solve_day_spans_one_dispatch(fp):

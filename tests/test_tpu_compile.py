@@ -107,9 +107,10 @@ def test_al_step_odd_horizon_compiles_for_v5e(one_chip, hours, cvec_rows):
     _compile_al_step(one_chip, "cr2", jnp.float32, cvec_rows, hours)
 
 
-def _fleet_at_scale(sharding):
-    """A W-row synthetic fleet and a cold engine state as shapes on
-    `sharding` (built from a 16-row fleet: only shapes are compiled)."""
+def _fleet_at_scale(sharding, fleet=None):
+    """A W-row fleet (default: synthetic) and a cold engine state as
+    shapes on `sharding` (built from a 16-row fleet: only shapes are
+    compiled)."""
     from repro.core.engine import EngineState
     from repro.core.fleet_solver import _jit_view, synthetic_fleet
 
@@ -120,7 +121,8 @@ def _fleet_at_scale(sharding):
         dtype = jnp.float32 if a.dtype == np.float64 else a.dtype
         return _shape(sharding, shape, dtype)
 
-    fleet = jax.tree.map(at_scale, _jit_view(synthetic_fleet(16, hours=T)))
+    fleet = jax.tree.map(at_scale, _jit_view(
+        synthetic_fleet(16, hours=T) if fleet is None else fleet))
     state = EngineState(x=_shape(sharding, (W, T)),
                         lam_eq=_shape(sharding, (0,)),
                         lam_in=_shape(sharding, (0,)),
@@ -140,6 +142,53 @@ def test_cr1_solve_compiles_for_v5e(one_chip, monkeypatch):
                               use_kernel=True).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+def _compile_cr3_market(sharding, mesh=None):
+    """A whole CR3 tick on the kernel path, compiled for `sharding`: the
+    best responses and the clearing loop of 8 regional markets of W/8
+    rows each (with `mesh`, each best response sharded over it)."""
+    from repro.core.api import CR3, _cr3_cleared
+    from repro.core.engine import EngineState
+    from repro.core.fleet_solver import synthetic_regional_fleet
+
+    states = ["CA", "WA", "AZ", "CO", "TX", "IL", "NY", "FL"]
+    fleet, _ = _fleet_at_scale(sharding, synthetic_regional_fleet(
+        16, states, hours=T, seed=0))
+    state = EngineState(x=_shape(sharding, (W, T)),
+                        lam_eq=_shape(sharding, (0,)),
+                        lam_in=_shape(sharding, (W,)),
+                        mu=_shape(sharding, ()))
+    R = len(states)
+    pair = (_shape(sharding, (R,)),) * 2
+    fiscal = ((_shape(sharding, (R, T)),) * 2, pair, pair)
+    return _cr3_cleared.lower(
+        fleet, pair, _shape(sharding, ()), _shape(sharding, (W, 1)), state,
+        _shape(sharding, (), jnp.int32), fiscal, None,
+        offsets=tuple(range(0, W + 1, W // R)), steps=150, outer=CR3.outer,
+        use_kernel=True, shift=1, reset_mu=True, mesh=mesh).compile()
+
+
+def test_cr3_market_compiles_for_v5e(one_chip, monkeypatch):
+    """A whole CR3 tick on one chip, as the `cr3_ticks` cell runs it."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    compiled = _compile_cr3_market(one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+def test_sharded_cr3_market_compiles_for_v5e_2x2(topo, monkeypatch):
+    """The CR3 market over four chips: the shard_map best response nested
+    in the clearing `while_loop`, with the per-region sums of row-sharded
+    plans outside the shard_map."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.launch.mesh import FLEET_AXIS
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    mesh = Mesh(np.asarray(topo.devices), (FLEET_AXIS,))
+    compiled = _compile_cr3_market(NamedSharding(mesh, PartitionSpec()),
+                                   mesh)
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_sharded_cr1_solve_compiles_for_v5e_2x2(topo, monkeypatch):
