@@ -74,18 +74,19 @@ from repro.core.engine import (EngineConfig, EngineState, al_minimize,
                                al_minimize_sharded)
 from repro.core.fleet_solver import (CR1_MU0, CR2_MU0, CR3_MU0,
                                      FleetProblem, FleetSolveResult,
-                                     _bounds, _enter_tick, _fleet_specs,
-                                     _jit_view, _pad_state, _projection,
-                                     _report, _single_region_view,
+                                     OnDevice, _bounds, _enter_tick,
+                                     _fleet_specs, _jit_view, _pad_state,
+                                     _projection, _report,
+                                     _single_region_view,
                                      cr2_reference_fleet, fleet_penalties,
-                                     pad_fleet, resolve_use_kernel)
+                                     pad_fleet, report_layout, report_sums,
+                                     resolve_use_kernel)
 from repro.core.regional import (CR1_NORM_FILLS, CR2_NORM_FILLS,
                                  cr1_norms as _cr1_norms,
                                  cr2_norms as _cr2_norms,
                                  cr3_reg_scale as _cr3_reg_scale,
                                  norm_specs as _norm_specs,
                                  pad_row_norms as _pad_row_norms,
-                                 region_layout as _region_layout,
                                  region_sums as _region_sums,
                                  region_totals as _region_totals)
 from repro.launch.mesh import fleet_axes, fleet_device_count
@@ -297,27 +298,64 @@ def _host_bytes(args) -> int:
     return n
 
 
-def _dispatch(run, *args, **static):
-    """`run(*args, **static)` — a jitted lane, dynamic arguments
-    positional — under a `dr.solve.dispatch` span: argument conversion,
-    upload and enqueue, until the call returns. Counts the dispatch and
-    the host bytes it uploads (`h2d_bytes`)."""
+def _dispatch(run, *args, **kw):
+    """`run(*args, **kw)` — a jitted lane, dynamic arguments positional,
+    static ones and a host `order` array by keyword — under a
+    `dr.solve.dispatch` span: argument conversion, upload and enqueue,
+    until the call returns. Counts the dispatch and the host bytes it
+    uploads (`h2d_bytes`)."""
     with span("dr.solve.dispatch") as sp:
         sp.count("dispatches", 1)
-        sp.count("h2d_bytes", _host_bytes(args))
-        return run(*args, **static)
+        sp.count("h2d_bytes", _host_bytes(
+            (args, [v for v in kw.values() if isinstance(v, np.ndarray)])))
+        return run(*args, **kw)
 
 
-def _fetch(*arrays) -> tuple:
+def _fetch(*outs) -> tuple:
     """Wait for the device (`dr.solve.wait`, where the host blocks
-    anyway), then pull `arrays` to the host (`dr.solve.pull`, counting
-    `d2h_bytes`)."""
+    anyway), then pull `outs` (arrays or pytrees of them) to the host
+    (`dr.solve.pull`, counting `d2h_bytes`)."""
     with span("dr.solve.wait"):
-        jax.block_until_ready(arrays[0])
+        jax.block_until_ready(outs[0])
     with span("dr.solve.pull") as sp:
-        out = tuple(np.asarray(a) for a in arrays)
-        sp.count("d2h_bytes", sum(a.nbytes for a in out))
+        out = jax.device_get(outs)
+        sp.count("d2h_bytes", sum(a.nbytes for a in
+                                  jax.tree_util.tree_leaves(out)))
     return out
+
+
+def _plan_sums(p: FleetProblem, D, pens, order, offsets: tuple,
+               roll: int = 0):
+    """`report_sums` of the plan `D` of (possibly device-padded) `p`,
+    traced inside a lane's own program; `roll` slides `p.usage` to the
+    window the plan was solved on (a day scan's last tick)."""
+    usage = jnp.asarray(p.usage)
+    if roll:
+        usage = jnp.roll(usage, -roll, axis=1)
+    return report_sums(D, pens, usage, p.is_batch, order, offsets,
+                       p.day_hours)
+
+
+def _tick_report(p: FleetProblem, D, pens, order, offsets):
+    """The extra outputs of a solve lane that reports from the device:
+    `()` when `offsets` is None, else hour 0 of the plan and its
+    `_plan_sums` — pulled instead of the plan."""
+    if offsets is None:
+        return ()
+    return D[:, 0], _plan_sums(p, D, pens, order, offsets)
+
+
+def _device_report(p: FleetProblem, out, *, iters: int,
+                   tel: int) -> FleetSolveResult:
+    """The result of a CR1/CR2 solve lane's outputs `out` (plan,
+    penalties, state[, telemetry], hour 0, report sums) for the true
+    fleet `p`: pulls hour 0 and the sums; the plan stays on the device
+    until `.D` is read."""
+    hour0, sums = _fetch(*out[-2:])
+    extras = {"telemetry": ConvergenceTrace.from_aux(out[3])} \
+        if tel else None
+    return _report(p, OnDevice(out[0], p.W), iters=iters, state=out[2],
+                   extras=extras, sums=sums, hour0=hour0[:p.W])
 
 
 def solve(problem: FleetProblem, policy, *,
@@ -455,12 +493,10 @@ def _maybe_migrate(p: FleetProblem, res: FleetSolveResult):
         return res
     from repro.core.migration import fleet_migration
     plan = fleet_migration(p, np.asarray(res.D))
-    wmci = np.asarray(p.mci)[np.asarray(p.region)]
-    carbon_base = float((np.asarray(p.usage) * wmci).sum())
     return dataclasses.replace(
         res,
         carbon_reduction_pct=res.carbon_reduction_pct
-        + 100.0 * plan.net_saved / carbon_base,
+        + 100.0 * plan.net_saved / res.carbon_base,
         extras={**res.extras, "migration": plan})
 
 
@@ -615,14 +651,12 @@ def _coupled_migrate(p: FleetProblem, policy, res: FleetSolveResult,
         y=y, carbon_saved=float((y * grad).sum()),
         migration_cost=float((y * cost_f[:, :, None]).sum()),
         moved_total=float(y.sum()))
-    wmci = mci[np.asarray(p.region)]
-    carbon_base = float((np.asarray(p.usage) * wmci).sum())
     cand = _report(p, D_f, np.asarray(pens),
                    iters=res.iters + steps * outer, state=res.state)
     cand = dataclasses.replace(
         cand,
         carbon_reduction_pct=cand.carbon_reduction_pct
-        + 100.0 * plan.net_saved / carbon_base,
+        + 100.0 * plan.net_saved / cand.carbon_base,
         extras={**res.extras, "migration": plan,
                 "coupled_migration": True})
     if cand.carbon_reduction_pct <= post.carbon_reduction_pct:
@@ -755,7 +789,8 @@ def _cr1_cfg(steps: int, moment_dtype: str = "float32",
 def _cr1_impl(p: FleetProblem, lam, state0: EngineState, steps: int,
               use_kernel: bool, shift: int = 0, reset_mu: bool = False,
               moment_dtype: str = "float32", sanitize: bool = False,
-              telemetry_every: int = 0, norms=None):
+              telemetry_every: int = 0, norms=None, order=None,
+              offsets: tuple | None = None):
     state0 = _enter_tick(state0, shift, reset_mu, CR1_MU0)
     norms = _cr1_norms(p) if norms is None else norms
     objective, project, step_scale = _cr1_pieces(p, use_kernel, norms=norms)
@@ -767,13 +802,16 @@ def _cr1_impl(p: FleetProblem, lam, state0: EngineState, steps: int,
                          step_scale=step_scale, init=state0, cfg=cfg,
                          fused_inner=fused)
     out = (D, fleet_penalties(p, D, use_kernel), aux["state"])
-    # Static knob: the off path returns the historical 3-tuple, so every
-    # telemetry-blind caller (sweeps, ensembles, day scans) is untouched.
-    return out + (aux["telemetry"],) if telemetry_every else out
+    # Static knobs: the telemetry trace, then (with `offsets`) hour 0 and
+    # the report sums ride as extra outputs; without them the historical
+    # 3-tuple, so the day scans' tick solves are untouched.
+    if telemetry_every:
+        out += (aux["telemetry"],)
+    return out + _tick_report(p, D, out[1], order, offsets)
 
 
 _CR1_STATIC = ("steps", "use_kernel", "shift", "reset_mu", "moment_dtype",
-               "sanitize", "telemetry_every")
+               "sanitize", "telemetry_every", "offsets")
 _cr1_run = jax.jit(_cr1_impl, static_argnames=_CR1_STATIC)
 _cr1_run_donated = jax.jit(_cr1_impl, static_argnames=_CR1_STATIC,
                            donate_argnums=(2,))
@@ -786,7 +824,8 @@ def _cr1_impl_sharded(p: FleetProblem, lam, norms, state0: EngineState,
                       mesh, steps: int, use_kernel: bool, shift: int = 0,
                       reset_mu: bool = False,
                       moment_dtype: str = "float32",
-                      telemetry_every: int = 0):
+                      telemetry_every: int = 0, order=None,
+                      offsets: tuple | None = None):
     state0 = _enter_tick(state0, shift, reset_mu, CR1_MU0)
     axis = fleet_axes(mesh)
     cfg = _cr1_cfg(steps, moment_dtype, telemetry_every=telemetry_every)
@@ -808,11 +847,13 @@ def _cr1_impl_sharded(p: FleetProblem, lam, norms, state0: EngineState,
         data_specs=(_fleet_specs(p, axis), P(), _norm_specs(p, axis)),
         init=state0, cfg=cfg)
     out = (D, _sharded_penalties(p, D, mesh, use_kernel), aux["state"])
-    return out + (aux["telemetry"],) if telemetry_every else out
+    if telemetry_every:
+        out += (aux["telemetry"],)
+    return out + _tick_report(p, D, out[1], order, offsets)
 
 
 _CR1_STATIC_SH = ("mesh", "steps", "use_kernel", "shift", "reset_mu",
-                  "moment_dtype", "telemetry_every")
+                  "moment_dtype", "telemetry_every", "offsets")
 _cr1_run_sharded = jax.jit(_cr1_impl_sharded, static_argnames=_CR1_STATIC_SH)
 _cr1_run_sharded_donated = jax.jit(_cr1_impl_sharded,
                                    static_argnames=_CR1_STATIC_SH,
@@ -892,6 +933,7 @@ class CR1:
         steps = ctx.resolved_steps(self)
         tel = _tel_every(ctx)
         warm = ctx.warm
+        order, offsets = report_layout(p)
         if ctx.mesh is None:
             if warm is None:
                 warm = EngineState.cold(jnp.zeros(p.usage.shape))
@@ -900,7 +942,8 @@ class CR1:
                     _cr1_run_checked, _jit_view(p), self.lam, warm,
                     steps=steps, use_kernel=use_kernel, shift=ctx.shift,
                     reset_mu=ctx.reset_mu, moment_dtype=ctx.moment_dtype,
-                    sanitize=True, telemetry_every=tel)
+                    sanitize=True, telemetry_every=tel, order=order,
+                    offsets=offsets)
                 err.throw()
             else:
                 run = _cr1_run_donated if ctx.donate else _cr1_run
@@ -908,28 +951,24 @@ class CR1:
                                 steps=steps, use_kernel=use_kernel,
                                 shift=ctx.shift, reset_mu=ctx.reset_mu,
                                 moment_dtype=ctx.moment_dtype,
-                                telemetry_every=tel)
-            D, pens = _fetch(*out[:2])
-            extras = {"telemetry": ConvergenceTrace.from_aux(out[3])} \
-                if tel else None
-            return _report(p, D, pens, iters=steps, state=out[2],
-                           extras=extras)
-        pp, W = pad_fleet(p, fleet_device_count(ctx.mesh))
-        norms = _cr1_norms(p)
-        if p.is_multiregion:
-            norms = _pad_row_norms(norms, pp.W, CR1_NORM_FILLS)
-        warm = _pad_state(warm, pp.W) if warm is not None \
-            else EngineState.cold(jnp.zeros(pp.usage.shape))
-        run = _cr1_run_sharded_donated if ctx.donate else _cr1_run_sharded
-        out = _dispatch(run, pp, self.lam, norms, warm, mesh=ctx.mesh,
-                        steps=steps, use_kernel=use_kernel,
-                        shift=ctx.shift, reset_mu=ctx.reset_mu,
-                        moment_dtype=ctx.moment_dtype, telemetry_every=tel)
-        D, pens = _fetch(*out[:2])
-        extras = {"telemetry": ConvergenceTrace.from_aux(out[3])} \
-            if tel else None
-        return _report(p, D[:W], pens[:W], iters=steps, state=out[2],
-                       extras=extras)
+                                telemetry_every=tel, order=order,
+                                offsets=offsets)
+        else:
+            pp, _ = pad_fleet(p, fleet_device_count(ctx.mesh))
+            norms = _cr1_norms(p)
+            if p.is_multiregion:
+                norms = _pad_row_norms(norms, pp.W, CR1_NORM_FILLS)
+            warm = _pad_state(warm, pp.W) if warm is not None \
+                else EngineState.cold(jnp.zeros(pp.usage.shape))
+            run = _cr1_run_sharded_donated if ctx.donate \
+                else _cr1_run_sharded
+            out = _dispatch(run, pp, self.lam, norms, warm, mesh=ctx.mesh,
+                            steps=steps, use_kernel=use_kernel,
+                            shift=ctx.shift, reset_mu=ctx.reset_mu,
+                            moment_dtype=ctx.moment_dtype,
+                            telemetry_every=tel, order=order,
+                            offsets=offsets)
+        return _device_report(p, out, iters=steps, tel=tel)
 
     # -- vmapped sweep lane -------------------------------------------------
     @classmethod
@@ -1004,7 +1043,7 @@ def _cr2_impl(p: FleetProblem, refs, state0: EngineState, steps: int,
               outer: int, use_kernel: bool, shift: int = 0,
               reset_mu: bool = False, moment_dtype: str = "float32",
               sanitize: bool = False, telemetry_every: int = 0,
-              norms=None):
+              norms=None, order=None, offsets: tuple | None = None):
     state0 = _enter_tick(state0, shift, reset_mu, CR2_MU0)
     norms = _cr2_norms(p, refs) if norms is None else norms
     objective, eq, project, step_scale = _cr2_pieces(p, refs, use_kernel,
@@ -1017,11 +1056,13 @@ def _cr2_impl(p: FleetProblem, refs, state0: EngineState, steps: int,
                          eq_residual=eq, step_scale=step_scale, init=state0,
                          cfg=cfg, fused_inner=fused)
     out = (D, fleet_penalties(p, D, use_kernel), aux["state"])
-    return out + (aux["telemetry"],) if telemetry_every else out
+    if telemetry_every:
+        out += (aux["telemetry"],)
+    return out + _tick_report(p, D, out[1], order, offsets)
 
 
 _CR2_STATIC = ("steps", "outer", "use_kernel", "shift", "reset_mu",
-               "moment_dtype", "sanitize", "telemetry_every")
+               "moment_dtype", "sanitize", "telemetry_every", "offsets")
 _cr2_run = jax.jit(_cr2_impl, static_argnames=_CR2_STATIC)
 _cr2_run_donated = jax.jit(_cr2_impl, static_argnames=_CR2_STATIC,
                            donate_argnums=(2,))
@@ -1033,7 +1074,8 @@ def _cr2_impl_sharded(p: FleetProblem, refs, norms, state0: EngineState,
                       mesh, steps: int, outer: int, use_kernel: bool,
                       shift: int = 0, reset_mu: bool = False,
                       moment_dtype: str = "float32",
-                      telemetry_every: int = 0):
+                      telemetry_every: int = 0, order=None,
+                      offsets: tuple | None = None):
     state0 = _enter_tick(state0, shift, reset_mu, CR2_MU0)
     axis = fleet_axes(mesh)
     cfg = _cr2_cfg(steps, outer, moment_dtype,
@@ -1056,11 +1098,13 @@ def _cr2_impl_sharded(p: FleetProblem, refs, norms, state0: EngineState,
         data_specs=(_fleet_specs(p, axis), P(axis), _norm_specs(p, axis)),
         init=state0, cfg=cfg)
     out = (D, _sharded_penalties(p, D, mesh, use_kernel), aux["state"])
-    return out + (aux["telemetry"],) if telemetry_every else out
+    if telemetry_every:
+        out += (aux["telemetry"],)
+    return out + _tick_report(p, D, out[1], order, offsets)
 
 
 _CR2_STATIC_SH = ("mesh", "steps", "outer", "use_kernel", "shift",
-                  "reset_mu", "moment_dtype", "telemetry_every")
+                  "reset_mu", "moment_dtype", "telemetry_every", "offsets")
 _cr2_run_sharded = jax.jit(_cr2_impl_sharded, static_argnames=_CR2_STATIC_SH)
 _cr2_run_sharded_donated = jax.jit(_cr2_impl_sharded,
                                    static_argnames=_CR2_STATIC_SH,
@@ -1143,6 +1187,7 @@ class CR2:
         tel = _tel_every(ctx)
         warm = ctx.warm
         refs = jnp.asarray(cr2_reference_fleet(p, self.cap_frac))
+        order, offsets = report_layout(p)
         if ctx.mesh is None:
             if warm is None:
                 warm = EngineState.cold(jnp.zeros(p.usage.shape), n_eq=p.W,
@@ -1153,7 +1198,7 @@ class CR2:
                     outer=self.outer, use_kernel=use_kernel,
                     shift=ctx.shift, reset_mu=ctx.reset_mu,
                     moment_dtype=ctx.moment_dtype, sanitize=True,
-                    telemetry_every=tel)
+                    telemetry_every=tel, order=order, offsets=offsets)
                 err.throw()
             else:
                 run = _cr2_run_donated if ctx.donate else _cr2_run
@@ -1161,31 +1206,28 @@ class CR2:
                                 outer=self.outer, use_kernel=use_kernel,
                                 shift=ctx.shift, reset_mu=ctx.reset_mu,
                                 moment_dtype=ctx.moment_dtype,
-                                telemetry_every=tel)
-            D, pens = _fetch(*out[:2])
-            extras = {"telemetry": ConvergenceTrace.from_aux(out[3])} \
-                if tel else None
-            return _report(p, D, pens, iters=steps * self.outer,
-                           state=out[2], extras=extras)
-        pp, W = pad_fleet(p, fleet_device_count(ctx.mesh))
-        norms = _cr2_norms(p, refs)
-        if p.is_multiregion:
-            norms = _pad_row_norms(norms, pp.W, CR2_NORM_FILLS)
-        refs_p = jnp.concatenate([refs, jnp.zeros(pp.W - W, refs.dtype)])
-        warm = _pad_state(warm, pp.W) if warm is not None \
-            else EngineState.cold(jnp.zeros(pp.usage.shape), n_eq=pp.W,
-                                  mu0=CR2_MU0)
-        run = _cr2_run_sharded_donated if ctx.donate else _cr2_run_sharded
-        out = _dispatch(run, pp, refs_p, norms, warm, mesh=ctx.mesh,
-                        steps=steps, outer=self.outer,
-                        use_kernel=use_kernel, shift=ctx.shift,
-                        reset_mu=ctx.reset_mu,
-                        moment_dtype=ctx.moment_dtype, telemetry_every=tel)
-        D, pens = _fetch(*out[:2])
-        extras = {"telemetry": ConvergenceTrace.from_aux(out[3])} \
-            if tel else None
-        return _report(p, D[:W], pens[:W], iters=steps * self.outer,
-                       state=out[2], extras=extras)
+                                telemetry_every=tel, order=order,
+                                offsets=offsets)
+        else:
+            pp, W = pad_fleet(p, fleet_device_count(ctx.mesh))
+            norms = _cr2_norms(p, refs)
+            if p.is_multiregion:
+                norms = _pad_row_norms(norms, pp.W, CR2_NORM_FILLS)
+            refs_p = jnp.concatenate([refs,
+                                      jnp.zeros(pp.W - W, refs.dtype)])
+            warm = _pad_state(warm, pp.W) if warm is not None \
+                else EngineState.cold(jnp.zeros(pp.usage.shape), n_eq=pp.W,
+                                      mu0=CR2_MU0)
+            run = _cr2_run_sharded_donated if ctx.donate \
+                else _cr2_run_sharded
+            out = _dispatch(run, pp, refs_p, norms, warm, mesh=ctx.mesh,
+                            steps=steps, outer=self.outer,
+                            use_kernel=use_kernel, shift=ctx.shift,
+                            reset_mu=ctx.reset_mu,
+                            moment_dtype=ctx.moment_dtype,
+                            telemetry_every=tel, order=order,
+                            offsets=offsets)
+        return _device_report(p, out, iters=steps * self.outer, tel=tel)
 
     # -- vmapped sweep lane -------------------------------------------------
     @classmethod
@@ -1405,7 +1447,8 @@ def _cr3_market(p: FleetProblem, rho, tax_frac, reg_scale,
     in float64 on the host. The rebates are `_cr3_rebates` over
     `order`/`offsets`, global sums under `mesh` (pad rows count nowhere).
 
-    Returns (D, penalties, state, rho, rounds)."""
+    Returns (D, penalties, state, rho, rounds, the plan's report sums
+    (`_plan_sums`))."""
     mci, bar, c9 = fiscal
     state = _enter_tick(state0, shift, reset_mu, CR3_MU0)
     multi = np.ndim(p.mci) == 2
@@ -1453,7 +1496,7 @@ def _cr3_market(p: FleetProblem, rho, tax_frac, reg_scale,
     k, D, pens, state, rho, _paid = jax.lax.while_loop(
         cond, body, (jnp.int32(0), jnp.zeros_like(x), jnp.zeros(W, x.dtype),
                      state, rho, tuple(jnp.zeros_like(a) for a in rho)))
-    return D, pens, state, rho, k
+    return D, pens, state, rho, k, _plan_sums(p, D, pens, order, offsets)
 
 
 _CR3_STATIC = ("offsets", "steps", "outer", "use_kernel", "shift",
@@ -1583,19 +1626,18 @@ class CR3:
             if pj.W > W:
                 reg_scale = jnp.concatenate(
                     [reg_scale, jnp.ones((pj.W - W, 1), reg_scale.dtype)])
-            order, offsets = _region_layout(p.region, R)
         else:
             reg_scale = 1e-3 / (W * p.T)
-            order, offsets = None, (0, W)
+        order, offsets = report_layout(p)
         collected = self.tax_frac * _cr3_taxes(p)
         twin = _cr3_cleared_donated if ctx.donate else _cr3_cleared
-        D, pens, state, rho, rounds = _dispatch(
+        D, _pens, state, rho, rounds, sums = _dispatch(
             twin, pj, _df.split(np.full(R, float(self.rho))), self.tax_frac,
             reg_scale, state, self.clearing_iters, _cr3_fiscal(p, collected),
             order, offsets=offsets, steps=steps, outer=self.outer,
             use_kernel=use_kernel, shift=ctx.shift, reset_mu=ctx.reset_mu,
             mesh=ctx.mesh)
-        D, pens, rho, rounds = _fetch(D, pens, rho, rounds)
+        D, rho, rounds, sums = _fetch(D, rho, rounds, sums)
         D, rho = D[:W], rho[0].astype(float) + rho[1]
         balanced, deficit = _cr3_balance(p, D, rho, collected, order,
                                          offsets)
@@ -1607,7 +1649,7 @@ class CR3:
                 self.clearing_iters, float(deficit.sum()),
                 float(rho[worst]), "CR3.solve (multi-region)"
                 if p.is_multiregion else "CR3.solve")
-        return _report(p, D, pens[:W],
+        return _report(p, D, sums=sums,
                        iters=steps * self.outer * int(rounds), state=state,
                        extras={"rho": rho if p.is_multiregion
                                else float(rho[0]),
@@ -1638,7 +1680,7 @@ class CR3:
             mu=jnp.full((N,), CR3_MU0))
         collected = np.asarray([pl.tax_frac for pl in policies],
                                float)[:, None] * _cr3_taxes(p)
-        Ds, pens, states, rho, rounds = _dispatch(
+        Ds, _pens, states, rho, rounds, sums = _dispatch(
             _cr3_cleared_lanes, _jit_view(p),
             _df.split([[pl.rho] for pl in policies]),
             np.asarray([pl.tax_frac for pl in policies], float),
@@ -1646,7 +1688,7 @@ class CR3:
             np.asarray([pl.clearing_iters for pl in policies], np.int32),
             _cr3_fiscal(p, collected), offsets=(0, p.W), steps=steps,
             outer=outer, use_kernel=use_kernel)
-        Ds, pens, rho, rounds = _fetch(Ds, pens, rho, rounds)
+        Ds, rho, rounds, sums = _fetch(Ds, rho, rounds, sums)
         rho = rho[0].astype(float) + rho[1]
         out = []
         for i, pl in enumerate(policies):
@@ -1656,7 +1698,8 @@ class CR3:
                 _cr3_unbalanced_warn(pl.clearing_iters, float(deficit[0]),
                                      float(rho[i, 0]), "CR3 sweep")
             out.append(_report(
-                p, Ds[i], pens[i], iters=steps * outer * int(rounds[i]),
+                p, Ds[i], sums=jax.tree_util.tree_map(lambda a: a[i], sums),
+                iters=steps * outer * int(rounds[i]),
                 state=jax.tree_util.tree_map(lambda a: a[i], states),
                 extras={"rho": float(rho[i, 0]),
                         "balanced": bool(balanced[0]),
@@ -1763,23 +1806,37 @@ def _day_impl(p: FleetProblem, xs, state0: EngineState, tick_solve,
     return out + (tel0, tel_w) if telemetry else out
 
 
+def _day_sums(p: FleetProblem, out, n: int, order, offsets):
+    """A day scan's outputs `out` (commitments, last plan, its
+    penalties, ...), with the last plan's `_plan_sums` appended unless
+    `offsets` is None: its window is the first one rolled `n - 1`
+    hours."""
+    if offsets is None:
+        return out
+    return tuple(out) + (_plan_sums(p, out[1], out[2], order, offsets,
+                                    roll=n - 1),)
+
+
 def _day_cr1_impl(p: FleetProblem, lam, mci_stack, state0: EngineState,
                   warm_steps: int, first_steps: int, first_shift: int,
                   first_reset: bool, use_kernel: bool, moment_dtype: str,
-                  sanitize: bool = False, telemetry_every: int = 0):
+                  sanitize: bool = False, telemetry_every: int = 0,
+                  order=None, offsets: tuple | None = None):
     def tick_solve(p_t, mci_t, st, steps, shift, reset_mu):
         p_t = dataclasses.replace(p_t, mci=mci_t)
         return _cr1_impl(p_t, lam, st, steps, use_kernel, shift, reset_mu,
                          moment_dtype, sanitize, telemetry_every)
 
-    return _day_impl(p, mci_stack, state0, tick_solve, warm_steps,
-                     first_steps, first_shift, first_reset,
-                     telemetry=telemetry_every > 0)
+    return _day_sums(p, _day_impl(p, mci_stack, state0, tick_solve,
+                                  warm_steps, first_steps, first_shift,
+                                  first_reset,
+                                  telemetry=telemetry_every > 0),
+                     mci_stack.shape[0], order, offsets)
 
 
 _DAY_CR1_STATIC = ("warm_steps", "first_steps", "first_shift",
                    "first_reset", "use_kernel", "moment_dtype",
-                   "sanitize", "telemetry_every")
+                   "sanitize", "telemetry_every", "offsets")
 _day_cr1 = jax.jit(_day_cr1_impl, static_argnames=_DAY_CR1_STATIC)
 _day_cr1_donated = jax.jit(_day_cr1_impl, static_argnames=_DAY_CR1_STATIC,
                            donate_argnums=(3,))
@@ -1793,7 +1850,8 @@ def _day_cr2_impl(p: FleetProblem, cap_frac, mci_stack,
                   state0: EngineState, warm_steps: int, first_steps: int,
                   first_shift: int, first_reset: bool, outer: int,
                   use_kernel: bool, moment_dtype: str,
-                  sanitize: bool = False, telemetry_every: int = 0):
+                  sanitize: bool = False, telemetry_every: int = 0,
+                  order=None, offsets: tuple | None = None):
     E = jnp.asarray(p.entitlement)[:, None]
 
     def tick_solve(p_t, mci_t, st, steps, shift, reset_mu):
@@ -1805,14 +1863,16 @@ def _day_cr2_impl(p: FleetProblem, cap_frac, mci_stack,
         return _cr2_impl(p_t, refs, st, steps, outer, use_kernel, shift,
                          reset_mu, moment_dtype, sanitize, telemetry_every)
 
-    return _day_impl(p, mci_stack, state0, tick_solve, warm_steps,
-                     first_steps, first_shift, first_reset,
-                     telemetry=telemetry_every > 0)
+    return _day_sums(p, _day_impl(p, mci_stack, state0, tick_solve,
+                                  warm_steps, first_steps, first_shift,
+                                  first_reset,
+                                  telemetry=telemetry_every > 0),
+                     mci_stack.shape[0], order, offsets)
 
 
 _DAY_CR2_STATIC = ("warm_steps", "first_steps", "first_shift",
                    "first_reset", "outer", "use_kernel", "moment_dtype",
-                   "sanitize", "telemetry_every")
+                   "sanitize", "telemetry_every", "offsets")
 _day_cr2 = jax.jit(_day_cr2_impl, static_argnames=_DAY_CR2_STATIC)
 _day_cr2_donated = jax.jit(_day_cr2_impl, static_argnames=_DAY_CR2_STATIC,
                            donate_argnums=(3,))
@@ -1825,7 +1885,8 @@ def _day_cr1_impl_sharded(p: FleetProblem, lam, mci_stack, norms_stack,
                           state0: EngineState, mesh, warm_steps: int,
                           first_steps: int, first_shift: int,
                           first_reset: bool, use_kernel: bool,
-                          moment_dtype: str):
+                          moment_dtype: str, order=None,
+                          offsets: tuple | None = None):
     """The whole-day CR1 scan INSIDE the W-axis shard_map: each device
     scans its row block through every tick of the day, so a full
     rolling-horizon day is still one dispatch on a fleet mesh. Per-tick
@@ -1849,16 +1910,18 @@ def _day_cr1_impl_sharded(p: FleetProblem, lam, mci_stack, norms_stack,
     # check_vma=False: the day scan's tick solves may dispatch the fused
     # al_step pallas_call (use_kernel), which has no shard_map
     # replication rule; every output is explicitly spec'd above.
-    return jax.shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(_fleet_specs(p, axis), P(), P(),
                   _norm_specs(p, axis, stacked=True), state_specs),
         out_specs=(P(None, axis), P(axis), P(axis), state_specs),
         check_vma=False)(p, lam, mci_stack, norms_stack, state0)
+    return _day_sums(p, out, mci_stack.shape[0], order, offsets)
 
 
 _DAY_CR1_STATIC_SH = ("mesh", "warm_steps", "first_steps", "first_shift",
-                      "first_reset", "use_kernel", "moment_dtype")
+                      "first_reset", "use_kernel", "moment_dtype",
+                      "offsets")
 _day_cr1_sharded = jax.jit(_day_cr1_impl_sharded,
                            static_argnames=_DAY_CR1_STATIC_SH)
 _day_cr1_sharded_donated = jax.jit(_day_cr1_impl_sharded,
@@ -1870,7 +1933,8 @@ def _day_cr2_impl_sharded(p: FleetProblem, cap_frac, mci_stack,
                           norms_stack, state0: EngineState, mesh,
                           warm_steps: int, first_steps: int,
                           first_shift: int, first_reset: bool, outer: int,
-                          use_kernel: bool, moment_dtype: str):
+                          use_kernel: bool, moment_dtype: str, order=None,
+                          offsets: tuple | None = None):
     """CR2 twin of `_day_cr1_impl_sharded`: fairness refs are recomputed
     in-scan from the local row block (row-separable), while the fleet-
     global norms (carbon normalizer, residual scale, step scale) ride in
@@ -1896,16 +1960,18 @@ def _day_cr2_impl_sharded(p: FleetProblem, cap_frac, mci_stack,
     # check_vma=False: the day scan's tick solves may dispatch the fused
     # al_step pallas_call (use_kernel), which has no shard_map
     # replication rule; every output is explicitly spec'd above.
-    return jax.shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(_fleet_specs(p, axis), P(), P(),
                   _norm_specs(p, axis, stacked=True), state_specs),
         out_specs=(P(None, axis), P(axis), P(axis), state_specs),
         check_vma=False)(p, cap_frac, mci_stack, norms_stack, state0)
+    return _day_sums(p, out, mci_stack.shape[0], order, offsets)
 
 
 _DAY_CR2_STATIC_SH = ("mesh", "warm_steps", "first_steps", "first_shift",
-                      "first_reset", "outer", "use_kernel", "moment_dtype")
+                      "first_reset", "outer", "use_kernel", "moment_dtype",
+                      "offsets")
 _day_cr2_sharded = jax.jit(_day_cr2_impl_sharded,
                            static_argnames=_DAY_CR2_STATIC_SH)
 _day_cr2_sharded_donated = jax.jit(_day_cr2_impl_sharded,
@@ -2033,6 +2099,7 @@ def _solve_day(problem: FleetProblem, policy, mci_stack,
     first_shift, first_reset = (0, False) if cold else (ctx.shift or 1,
                                                         True)
     stack = jnp.asarray(mci_stack)
+    order, offsets = report_layout(problem)
     if not isinstance(policy, (CR1, CR2)):
         raise NotImplementedError(
             f"solve_day supports CR1/CR2 (pure scannable engine "
@@ -2050,25 +2117,25 @@ def _solve_day(problem: FleetProblem, policy, mci_stack,
         if isinstance(policy, CR1):
             run = _day_cr1_sharded_donated if ctx.donate \
                 else _day_cr1_sharded
-            committed, D, pens, state = _dispatch(
+            out = _dispatch(
                 run, pp, policy.lam, stack, norms_stack, state0,
                 mesh=ctx.mesh,
                 warm_steps=warm_steps, first_steps=first_steps,
                 first_shift=first_shift, first_reset=first_reset,
-                use_kernel=use_kernel, moment_dtype=ctx.moment_dtype)
+                use_kernel=use_kernel, moment_dtype=ctx.moment_dtype,
+                order=order, offsets=offsets)
             mult = 1
         else:
             run = _day_cr2_sharded_donated if ctx.donate \
                 else _day_cr2_sharded
-            committed, D, pens, state = _dispatch(
+            out = _dispatch(
                 run, pp, policy.cap_frac, stack, norms_stack, state0,
                 mesh=ctx.mesh, warm_steps=warm_steps,
                 first_steps=first_steps, first_shift=first_shift,
                 first_reset=first_reset, outer=policy.outer,
-                use_kernel=use_kernel, moment_dtype=ctx.moment_dtype)
+                use_kernel=use_kernel, moment_dtype=ctx.moment_dtype,
+                order=order, offsets=offsets)
             mult = policy.outer
-        D, pens, committed = _fetch(D, pens, committed)
-        committed, D, pens = committed[:, :W], D[:W], pens[:W]
     else:
         pj = _jit_view(problem)
         W = problem.W
@@ -2082,7 +2149,7 @@ def _solve_day(problem: FleetProblem, policy, mci_stack,
                     first_steps=first_steps, first_shift=first_shift,
                     first_reset=first_reset, use_kernel=use_kernel,
                     moment_dtype=ctx.moment_dtype, sanitize=True,
-                    telemetry_every=tel)
+                    telemetry_every=tel, order=order, offsets=offsets)
                 err.throw()
             else:
                 run = _day_cr1_donated if ctx.donate else _day_cr1
@@ -2091,7 +2158,8 @@ def _solve_day(problem: FleetProblem, policy, mci_stack,
                     warm_steps=warm_steps,
                     first_steps=first_steps, first_shift=first_shift,
                     first_reset=first_reset, use_kernel=use_kernel,
-                    moment_dtype=ctx.moment_dtype, telemetry_every=tel)
+                    moment_dtype=ctx.moment_dtype, telemetry_every=tel,
+                    order=order, offsets=offsets)
             mult = 1
         else:
             state0 = ctx.warm if ctx.warm is not None else EngineState.cold(
@@ -2104,7 +2172,7 @@ def _solve_day(problem: FleetProblem, policy, mci_stack,
                     first_shift=first_shift, first_reset=first_reset,
                     outer=policy.outer, use_kernel=use_kernel,
                     moment_dtype=ctx.moment_dtype, sanitize=True,
-                    telemetry_every=tel)
+                    telemetry_every=tel, order=order, offsets=offsets)
                 err.throw()
             else:
                 run = _day_cr2_donated if ctx.donate else _day_cr2
@@ -2113,9 +2181,9 @@ def _solve_day(problem: FleetProblem, policy, mci_stack,
                     warm_steps=warm_steps, first_steps=first_steps,
                     first_shift=first_shift, first_reset=first_reset,
                     outer=policy.outer, use_kernel=use_kernel,
-                    moment_dtype=ctx.moment_dtype, telemetry_every=tel)
+                    moment_dtype=ctx.moment_dtype, telemetry_every=tel,
+                    order=order, offsets=offsets)
             mult = policy.outer
-        committed, D, pens, state = out[:4]
         if tel:
             # One ConvergenceTrace per tick: tick 0's trace is separate
             # (different step budget → different sample count), warm
@@ -2123,17 +2191,17 @@ def _solve_day(problem: FleetProblem, policy, mci_stack,
             traces = (ConvergenceTrace.from_aux(out[4]),)
             if out[5] is not None:
                 traces += ConvergenceTrace.split(out[5])
-        D, pens, committed = _fetch(D, pens, committed)
+    # (commitments, last plan, its penalties, state, [telemetry,] sums):
+    # the commitments and the sums come back, the plan stays on device.
+    committed, sums = _fetch(out[0], out[-1])
+    committed = committed[:, :W]
     iters = (first_steps * mult,) + (warm_steps * mult,) * (n - 1)
-    # Reporting view: the final tick's rolled window.
-    p_last = dataclasses.replace(
-        problem, mci=mci_stack[-1],
-        usage=np.roll(np.asarray(problem.usage), -(n - 1), axis=1),
-        jobs=np.roll(np.asarray(problem.jobs), -(n - 1), axis=1),
-        upper=None if problem.upper is None
-        else np.roll(np.asarray(problem.upper), -(n - 1), axis=1))
-    last = _report(p_last, D, pens, iters=iters[-1], state=state,
-                   extras={"telemetry": traces} if tel else None)
+    # Reporting view: the final tick's forecast (the sums already cover
+    # its rolled window).
+    last = _report(dataclasses.replace(problem, mci=mci_stack[-1]),
+                   OnDevice(out[1], W), iters=iters[-1], state=out[3],
+                   extras={"telemetry": traces} if tel else None,
+                   sums=sums, hour0=committed[-1])
     return DayResult(committed=committed, last=last, inner_steps=iters)
 
 

@@ -1,7 +1,7 @@
 """Double-float arithmetic: a value carried as the unevaluated sum
 `hi + lo` of two float32 arrays (about 48 significant bits), for the few
 device sums whose decisions must agree with float64 ones on a chip that
-has no float64 — CR3's Eq.-6 fiscal sums (`api._cr3_market`).
+has no float64 — CR3's Eq.-6 fiscal sums (`api._cr3_market`) and a solve's report sums (`fleet_solver.report_sums`).
 
 A pair is a tuple `(hi, lo)` with |lo| at most half an ulp of hi. Sums
 use Knuth's error-free TwoSum, which needs round-to-nearest float32
@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["add", "div", "gt", "mul", "split", "tree_sum"]
+__all__ = ["add", "div", "gt", "mul", "pair", "reduce_sum", "split",
+           "tree_sum", "value"]
 
 
 def split(x) -> tuple[np.ndarray, np.ndarray]:
@@ -24,6 +25,19 @@ def split(x) -> tuple[np.ndarray, np.ndarray]:
     x = np.asarray(x, np.float64)
     hi = x.astype(np.float32)
     return hi, (x - hi).astype(np.float32)
+
+
+def value(x) -> np.ndarray:
+    """Host-side: the float64 value of the pair `x` (`split`'s inverse)."""
+    return np.asarray(x[0], np.float64) + np.asarray(x[1], np.float64)
+
+
+def pair(x):
+    """`x` as a pair: a pair as it is, an array with a zero low part."""
+    if isinstance(x, tuple):
+        return x
+    x = jnp.asarray(x)
+    return x, jnp.zeros_like(x)
 
 
 def _two_sum(a, b):
@@ -93,3 +107,13 @@ def tree_sum(x, axis: int):
         od = lambda a: jax.lax.slice_in_dim(a, 1, None, 2, axis)  # noqa: E731
         hi, lo = add((ev(hi), ev(lo)), (od(hi), od(lo)))
     return jnp.squeeze(hi, axis), jnp.squeeze(lo, axis)
+
+
+def reduce_sum(x, axis: int):
+    """Sum of the pair (or array) `x` over `axis` by the compiler's own
+    reduction, each step an `add`: one pass over `x` in whatever order
+    suits its layout, within about n·2^-48 of Σ|x| for n terms in any
+    order. The order may differ between programs: use `tree_sum` where
+    the bits must not depend on the program or the layout."""
+    zero = jnp.zeros((), pair(x)[0].dtype)
+    return jax.lax.reduce(pair(x), (zero, zero), add, (axis,))

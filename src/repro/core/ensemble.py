@@ -56,7 +56,8 @@ from repro.core.engine import EngineConfig, EngineState, al_minimize
 from repro.core.fleet_solver import (CR1_MU0, CR2_MU0, PAD_FILLS,
                                      FleetProblem, _fleet_specs, _jit_view,
                                      cr2_reference_fleet, fleet_penalties,
-                                     pad_fleet, resolve_use_kernel)
+                                     held_report_sums, pad_fleet,
+                                     report_numbers, resolve_use_kernel)
 from repro.core.metrics import jain_index, max_min_ratio
 from repro.core.regional import (CR1_NORM_FILLS, CR2_NORM_FILLS, cr1_norms,
                                  cr2_norms, norm_specs, pad_row_norms,
@@ -445,30 +446,21 @@ def _stack_arrays(base: FleetProblem, stack: ScenarioStack):
 
 def _result_from_stacks(base: FleetProblem, stack: ScenarioStack, policy,
                         D: np.ndarray, pens: np.ndarray, batched: bool,
-                        ) -> EnsembleResult:
-    """Vectorized `fleet_solver._report` over the scenario axis."""
+                        ) -> tuple[EnsembleResult, np.ndarray]:
+    """`fleet_solver._report` over the scenario axis: every scenario's
+    report sums in one call (`held_report_sums`), finished in float64.
+    Returns the result and each scenario's carbon base (S,)."""
     mci, usage, ent = _stack_arrays(base, stack)
-    if mci.ndim == 3:                # multi-region: (S, R, T) MCI stacks
-        wmci = mci[:, np.asarray(base.region), :]                # (S, W, T)
-        carbon_base = (usage * wmci).sum(axis=(1, 2))            # (S,)
-        car = (D * wmci).sum(axis=(1, 2))
-    else:
-        carbon_base = (usage.sum(axis=1) * mci).sum(axis=1)      # (S,)
-        car = np.einsum("swt,st->s", D, mci)
-    n_days = max(1, base.T // base.day_hours)
-    span = n_days * base.day_hours
-    sums = D[:, :, :span].reshape(D.shape[0], base.W, n_days,
-                                  base.day_hours).sum(-1)
-    is_batch = np.asarray(base.is_batch, bool)
-    viol = np.abs(sums[:, is_batch]).max(axis=(1, 2)) if is_batch.any() \
-        else np.zeros(D.shape[0])
+    sums = held_report_sums(base, D, pens, usage=usage, lanes=True)
+    car, pen, viol, carbon_base = report_numbers(
+        mci.reshape(stack.S, -1, base.T), ent.sum(axis=1), sums)
     labels = tuple(stack.label(s) for s in range(stack.S))
     return EnsembleResult(
-        policy=policy, labels=labels,
-        carbon_reduction_pct=100 * car / carbon_base,
-        total_penalty_pct=100 * pens.sum(axis=1) / ent.sum(axis=1),
-        penalties=pens, entitlement=ent, preservation_violation=viol,
-        D=D, extras=tuple({} for _ in range(stack.S)), batched=batched)
+        policy=policy, labels=labels, carbon_reduction_pct=car,
+        total_penalty_pct=pen, penalties=pens, entitlement=ent,
+        preservation_violation=viol, D=D,
+        extras=tuple({} for _ in range(stack.S)),
+        batched=batched), carbon_base
 
 
 # ---------------------------------------------------------------------------
@@ -529,17 +521,18 @@ def evaluate_ensemble(problem: FleetProblem, policy, scenarios, *,
     use_kernel = resolve_use_kernel(ctx.use_kernel)
     D, pens, _ = _run_batched(policy, problem, stack, steps=steps,
                               use_kernel=use_kernel, mesh=ctx.mesh)
-    res = _result_from_stacks(problem, stack, policy, D, pens,
-                              batched=True)
-    return _apply_migration_credit(problem, stack, res)
+    res, carbon_base = _result_from_stacks(problem, stack, policy, D, pens,
+                                           batched=True)
+    return _apply_migration_credit(problem, stack, res, carbon_base)
 
 
 def _apply_migration_credit(base: FleetProblem, stack: ScenarioStack,
-                            res: EnsembleResult) -> EnsembleResult:
+                            res: EnsembleResult,
+                            carbon_base: np.ndarray) -> EnsembleResult:
     """Per-scenario migration post-stage for the batched lane — exactly
     what `api.solve`'s `_maybe_migrate` applies in the loop lane, so the
     two lanes stay in parity on multi-region problems with a usable
-    topology."""
+    topology. `carbon_base` is each scenario's report denominator."""
     if (base.topology is None or not base.is_multiregion
             or not np.any(np.asarray(base.topology.bandwidth) > 0.0)):
         return res
@@ -548,9 +541,7 @@ def _apply_migration_credit(base: FleetProblem, stack: ScenarioStack,
     extras = []
     for s, ps in enumerate(stack.problems(base)):
         plan = fleet_migration(ps, np.asarray(res.D[s]))
-        wmci = np.asarray(ps.mci)[np.asarray(ps.region)]
-        carbon_base = float((np.asarray(ps.usage) * wmci).sum())
-        car[s] += 100.0 * plan.net_saved / carbon_base
+        car[s] += 100.0 * plan.net_saved / carbon_base[s]
         extras.append({"migration": plan})
     return dataclasses.replace(res, carbon_reduction_pct=car,
                                extras=tuple(extras))

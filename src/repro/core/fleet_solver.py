@@ -51,14 +51,16 @@ removed once nothing imports them.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
-from typing import Sequence
+from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.core import dfloat as _df
 from repro.core import features as feat
 from repro.core.engine import EngineState
 from repro.core.penalty import PenaltyModel
@@ -550,10 +552,43 @@ def _enter_tick(state: EngineState, shift: int, reset_mu: bool,
     return state
 
 
+class OnDevice(NamedTuple):
+    """A plan the solve left on the device: the plan is `array[:rows]`
+    (`array` may carry device pad rows)."""
+    array: jax.Array
+    rows: int
+
+
+class _PulledOnRead:
+    """`FleetSolveResult.D`: a host array, or an `OnDevice` plan that the
+    first read pulls to the host (a `dr.solve.pull` span counting its
+    `d2h_bytes` and one `plan_pulls`) and keeps."""
+
+    def __get__(self, obj, owner=None):
+        if obj is None:
+            raise AttributeError("D")    # a dataclass field with no default
+        D = obj.__dict__["_D"]
+        if isinstance(D, OnDevice):
+            with span("dr.solve.pull") as sp:
+                host = np.asarray(D.array)
+                sp.count("d2h_bytes", host.nbytes)
+                sp.count("plan_pulls", 1)
+            D = obj.__dict__["_D"] = host[:D.rows]
+        return D
+
+    def __set__(self, obj, value):
+        obj.__dict__["_D"] = value
+
+
 @dataclasses.dataclass(frozen=True)
 class FleetSolveResult:
-    """Uniform result of one fleet policy solve (any policy)."""
-    D: np.ndarray
+    """Uniform result of one fleet policy solve (any policy).
+
+    `D` is the (W, T) plan as a host array. A solve may leave it on the
+    device (`OnDevice`); it is then pulled on first read. `hour0` is the
+    plan's hour 0, the hour a streaming tick commits, always on the host.
+    """
+    D: np.ndarray = _PulledOnRead()
     carbon_reduction_pct: float
     total_penalty_pct: float
     iters: int
@@ -565,6 +600,10 @@ class FleetSolveResult:
     # rebates) and "fiscal_deficit" (rebates − taxes when they didn't,
     # NP·kgCO2/MWh) here; CR1/CR2 leave it empty.
     extras: dict = dataclasses.field(default_factory=dict)
+    hour0: np.ndarray | None = None
+    # Carbon of the window's usage, the denominator of
+    # carbon_reduction_pct (a migration credit's too).
+    carbon_base: float | None = None
 
     @property
     def balanced(self) -> bool:
@@ -610,33 +649,128 @@ def _projection(p: FleetProblem, lo: Array, hi: Array):
     return project
 
 
-def _report(p: FleetProblem, D: np.ndarray, pens: np.ndarray,
-            iters: int, state: EngineState | None = None,
-            extras: dict | None = None) -> FleetSolveResult:
-    """The result of a solve from its plan `D` and penalties `pens`, both
-    on the host: carbon, penalty and preservation sums (`dr.solve.report`
-    span)."""
+class ReportSums(NamedTuple):
+    """A plan's report as the device sums it (`report_sums`), each a
+    `dfloat` pair; `report_numbers` finishes it in float64."""
+    plan: tuple      #: (R, T): Σ of the plan over each region's rows, by hour
+    usage: tuple     #: (R, T): the same of the window's usage
+    penalty: tuple   #: (): Σ of the penalties
+    day_max: tuple   #: (): largest |Σ over a day| of a batch row's plan
+
+
+def report_layout(p: FleetProblem) -> tuple:
+    """`(order, offsets)` of `p`'s rows for `report_sums`: grouped by
+    region (`regional.region_layout`), or one run of all W rows."""
+    if not p.is_multiregion:
+        return None, (0, p.W)
+    from repro.core.regional import region_layout
+    return region_layout(p.region, p.R)
+
+
+def report_sums(D, pens, usage, is_batch, order, offsets: tuple,
+                day_hours: int) -> ReportSums:
+    """The sums a solve's report reads, traced, in double-float
+    (`dfloat`: float64 accuracy from float32 operations): the hour
+    totals of the plan `D` and of `usage` over each region's rows
+    (`regional.region_sums` over `order`/`offsets`, see `report_layout`),
+    the penalties' total and the largest |day total| over batch rows.
+    `D`, `pens` and `usage` are arrays or pairs; rows past `offsets[-1]`
+    (device pad rows) count nowhere.
+
+    Each sum is one pass of the compiler's reduction (`dfloat.reduce_sum`),
+    within n·2^-48 of Σ|x| for n terms. The usage is
+    the float32 copy the solve ran on, each entry within 2^-24 of the
+    fleet's float64 usage, so the carbon base can stand up to 6e-8 (in
+    practice ~1e-10, the roundings being independent) from a float64 sum
+    of the float64 usage."""
+    from repro.core.regional import region_sums
+    W = offsets[-1]
+    D, usage = _df.pair(D), _df.pair(usage)
+    T = D[0].shape[1]
+    n_days = max(1, T // day_hours)
+    hours = n_days * day_hours
+    days = _df.reduce_sum(tuple(
+        a[:W, :hours].reshape(W, n_days, day_hours) for a in D), axis=2)
+    isb = jnp.asarray(is_batch)[:W, None]
+    hi = jnp.where(isb, jnp.abs(days[0]), 0.0)
+    lo = jnp.where(isb, jnp.where(days[0] < 0, -days[1], days[1]), 0.0)
+    top = hi.max()
+    return ReportSums(
+        plan=region_sums(D, order, offsets, total=_df.reduce_sum),
+        usage=region_sums(usage, order, offsets, total=_df.reduce_sum),
+        penalty=_df.reduce_sum(tuple(a[:W] for a in _df.pair(pens)), 0),
+        day_max=(top, jnp.where(hi == top, lo, -jnp.inf).max()))
+
+
+@functools.partial(jax.jit, static_argnames=("offsets", "day_hours",
+                                             "lanes"))
+def _held_sums(D, pens, usage, is_batch, order, *, offsets: tuple,
+               day_hours: int, lanes: bool = False) -> ReportSums:
+    """`report_sums` of arrays a caller holds, in one call; with `lanes`
+    over a leading lane axis of `D`, `pens` and `usage`."""
+    def one(d, pn, u):
+        return report_sums(d, pn, u, is_batch, order, offsets, day_hours)
+    return jax.vmap(one)(D, pens, usage) if lanes else one(D, pens, usage)
+
+
+def held_report_sums(p: FleetProblem, D, pens, *, usage=None,
+                     lanes: bool = False) -> ReportSums:
+    """`report_sums` of a plan of `p` that the caller holds, pulled to
+    the host. A host plan and penalties go up as float32 pairs
+    (`dfloat.split`: a float64 plan is summed exactly), device arrays as
+    they are; the usage goes up in float32, as every solve holds it, so
+    a plan reports the same numbers from any lane. With `lanes`, `D`,
+    `pens` and `usage` (default `p.usage`) carry a leading lane axis."""
+    def held(a):
+        a = a.array if isinstance(a, OnDevice) else a
+        return a if isinstance(a, jax.Array) else _df.split(a)
+
+    order, offsets = report_layout(p)
+    usage = p.usage if usage is None else usage
+    return jax.device_get(_held_sums(
+        held(D), held(pens), np.asarray(usage, np.float32),
+        np.asarray(p.is_batch), order, offsets=offsets,
+        day_hours=p.day_hours, lanes=lanes))
+
+
+def report_numbers(mci: np.ndarray, entitlement_total, sums: ReportSums):
+    """(carbon_reduction_pct, total_penalty_pct, preservation_violation,
+    carbon_base) in float64 from pulled `sums`: carbon is
+    Σ_{r,t} mci[r,t]·plan[r,t], i.e. Σ_w ⟨D_w, mci_region(w)⟩ grouped by
+    region, over `carbon_base`, the same sum of the usage. `mci` is
+    (R, T) float64; a leading lane axis on `mci`, `entitlement_total`
+    and `sums` rides along."""
+    car = (mci * _df.value(sums.plan)).sum(axis=(-2, -1))
+    base = (mci * _df.value(sums.usage)).sum(axis=(-2, -1))
+    return (100 * car / base,
+            100 * _df.value(sums.penalty) / entitlement_total,
+            _df.value(sums.day_max), base)
+
+
+def _report(p: FleetProblem, D, pens=None, *, iters: int,
+            state: EngineState | None = None, extras: dict | None = None,
+            sums: ReportSums | None = None,
+            hour0: np.ndarray | None = None) -> FleetSolveResult:
+    """The result of a solve of `p` (true rows) from its plan `D` (a host
+    array, or `OnDevice`) and its `sums` (`report_sums`, pulled); without
+    `sums` they are computed from `D` and `pens` by one jitted call
+    (`held_report_sums`). The host finishes them in float64 under the
+    `dr.solve.report` span (`report_numbers`); `hour0` defaults to a
+    copy of the host plan's hour 0 (a copy, so that whoever keeps the
+    hour does not keep the plan)."""
     with span("dr.solve.report"):
-        mci = np.asarray(p.mci)
-        if mci.ndim == 2:
-            wmci = mci[np.asarray(p.region)]
-            carbon_base = float((np.asarray(p.usage) * wmci).sum())
-            car = float((D * wmci).sum())
-        else:
-            carbon_base = float((np.asarray(p.usage).sum(0) * mci).sum())
-            car = float((D @ mci).sum())
-        n_days = max(1, p.T // p.day_hours)
-        hours = n_days * p.day_hours
-        sums = D[:, :hours].reshape(p.W, n_days, p.day_hours).sum(-1)
-        is_batch = np.asarray(p.is_batch)
-        viol = float(np.abs(sums[is_batch]).max()) if is_batch.any() \
-            else 0.0
+        if sums is None:
+            sums = held_report_sums(p, D, pens)
+        car, pen, viol, base = report_numbers(
+            np.asarray(p.mci, float).reshape(-1, p.T),
+            np.asarray(p.entitlement, float).sum(), sums)
+        if hour0 is None:
+            hour0 = np.array(D[:, 0])
         return FleetSolveResult(
-            D=D, carbon_reduction_pct=100 * car / carbon_base,
-            total_penalty_pct=100 * float(pens.sum())
-            / float(np.asarray(p.entitlement).sum()),
-            iters=iters, preservation_violation=viol, state=state,
-            extras=extras or {})
+            D=D, carbon_reduction_pct=float(car),
+            total_penalty_pct=float(pen), iters=iters,
+            preservation_violation=float(viol), state=state,
+            extras=extras or {}, hour0=hour0, carbon_base=float(base))
 
 
 # ---------------------------------------------------------------------------

@@ -24,10 +24,10 @@ Two flavors live here, matching the two places reductions run:
   * **Traced per-region totals** (``region_layout`` on the host,
     ``region_sums`` in the trace): CR3's Eq.-6 rebates paid per region,
     summed on the device inside the clearing loop in double-float
-    (``dfloat``), so the loop's balance test agrees with a float64 one.
-    Each region's rows are summed by one fixed pairwise tree, so a
-    region's total is the same, bit for bit, alone or inside a
-    multi-region fleet.
+    (``dfloat``), so the loop's balance test agrees with a float64 one,
+    and a solve's report sums (``fleet_solver.report_sums``). CR3 sums
+    each region's rows by one fixed pairwise tree, so a region's total
+    is the same, bit for bit, alone or inside a multi-region fleet.
 
   * **Host-side, exact numpy** (``region_totals``): per-region
     accumulation of (W,) or (W, T) row weights — CR3's taxes
@@ -105,23 +105,33 @@ def region_layout(region, R: int):
     return np.argsort(region, kind="stable").astype(np.int32), offsets
 
 
-def region_sums(x, order, offsets: tuple):
-    """Per-region totals of the per-row values `x` ((W,) or (W, T)),
-    traced, as a `dfloat` pair of (R,) or (R, T) arrays: each region's
-    rows (in `order`, bounded by `offsets`; see `region_layout`) are
-    summed by one fixed pairwise tree (`dfloat.tree_sum`), with float64
-    accuracy from float32 operations. Padding a run with zeros leaves
-    every sum of the tree as it was, so a region's total is the same
-    reduction of the same values, bit for bit, whether the region is
-    solved alone or inside a multi-region fleet. Rows past
-    `offsets[-1]` (device pad rows) count nowhere."""
-    if order is not None:
-        x = x[order]
+def region_sums(x, order, offsets: tuple, *, total=dfloat.tree_sum):
+    """Per-region totals of the per-row values `x` ((W,) or (W, T), or a
+    `dfloat` pair of such arrays), traced, as a `dfloat` pair of (R,) or
+    (R, T) arrays: each region's rows (in `order`, bounded by `offsets`;
+    see `region_layout`) are summed by `total`, with float64 accuracy
+    from float32 operations. Rows past `offsets[-1]` (device pad rows)
+    count nowhere.
+
+    By default one fixed pairwise tree (`dfloat.tree_sum`): padding a run
+    with zeros leaves every sum of the tree as it was, so a region's
+    total is the same reduction of the same values, bit for bit, whether
+    the region is solved alone or inside a multi-region fleet (CR3's
+    market relies on this). `dfloat.reduce_sum` reads the values once,
+    in the compiler's order (a solve's report sums)."""
     width = max(b - a for a, b in zip(offsets[:-1], offsets[1:]))
-    tail = ((0, 0),) * (x.ndim - 1)
-    runs = jnp.stack([jnp.pad(x[a:b], ((0, width - (b - a)),) + tail)
-                      for a, b in zip(offsets[:-1], offsets[1:])])
-    return dfloat.tree_sum((runs, jnp.zeros_like(runs)), axis=1)
+
+    def runs(a):
+        if order is not None:
+            a = a[order]
+        tail = ((0, 0),) * (a.ndim - 1)
+        return jnp.stack([jnp.pad(a[lo:hi], ((0, width - (hi - lo)),) + tail)
+                          for lo, hi in zip(offsets[:-1], offsets[1:])])
+
+    if isinstance(x, tuple):
+        return total(tuple(runs(a) for a in x), axis=1)
+    x = runs(x)
+    return total((x, jnp.zeros_like(x)), axis=1)
 
 
 def region_totals(region, weights, R: int) -> np.ndarray:

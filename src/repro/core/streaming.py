@@ -77,7 +77,8 @@ class TickResult:
 
     `plan` (the full-horizon solve: D matrix, engine state, ...) is only
     retained on the *latest* tick — older history entries drop it so a
-    long-lived controller holds O(W) per tick, not O(W·T)."""
+    long-lived controller holds O(W) per tick, not O(W·T). Its D stays
+    on the device until `plan.D` is read; a tick pulls hour 0 only."""
     tick: int
     committed: np.ndarray        # (W,) NP adjustments enforced this hour
     forecast_mci: float | np.ndarray   # hour-0 forecast ((R,) multi-region)
@@ -445,7 +446,9 @@ class RollingHorizonSolver:
                 self._state = plan.state
                 self._prev_forecast = mci_hat
                 self._tick = tick + 1
-                committed = np.asarray(plan.D[:, 0])
+                # hour 0 came back with the solve; the full plan stays on
+                # the device until a caller reads `plan.D`
+                committed = plan.hour0
                 out = TickResult(
                     tick=tick, committed=committed,
                     forecast_mci=(float(mci_hat[0]) if mci_hat.ndim == 1

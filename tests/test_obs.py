@@ -408,6 +408,13 @@ def _children(recs, parent):
     return [r for r in recs if r.parent == parent.id]
 
 
+def _sums_bytes(R: int, T: int) -> int:
+    """What a solve's report sums pull (`fleet_solver.ReportSums`): the
+    plan's and the usage's (R, T) hour totals and two scalars, each a
+    float32 pair."""
+    return (2 * R * T + 2) * 2 * 4
+
+
 def _host_bytes(p) -> int:
     """What a solve uploads: every array of the fleet in float32 (x64
     off), the bool column as bytes, and the float λ."""
@@ -458,11 +465,32 @@ def test_cr1_stream_counts_bytes_as_the_arrays_nbytes(fp, cr1_stream):
     assert up == [_host_bytes(fp)] * 3
     pulls = [r.counts["d2h_bytes"] for r in recs
              if r.name == "dr.solve.pull"]
-    D = report.ticks[-1].plan.D
-    assert D.dtype == np.float32
-    assert pulls == [D.nbytes + fp.W * 4] * 3       # the plan and pens
+    committed = report.ticks[-1].committed
+    assert committed.dtype == np.float32
+    # hour 0 and the report sums; the plan stays on the device
+    assert pulls == [committed.nbytes + _sums_bytes(1, fp.T)] * 3
     assert [r.counts["dispatches"] for r in recs
             if r.name == "dr.solve.dispatch"] == [1, 1, 1]
+
+
+def test_cr1_stream_tick_leaves_the_plan_on_the_device(fp, cr1_stream):
+    """A tick is one dispatch and pulls no plan (`plan_pulls` 0); reading
+    the last tick's `plan.D` afterwards is one `dr.solve.pull` of the
+    whole plan, outside any tick, and the second read pulls nothing."""
+    report, recs, _ = cr1_stream
+    for t in (r for r in recs if r.name == "dr.tick"):
+        kids = [r for r in recs if r.tick == t.tick and r is not t]
+        assert sum(k.counts.get("dispatches", 0) for k in kids) == 1
+        assert sum(k.counts.get("plan_pulls", 0) for k in kids) == 0
+    plan = report.ticks[-1].plan
+    n0 = len(spans.recorded())
+    D = plan.D
+    assert plan.D is D
+    (pull,) = spans.recorded()[n0:]
+    assert pull.name == "dr.solve.pull" and pull.tick is None
+    assert pull.counts == {"d2h_bytes": D.nbytes, "plan_pulls": 1}
+    assert D.shape == (fp.W, fp.T) and D.dtype == np.float32
+    np.testing.assert_array_equal(D[:, 0], report.ticks[-1].committed)
 
 
 def test_phases_cover_the_tick(cr1_stream):
@@ -481,7 +509,8 @@ def test_tick_event_carries_counted_dispatches_phases_and_bytes(
     for ev in ticks:
         assert ev["dispatches"] == 1
         assert ev["h2d_bytes"] == _host_bytes(fp)
-        assert ev["d2h_bytes"] == fp.W * fp.T * 4 + fp.W * 4
+        # hour 0 and the report sums, not the plan
+        assert ev["d2h_bytes"] == fp.W * 4 + _sums_bytes(1, fp.T)
         assert set(ev["phases_ms"]) == set(TICK_PHASES + SOLVE_PHASES)
         # the latency is the tick span up to the ledger write
         span_s = spans_by_tick[ev["tick"]].elapsed_ns * 1e-9
@@ -535,9 +564,10 @@ def test_cr3_tick_counts_every_clearing_round(tmp_path):
     assert ev["dispatches"] == 1
     assert ev["h2d_bytes"] == sum(r.counts.get("h2d_bytes", 0)
                                   for r in recs)
-    # the plan and penalties, then ρ as a float32 pair (one region) and
-    # the round count
-    assert ev["d2h_bytes"] == (tight.W * tight.T + tight.W + 2 + 1) * 4
+    # the plan (the float64 balance reads it), ρ as a float32 pair (one
+    # region), the round count and the report sums
+    assert ev["d2h_bytes"] == ((tight.W * tight.T + 2 + 1) * 4
+                               + _sums_bytes(1, tight.T))
 
 
 def test_solve_day_spans_one_dispatch(fp):
@@ -550,8 +580,9 @@ def test_solve_day_spans_one_dispatch(fp):
     assert root.meta == {"policy": "cr1", "lane": "day"}
     assert [k.name for k in _children(recs, root)] == SOLVE_PHASES
     (pull,) = [r for r in recs if r.name == "dr.solve.pull"]
-    # the plan and pens of the last tick, and the (n, W) commitments
-    assert pull.counts["d2h_bytes"] == (fp.W * fp.T + fp.W + 3 * fp.W) * 4
+    # the (n, W) commitments and the last plan's report sums; the plan
+    # stays on the device
+    assert pull.counts["d2h_bytes"] == 3 * fp.W * 4 + _sums_bytes(1, fp.T)
 
 
 def test_importing_obs_loads_neither_jax_nor_core():

@@ -6,11 +6,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import api
 from repro.core.api import CR1, CR2, SolveContext, solve
 from repro.core.carbon import ForecastStream, caiso_2021
 from repro.core.engine import EngineConfig, EngineState, al_minimize
-from repro.core.fleet_solver import synthetic_fleet, synthetic_regional_fleet
+from repro.core.fleet_solver import (_jit_view, synthetic_fleet,
+                                     synthetic_regional_fleet)
 from repro.core.streaming import RollingHorizonSolver
+from repro.obs import spans
 
 
 # ---------------------------------------------------------------------------
@@ -457,3 +460,61 @@ def test_rolling_horizon_cr2_carries_multipliers():
     st = report.ticks[-1].plan.state
     assert st.lam_eq.shape == (4,)
     assert np.isfinite(np.asarray(st.lam_eq)).all()
+
+
+# ---------------------------------------------------------------------------
+# A tick pulls hour 0 and the report sums; the plan stays on the device
+# ---------------------------------------------------------------------------
+def _tick_stream(donate: bool = False):
+    p = synthetic_fleet(6)
+    stream = ForecastStream.caiso(n_ticks=3, horizon=p.T, seed=4)
+    return p, RollingHorizonSolver(p, stream, policy="cr1", cold_steps=40,
+                                   warm_steps=10, use_kernel=False,
+                                   donate=donate)
+
+
+def test_tick_is_one_dispatch_and_pulls_no_plan():
+    _, rhs = _tick_stream()
+    spans.clear()
+    outs = [rhs.step() for _ in range(2)]
+    recs = spans.recorded()
+    for tick in (0, 1):
+        counts = [r.counts for r in recs if r.tick == tick]
+        assert sum(c.get("dispatches", 0) for c in counts) == 1
+        assert sum(c.get("plan_pulls", 0) for c in counts) == 0
+    assert [o.committed.shape for o in outs] == [(6,), (6,)]
+
+
+def test_plan_read_after_the_tick_is_the_lanes_plan():
+    """`plan.D`, read after the tick, is the plan the lane computed, bit
+    for bit (the same program run directly), its hour 0 is what the tick
+    committed, and reading it is one counted plan pull."""
+    p, rhs = _tick_stream()
+    out = rhs.step()
+    p0 = rhs._window_problem(0, rhs._forecast(0))
+    lane = api._cr1_run(_jit_view(p0), CR1().lam,
+                        EngineState.cold(jnp.zeros(p0.usage.shape)),
+                        steps=40, use_kernel=False, order=None,
+                        offsets=(0, p.W))
+    spans.clear()
+    D = out.plan.D
+    (pull,) = spans.recorded()
+    assert pull.name == "dr.solve.pull"
+    assert pull.counts == {"d2h_bytes": D.nbytes, "plan_pulls": 1}
+    np.testing.assert_array_equal(D, np.asarray(lane[0]))
+    np.testing.assert_array_equal(D[:, 0], out.committed)
+    assert out.plan.D is D and len(spans.recorded()) == 1   # pulled once
+
+
+def test_donated_tick_leaves_the_previous_plan_readable():
+    """With `donate=True` tick k+1 consumes tick k's engine state; the
+    plan tick k left on the device is not that state, so it can still be
+    read afterwards, and equals the undonated run's plan."""
+    plans = {}
+    for donate in (True, False):
+        _, rhs = _tick_stream(donate)
+        first = rhs.step()
+        rhs.step()
+        assert first.plan.state.x.is_deleted() is donate
+        plans[donate] = first.plan.D
+    np.testing.assert_array_equal(plans[True], plans[False])

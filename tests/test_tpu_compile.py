@@ -132,14 +132,15 @@ def _fleet_at_scale(sharding, fleet=None):
 
 def test_cr1_solve_compiles_for_v5e(one_chip, monkeypatch):
     """The whole jitted CR1 solve on the kernel path, as the chip runs it
-    by default."""
+    by default, with the report's device sums and hour 0 it returns."""
     from repro.core.api import CR1, _cr1_run
 
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
     fleet, state = _fleet_at_scale(one_chip)
     compiled = _cr1_run.lower(fleet, _shape(one_chip, ()), state,
                               steps=CR1.default_steps,
-                              use_kernel=True).compile()
+                              use_kernel=True, order=None,
+                              offsets=(0, W)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
 
@@ -205,7 +206,8 @@ def test_sharded_cr1_solve_compiles_for_v5e_2x2(topo, monkeypatch):
     norms = (_shape(rep, ()),) * 3
     compiled = _cr1_run_sharded.lower(
         fleet, _shape(rep, ()), norms, state, mesh=mesh,
-        steps=CR1.default_steps, use_kernel=True).compile()
+        steps=CR1.default_steps, use_kernel=True, order=None,
+        offsets=(0, W)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
