@@ -175,7 +175,7 @@ def fiscal_sums(p, r) -> None:
 
 
 def single_chip(jax) -> None:
-    from repro.core.api import CR1, CR2, CR3, SolveContext, _cr1_run, solve
+    from repro.core.api import _TICK, CR1, CR2, CR3, SolveContext, solve
     from repro.core.engine import EngineState
     from repro.core.fleet_solver import (_jit_view, resolve_use_kernel,
                                          synthetic_fleet)
@@ -219,8 +219,9 @@ def single_chip(jax) -> None:
 
     # The compiled CR1 solve must hold the Pallas kernel.
     state = EngineState.cold(jax.numpy.zeros(p.usage.shape))
-    hlo = _cr1_run.lower(_jit_view(p), 1.45, state, steps=CR1.default_steps,
-                         use_kernel=True).compile().as_text()
+    hlo = _TICK["cr1"].plain.lower(_jit_view(p), 1.45, state,
+                                   steps=CR1.default_steps,
+                                   use_kernel=True).compile().as_text()
     if "tpu_custom_call" not in hlo:
         fail("compiled CR1 solve holds no tpu_custom_call")
     log("compiled CR1 solve holds a tpu_custom_call (the al_step kernel)")

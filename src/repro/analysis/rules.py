@@ -212,21 +212,59 @@ def _function_index(tree: ast.Module) -> dict[str, list[ast.FunctionDef]]:
     return idx
 
 
+def _jit_factories(tree: ast.Module) -> dict[str, int]:
+    """Module functions that jit one of their own parameters (`def
+    f(impl, ...): ... jax.jit(impl, ...)`): name -> that parameter's
+    position, so a call `f(fn, ...)` makes `fn` a root."""
+    out: dict[str, int] = {}
+    for fn in tree.body:
+        if not isinstance(fn, ast.FunctionDef):
+            continue
+        params = [a.arg for a in fn.args.posonlyargs + fn.args.args]
+        for node in ast.walk(fn):
+            t = _jit_target(node) if isinstance(node, ast.Call) else None
+            if t in params:
+                out[fn.name] = params.index(t)
+    return out
+
+
+def _module_bindings(tree: ast.Module) -> dict[str, ast.AST]:
+    """Top-level `NAME = value` bindings: name -> value."""
+    out: dict[str, ast.AST] = {}
+    for node in tree.body:
+        if isinstance(node, ast.Assign):
+            out.update((t.id, node.value) for t in node.targets
+                       if isinstance(t, ast.Name))
+        elif isinstance(node, ast.AnnAssign) and node.value is not None \
+                and isinstance(node.target, ast.Name):
+            out[node.target.id] = node.value
+    return out
+
+
 def _jit_reachable(mod: Module) -> list[ast.FunctionDef]:
     """FunctionDefs reachable (same module) from a jit root.
 
     Roots: `X = jax.jit(fn, ...)` assignments, `@jax.jit` /
-    `@functools.partial(jax.jit, ...)` decorators, and EXTRA_JIT_ROOTS.
+    `@functools.partial(jax.jit, ...)` decorators, functions handed to a
+    jit factory of the module (`_jit_factories`), and EXTRA_JIT_ROOTS.
     Edges: any Name reference inside a reachable body that matches a
     module function (catches plain calls and functions handed to
-    vmap/scan/shard_map alike — a deliberate over-approximation)."""
+    vmap/scan/shard_map alike — a deliberate over-approximation), or a
+    top-level binding, whose value is then walked the same way (a table
+    of functions that a traced body looks up)."""
     idx = _function_index(mod.tree)
+    factories = _jit_factories(mod.tree)
+    bindings = _module_bindings(mod.tree)
     roots: set[str] = set()
     for node in ast.walk(mod.tree):
         if isinstance(node, ast.Call):
             t = _jit_target(node)
             if t:
                 roots.add(t)
+            pos = factories.get(getattr(node.func, "id", None))
+            if pos is not None and len(node.args) > pos \
+                    and isinstance(node.args[pos], ast.Name):
+                roots.add(node.args[pos].id)
         if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
             for dec in node.decorator_list:
                 if _is_jax_jit(dec) or _partial_jit_decorator(dec):
@@ -237,19 +275,23 @@ def _jit_reachable(mod: Module) -> list[ast.FunctionDef]:
             roots |= names
     seen: set[int] = set()
     out: list[ast.FunctionDef] = []
-    work = [fn for name in roots for fn in idx.get(name, [])]
+    work: list[ast.AST] = [fn for name in roots for fn in idx.get(name, [])]
     while work:
         fn = work.pop()
         if id(fn) in seen:
             continue
         seen.add(id(fn))
-        out.append(fn)
+        if isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            out.append(fn)
         for node in ast.walk(fn):
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)) \
                     and node is not fn and id(node) not in seen:
                 work.append(node)   # nested defs run under the same trace
-            if isinstance(node, ast.Name) and node.id in idx:
-                work.extend(f for f in idx[node.id] if id(f) not in seen)
+            if isinstance(node, ast.Name):
+                work.extend(f for f in idx.get(node.id, ())
+                            if id(f) not in seen)
+                if node.id in bindings:
+                    work.append(bindings[node.id])
     return out
 
 

@@ -15,10 +15,13 @@ policies — Efficient (CR1), Fair-Centralized (CR2), Fair-Decentralized
   * Each policy owns its engine backend: the objective/constraint pieces,
     the fleet-global normalizers, and the `EngineConfig` it feeds the
     shared projected-Adam + augmented-Lagrangian loop
-    (`repro.core.engine.al_minimize`). CR3 additionally owns its Eq.-6
-    fiscal clearing (the coordinator lowering each region's carbon
-    price ρ until taxes cover rebates), an on-device loop around its
-    best responses, one dispatch a solve.
+    (`repro.core.engine.al_minimize`). CR1 and CR2 differ only in their
+    pieces (`_ENGINES`): every lane of theirs — the tick, solo or on a
+    mesh, the sweep, the day scan and the ensemble — wraps one tick
+    body, `_tick`. CR3 additionally owns its Eq.-6 fiscal clearing (the
+    coordinator lowering each region's carbon price ρ until taxes cover
+    rebates), an on-device loop around its best responses, one dispatch
+    a solve.
 
   * `solve(problem, policy, ctx=SolveContext(...))` is the single entry
     point. `SolveContext` bundles everything orthogonal to policy
@@ -61,7 +64,8 @@ from __future__ import annotations
 import dataclasses
 import functools
 import warnings
-from typing import Any, ClassVar, Protocol, Sequence, runtime_checkable
+from typing import (Any, Callable, ClassVar, NamedTuple, Protocol,
+                    Sequence, runtime_checkable)
 
 import jax
 import jax.numpy as jnp
@@ -405,7 +409,7 @@ def sweep(problem: FleetProblem, policies: Sequence, *,
     `stack_states([r.state for r in last_sweep])`) instead rides the
     CR1/CR2 vmap lane as a warm-started refinement sweep — each lane
     warm-starts from its own slice, so a Pareto front can be polished
-    with a fraction of the cold step budget.
+    with a fraction of the cold step budget, on a mesh as off it.
 
     Results are returned in `policies` order."""
     ctx = ctx or SolveContext()
@@ -421,8 +425,7 @@ def sweep(problem: FleetProblem, policies: Sequence, *,
         return []
     fam = type(pols[0])
     stacked = _stacked_warm(ctx.warm, len(pols))
-    warm_ok = ctx.warm is None or (stacked and ctx.mesh is None
-                                   and fam in (CR1, CR2))
+    warm_ok = ctx.warm is None or (stacked and fam in (CR1, CR2))
     # ctx.telemetry forces the loop lane: the vmapped sweep impls have
     # no telemetry plumbing, and the loop gives each policy its own
     # per-solve ConvergenceTrace in result.extras anyway.
@@ -500,7 +503,7 @@ def _maybe_migrate(p: FleetProblem, res: FleetSolveResult):
         extras={**res.extras, "migration": plan})
 
 
-def _coupled_impl(p: FleetProblem, D0, hyper, refs, fr, to, bw, cost,
+def _coupled_impl(p: FleetProblem, D0, hyper, fr, to, bw, cost,
                   ceil, *, mode: str, steps: int, outer: int,
                   use_kernel: bool, has_ceiling: bool):
     """Joint (curtailment, interconnect-flow) refinement — the coupled
@@ -531,11 +534,11 @@ def _coupled_impl(p: FleetProblem, D0, hyper, refs, fr, to, bw, cost,
     D0 = jnp.asarray(D0, f32)
     margin = mci[fr] - mci[to] - cost[:, None]            # (L, T)
     flow_norm = 100.0 / (usage * mci[region]).sum()
-    if mode == "cr1":
-        obj_D, project_D, step_D = _cr1_pieces(p, use_kernel)
-        eq_D = None
-    else:
-        obj_D, eq_D, project_D, step_D = _cr2_pieces(p, refs, use_kernel)
+    eng = _ENGINES[mode]
+    pieces = eng.pieces(p, hyper, eng.norms(p, hyper), use_kernel, None)
+    obj_D, project_D, step_D = (pieces["objective"], pieces["project"],
+                                pieces["step_scale"])
+    eq_D = pieces.get("eq_residual")
 
     movable0 = jax.ops.segment_sum(
         jnp.where(isb, jnp.maximum(usage - D0, 0.0), 0.0), region,
@@ -579,7 +582,7 @@ def _coupled_impl(p: FleetProblem, D0, hyper, refs, fr, to, bw, cost,
     cfg = EngineConfig(inner_steps=steps, outer_steps=outer, mu0=10.0,
                        mu_growth=3.0)
     z0 = jnp.concatenate([D0, jnp.zeros((L, T), f32)])
-    z, _ = al_minimize(objective, project, z0, hyper=hyper,
+    z, _ = al_minimize(objective, project, z0, hyper=pieces.get("hyper"),
                        eq_residual=eq, ineq_residual=ineq,
                        step_scale=step, cfg=cfg)
     D = z[:W]
@@ -619,17 +622,13 @@ def _coupled_migrate(p: FleetProblem, policy, res: FleetSolveResult,
             ceil = np.broadcast_to(ceil[:, None], (R, T))
     else:
         ceil = np.zeros((R, T))
-    if type(policy) is CR1:
-        hyper, refs, mode, outer = policy.lam, None, "cr1", 4
-    else:
-        refs = jnp.asarray(cr2_reference_fleet(p, policy.cap_frac))
-        hyper, mode, outer = None, "cr2", max(4, policy.outer)
+    outer = max(4, policy.outer)
     D0 = np.asarray(res.D, float)
     D_f, y_l, pens = _coupled_run(
-        _jit_view(p), jnp.asarray(D0, jnp.float32), hyper, refs,
+        _jit_view(p), jnp.asarray(D0, jnp.float32), policy._hyper(p),
         jnp.asarray(fr), jnp.asarray(to), jnp.asarray(bw, jnp.float32),
         jnp.asarray(cost, jnp.float32), jnp.asarray(ceil, jnp.float32),
-        mode=mode, steps=steps, outer=outer, use_kernel=use_kernel,
+        mode=policy.name, steps=steps, outer=outer, use_kernel=use_kernel,
         has_ceiling=has_ceiling)
     D_f = np.asarray(D_f, float)
     tot0 = float(D0.sum())
@@ -688,7 +687,7 @@ def ensemble(problem: FleetProblem, policy, scenarios, *,
 
 
 # ---------------------------------------------------------------------------
-# Fused AL inner loop (Pallas al_step kernel) — CR1/CR2 hot path
+# CR1/CR2 engine lanes: one pieces function per policy, one tick body
 # ---------------------------------------------------------------------------
 def _al_fused_inner(p: FleetProblem, mode: str, cfg: EngineConfig, *,
                     car_norm, step_scale, coef0=0.0, scale=None, refs=None):
@@ -738,29 +737,16 @@ def _al_fused_inner(p: FleetProblem, mode: str, cfg: EngineConfig, *,
         day_hours=p.day_hours)
 
 
-def _sharded_penalties(p: FleetProblem, D: Array, mesh,
-                       use_kernel: bool) -> Array:
-    """`fleet_penalties` of a plan sharded over `mesh`, row block by row
-    block: the TPU cannot partition a Pallas kernel on its own, so the
-    dr_features kernel runs inside a shard_map like the solve itself."""
-    axis = fleet_axes(mesh)
-    # check_vma=False: `fleet_penalties` may dispatch the dr_features
-    # pallas_call (use_kernel), which has no shard_map replication rule;
-    # the one output is explicitly spec'd.
-    return jax.shard_map(
-        lambda pb, d: fleet_penalties(pb, d, use_kernel), mesh=mesh,
-        in_specs=(_fleet_specs(p, axis), P(axis)), out_specs=P(axis),
-        check_vma=False)(p, D)
-
-
-# ---------------------------------------------------------------------------
-# CR1 — Efficient DR (unconstrained trade-off objective)
-# ---------------------------------------------------------------------------
-def _cr1_pieces(p: FleetProblem, use_kernel: bool, norms=None):
+def _cr1_pieces(p: FleetProblem, lam, norms, use_kernel: bool,
+                cfg: EngineConfig | None) -> dict:
+    """CR1's `al_minimize` pieces on the rows of `p` (a fleet, or one
+    device's block of it): the λ-weighted penalty less the carbon, the
+    box and day-preservation projection, and with the kernel the fused
+    inner loop under `cfg` (none where `cfg` is None: the caller runs
+    its own loop). `norms` are `regional.cr1_norms` of the true fleet."""
     lo, hi = _bounds(p)
     mci = jnp.asarray(p.mci)
-    pen_norm, car_norm, step_scale = \
-        _cr1_norms(p) if norms is None else norms
+    pen_norm, car_norm, step_scale = norms
 
     if mci.ndim == 2:
         wmci = mci[jnp.asarray(p.region)]
@@ -774,247 +760,23 @@ def _cr1_pieces(p: FleetProblem, use_kernel: bool, norms=None):
             return (lam * pen_norm * fleet_penalties(p, D, use_kernel).sum()
                     - car_norm * (D @ mci).sum())
 
-    project = _projection(p, lo, hi)
-    return objective, project, step_scale
+    pieces = dict(objective=objective, project=_projection(p, lo, hi),
+                  hyper=lam, step_scale=step_scale)
+    if use_kernel and cfg is not None:
+        pieces["fused_inner"] = _al_fused_inner(
+            p, "cr1", cfg, car_norm=car_norm, step_scale=step_scale,
+            coef0=lam * pen_norm)
+    return pieces
 
 
-def _cr1_cfg(steps: int, moment_dtype: str = "float32",
-             sanitize: bool = False,
-             telemetry_every: int = 0) -> EngineConfig:
-    return EngineConfig(inner_steps=steps, outer_steps=1,
-                        moment_dtype=moment_dtype, sanitize=sanitize,
-                        telemetry_every=telemetry_every)
-
-
-def _cr1_impl(p: FleetProblem, lam, state0: EngineState, steps: int,
-              use_kernel: bool, shift: int = 0, reset_mu: bool = False,
-              moment_dtype: str = "float32", sanitize: bool = False,
-              telemetry_every: int = 0, norms=None, order=None,
-              offsets: tuple | None = None):
-    state0 = _enter_tick(state0, shift, reset_mu, CR1_MU0)
-    norms = _cr1_norms(p) if norms is None else norms
-    objective, project, step_scale = _cr1_pieces(p, use_kernel, norms=norms)
-    cfg = _cr1_cfg(steps, moment_dtype, sanitize, telemetry_every)
-    fused = _al_fused_inner(p, "cr1", cfg, car_norm=norms[1],
-                            step_scale=step_scale,
-                            coef0=lam * norms[0]) if use_kernel else None
-    D, aux = al_minimize(objective, project, state0.x, hyper=lam,
-                         step_scale=step_scale, init=state0, cfg=cfg,
-                         fused_inner=fused)
-    out = (D, fleet_penalties(p, D, use_kernel), aux["state"])
-    # Static knobs: the telemetry trace, then (with `offsets`) hour 0 and
-    # the report sums ride as extra outputs; without them the historical
-    # 3-tuple, so the day scans' tick solves are untouched.
-    if telemetry_every:
-        out += (aux["telemetry"],)
-    return out + _tick_report(p, D, out[1], order, offsets)
-
-
-_CR1_STATIC = ("steps", "use_kernel", "shift", "reset_mu", "moment_dtype",
-               "sanitize", "telemetry_every", "offsets")
-_cr1_run = jax.jit(_cr1_impl, static_argnames=_CR1_STATIC)
-_cr1_run_donated = jax.jit(_cr1_impl, static_argnames=_CR1_STATIC,
-                           donate_argnums=(2,))
-# The sanitizer twin: same impl, checkify-functionalized user checks
-# (`EngineConfig.sanitize` emits them); returns (err, out).
-_cr1_run_checked = checked_jit(_cr1_impl, static_argnames=_CR1_STATIC)
-
-
-def _cr1_impl_sharded(p: FleetProblem, lam, norms, state0: EngineState,
-                      mesh, steps: int, use_kernel: bool, shift: int = 0,
-                      reset_mu: bool = False,
-                      moment_dtype: str = "float32",
-                      telemetry_every: int = 0, order=None,
-                      offsets: tuple | None = None):
-    state0 = _enter_tick(state0, shift, reset_mu, CR1_MU0)
-    axis = fleet_axes(mesh)
-    cfg = _cr1_cfg(steps, moment_dtype, telemetry_every=telemetry_every)
-
-    def build(blk):
-        pb, lam_b, norms_b = blk
-        objective, project, step_scale = _cr1_pieces(pb, use_kernel,
-                                                     norms=norms_b)
-        pieces = dict(objective=objective, project=project, hyper=lam_b,
-                      step_scale=step_scale)
-        if use_kernel:
-            pieces["fused_inner"] = _al_fused_inner(
-                pb, "cr1", cfg, car_norm=norms_b[1], step_scale=step_scale,
-                coef0=lam_b * norms_b[0])
-        return pieces
-
-    D, aux = al_minimize_sharded(
-        build, (p, lam, norms), mesh=mesh, axis_name=axis,
-        data_specs=(_fleet_specs(p, axis), P(), _norm_specs(p, axis)),
-        init=state0, cfg=cfg)
-    out = (D, _sharded_penalties(p, D, mesh, use_kernel), aux["state"])
-    if telemetry_every:
-        out += (aux["telemetry"],)
-    return out + _tick_report(p, D, out[1], order, offsets)
-
-
-_CR1_STATIC_SH = ("mesh", "steps", "use_kernel", "shift", "reset_mu",
-                  "moment_dtype", "telemetry_every", "offsets")
-_cr1_run_sharded = jax.jit(_cr1_impl_sharded, static_argnames=_CR1_STATIC_SH)
-_cr1_run_sharded_donated = jax.jit(_cr1_impl_sharded,
-                                   static_argnames=_CR1_STATIC_SH,
-                                   donate_argnums=(3,))
-
-
-@functools.partial(jax.jit, static_argnames=("steps", "use_kernel"))
-def _cr1_sweep_run(p: FleetProblem, lams, init: EngineState, steps: int,
-                   use_kernel: bool):
-    norms = _cr1_norms(p)
-    objective, project, step_scale = _cr1_pieces(p, use_kernel, norms=norms)
-    cfg = _cr1_cfg(steps)
-
-    def solve_one(lam, st):
-        fused = _al_fused_inner(
-            p, "cr1", cfg, car_norm=norms[1], step_scale=step_scale,
-            coef0=lam * norms[0]) if use_kernel else None
-        D, aux = al_minimize(objective, project, st.x,
-                             hyper=lam, step_scale=step_scale, init=st,
-                             cfg=cfg, fused_inner=fused)
-        return D, fleet_penalties(p, D, use_kernel), aux["state"]
-
-    return jax.vmap(solve_one)(lams, init)
-
-
-@functools.partial(jax.jit, static_argnames=("mesh", "steps", "use_kernel"))
-def _cr1_sweep_sharded(p: FleetProblem, lams, norms, mesh, steps: int,
-                       use_kernel: bool):
-    """The λ grid vmapped INSIDE the W-axis shard_map: every device solves
-    its row block for all grid points in one call (sharded Pareto lane)."""
-    axis = fleet_axes(mesh)
-
-    def body(pb, lams_b, norms_b):
-        objective, project, step_scale = _cr1_pieces(pb, use_kernel,
-                                                     norms=norms_b)
-        cfg = _cr1_cfg(steps)
-
-        def solve_one(lam):
-            fused = _al_fused_inner(
-                pb, "cr1", cfg, car_norm=norms_b[1], step_scale=step_scale,
-                coef0=lam * norms_b[0]) if use_kernel else None
-            D, _ = al_minimize(objective, project,
-                               jnp.zeros(pb.usage.shape), hyper=lam,
-                               step_scale=step_scale, cfg=cfg,
-                               fused_inner=fused)
-            return D, fleet_penalties(pb, D, use_kernel)
-
-        return jax.vmap(solve_one)(lams_b)
-
-    # check_vma=False: `body` may dispatch the fused al_step pallas_call
-    # (use_kernel), which has no shard_map replication rule; every output
-    # is explicitly spec'd above.
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(_fleet_specs(p, axis), P(), _norm_specs(p, axis)),
-        out_specs=(P(None, axis), P(None, axis)),
-        check_vma=False)(p, lams, norms)
-
-
-@_register
-@dataclasses.dataclass(frozen=True)
-class CR1:
-    """Efficient DR (paper Eq. 3): maximize λ-weighted penalty/carbon
-    trade-off over the whole fleet — unconstrained but for the box and
-    batch day-preservation, both handled by projection."""
-
-    lam: float = 1.45
-
-    name: ClassVar[str] = "cr1"
-    default_steps: ClassVar[int] = 600
-    mu0: ClassVar[float] = CR1_MU0
-
-    def solve(self, p: FleetProblem,
-              ctx: SolveContext = SolveContext()) -> FleetSolveResult:
-        use_kernel = resolve_use_kernel(ctx.use_kernel)
-        _require_telemetry_ok(ctx, use_kernel)
-        steps = ctx.resolved_steps(self)
-        tel = _tel_every(ctx)
-        warm = ctx.warm
-        order, offsets = report_layout(p)
-        if ctx.mesh is None:
-            if warm is None:
-                warm = EngineState.cold(jnp.zeros(p.usage.shape))
-            if ctx.sanitize:
-                err, out = _dispatch(
-                    _cr1_run_checked, _jit_view(p), self.lam, warm,
-                    steps=steps, use_kernel=use_kernel, shift=ctx.shift,
-                    reset_mu=ctx.reset_mu, moment_dtype=ctx.moment_dtype,
-                    sanitize=True, telemetry_every=tel, order=order,
-                    offsets=offsets)
-                err.throw()
-            else:
-                run = _cr1_run_donated if ctx.donate else _cr1_run
-                out = _dispatch(run, _jit_view(p), self.lam, warm,
-                                steps=steps, use_kernel=use_kernel,
-                                shift=ctx.shift, reset_mu=ctx.reset_mu,
-                                moment_dtype=ctx.moment_dtype,
-                                telemetry_every=tel, order=order,
-                                offsets=offsets)
-        else:
-            pp, _ = pad_fleet(p, fleet_device_count(ctx.mesh))
-            norms = _cr1_norms(p)
-            if p.is_multiregion:
-                norms = _pad_row_norms(norms, pp.W, CR1_NORM_FILLS)
-            warm = _pad_state(warm, pp.W) if warm is not None \
-                else EngineState.cold(jnp.zeros(pp.usage.shape))
-            run = _cr1_run_sharded_donated if ctx.donate \
-                else _cr1_run_sharded
-            out = _dispatch(run, pp, self.lam, norms, warm, mesh=ctx.mesh,
-                            steps=steps, use_kernel=use_kernel,
-                            shift=ctx.shift, reset_mu=ctx.reset_mu,
-                            moment_dtype=ctx.moment_dtype,
-                            telemetry_every=tel, order=order,
-                            offsets=offsets)
-        return _device_report(p, out, iters=steps, tel=tel)
-
-    # -- vmapped sweep lane -------------------------------------------------
-    @classmethod
-    def _sweep_uniform(cls, policies: Sequence["CR1"]) -> bool:
-        return True          # λ is the only knob and it is traced
-
-    @classmethod
-    def _sweep_family(cls, p: FleetProblem, policies: Sequence["CR1"],
-                      ctx: SolveContext) -> list[FleetSolveResult]:
-        use_kernel = resolve_use_kernel(ctx.use_kernel)
-        steps = ctx.steps if ctx.steps is not None else cls.default_steps
-        lams = jnp.asarray([pl.lam for pl in policies], jnp.float32)
-        N = len(policies)
-        if ctx.mesh is None:
-            W = p.W
-            init = ctx.warm if ctx.warm is not None else EngineState(
-                x=jnp.zeros((N,) + p.usage.shape),
-                lam_eq=jnp.zeros((N, 0)), lam_in=jnp.zeros((N, 0)),
-                mu=jnp.full((N,), CR1_MU0))
-            Ds, pens, states = _cr1_sweep_run(_jit_view(p), lams, init,
-                                              steps, use_kernel)
-        else:
-            pp, W = pad_fleet(p, fleet_device_count(ctx.mesh))
-            norms = _cr1_norms(p)
-            if p.is_multiregion:
-                norms = _pad_row_norms(norms, pp.W, CR1_NORM_FILLS)
-            Ds, pens = _cr1_sweep_sharded(pp, lams, norms,
-                                          mesh=ctx.mesh, steps=steps,
-                                          use_kernel=use_kernel)
-            states = None
-        return [_report(p, np.asarray(D)[:W], np.asarray(pen)[:W],
-                        iters=steps,
-                        state=None if states is None else
-                        jax.tree_util.tree_map(lambda a, i=i: a[i], states))
-                for i, (D, pen) in enumerate(zip(np.asarray(Ds),
-                                                 np.asarray(pens)))]
-
-
-# ---------------------------------------------------------------------------
-# CR2 — Fair-Centralized DR (per-workload penalty-equality targets)
-# ---------------------------------------------------------------------------
-def _cr2_pieces(p: FleetProblem, refs, use_kernel: bool, norms=None):
+def _cr2_pieces(p: FleetProblem, refs, norms, use_kernel: bool,
+                cfg: EngineConfig | None) -> dict:
+    """CR2's pieces, as `_cr1_pieces`: the carbon objective and one
+    equality per workload, its penalty at its fairness reference in
+    `refs`. `norms` are `regional.cr2_norms` of the true fleet."""
     lo, hi = _bounds(p)
     mci = jnp.asarray(p.mci)
-    car_norm, scale, step_scale = \
-        _cr2_norms(p, refs) if norms is None else norms
+    car_norm, scale, step_scale = norms
 
     if mci.ndim == 2:
         wmci = mci[jnp.asarray(p.region)]
@@ -1028,146 +790,336 @@ def _cr2_pieces(p: FleetProblem, refs, use_kernel: bool, norms=None):
     def eq(D: Array, _) -> Array:
         return (fleet_penalties(p, D, use_kernel) - refs) / scale
 
-    return objective, eq, _projection(p, lo, hi), step_scale
+    pieces = dict(objective=objective, eq_residual=eq,
+                  project=_projection(p, lo, hi), step_scale=step_scale)
+    if use_kernel and cfg is not None:
+        pieces["fused_inner"] = _al_fused_inner(
+            p, "cr2", cfg, car_norm=car_norm, step_scale=step_scale,
+            scale=scale, refs=refs)
+    return pieces
 
 
-def _cr2_cfg(steps: int, outer: int, moment_dtype: str = "float32",
-             sanitize: bool = False,
-             telemetry_every: int = 0) -> EngineConfig:
-    return EngineConfig(inner_steps=steps, outer_steps=outer, mu0=CR2_MU0,
-                        mu_growth=2.0, moment_dtype=moment_dtype,
-                        sanitize=sanitize, telemetry_every=telemetry_every)
+def _cr2_window_refs(p: FleetProblem, cap_frac, use_kernel: bool):
+    """CR2's fairness references of the window `p`, in the program: the
+    jnp twin of `cr2_reference_fleet`."""
+    E = jnp.asarray(p.entitlement)[:, None]
+    d_cap = jnp.maximum(jnp.asarray(p.usage) - cap_frac * E, 0.0)
+    return fleet_penalties(p, d_cap, use_kernel)
 
 
-def _cr2_impl(p: FleetProblem, refs, state0: EngineState, steps: int,
-              outer: int, use_kernel: bool, shift: int = 0,
-              reset_mu: bool = False, moment_dtype: str = "float32",
-              sanitize: bool = False, telemetry_every: int = 0,
-              norms=None, order=None, offsets: tuple | None = None):
-    state0 = _enter_tick(state0, shift, reset_mu, CR2_MU0)
-    norms = _cr2_norms(p, refs) if norms is None else norms
-    objective, eq, project, step_scale = _cr2_pieces(p, refs, use_kernel,
-                                                     norms=norms)
-    cfg = _cr2_cfg(steps, outer, moment_dtype, sanitize, telemetry_every)
-    fused = _al_fused_inner(p, "cr2", cfg, car_norm=norms[0],
-                            step_scale=step_scale, scale=norms[1],
-                            refs=refs) if use_kernel else None
-    D, aux = al_minimize(objective, project, state0.x,
-                         eq_residual=eq, step_scale=step_scale, init=state0,
-                         cfg=cfg, fused_inner=fused)
-    out = (D, fleet_penalties(p, D, use_kernel), aux["state"])
+class _Engine(NamedTuple):
+    """What the shared lanes need to know of an engine policy."""
+    pieces: Callable    # (p, hyper, norms, use_kernel, cfg) -> pieces
+    norms: Callable     # (true fleet, hyper) -> its fleet-global norms
+    knob: str           # the policy field that the hyper is made from
+    hyper: Callable     # (fleet, knob) -> the hyper, made on the host
+    window: Callable    # (window, knob, use_kernel) -> a day tick's hyper
+    mu0: float
+    fills: tuple        # inert pad-row values of its multi-region norms
+    row_hyper: bool     # the hyper holds one value per row
+
+
+#: Engine policy name -> its lane inputs. The traced hyper is CR1's λ or
+#: CR2's (W,) fairness references, each row's penalty under an equal cap
+#: at `cap_frac`.
+_ENGINES = {
+    "cr1": _Engine(_cr1_pieces, lambda p, lam: _cr1_norms(p), "lam",
+                   lambda p, lam: lam, lambda p, lam, use_kernel: lam,
+                   CR1_MU0, CR1_NORM_FILLS, row_hyper=False),
+    "cr2": _Engine(_cr2_pieces, _cr2_norms, "cap_frac",
+                   lambda p, c: jnp.asarray(cr2_reference_fleet(p, c)),
+                   _cr2_window_refs, CR2_MU0, CR2_NORM_FILLS,
+                   row_hyper=True),
+}
+
+
+def _shard(fn, mesh, in_specs, out_specs):
+    """`fn`, written for one device's rows, inside one shard_map over
+    `mesh`: the mesh form of a lane."""
+    # check_vma=False: `fn` may dispatch the fused al_step or the
+    # dr_features pallas_call (use_kernel), which have no shard_map
+    # replication rule; every output is explicitly spec'd.
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def _penalties(p: FleetProblem, D: Array, use_kernel: bool,
+               mesh=None) -> Array:
+    """`fleet_penalties` of the plan `D`; on a mesh row block by row
+    block, since the TPU cannot partition a Pallas kernel on its own."""
+    def pens(pb, d):
+        return fleet_penalties(pb, d, use_kernel)
+
+    if mesh is None:
+        return pens(p, D)
+    axis = fleet_axes(mesh)
+    return _shard(pens, mesh, (_fleet_specs(p, axis), P(axis)),
+                  P(axis))(p, D)
+
+
+def _minimize(build, data, specs, state0: EngineState, cfg: EngineConfig,
+              mesh=None):
+    """`al_minimize` from `state0` on the pieces `build(data)` returns;
+    on a mesh `al_minimize_sharded` of the same pieces on each device's
+    rows, `specs(axis)` being the shard_map specs of `data`."""
+    if mesh is None:
+        pieces = dict(build(data))
+        return al_minimize(pieces.pop("objective"), pieces.pop("project"),
+                           state0.x, init=state0, cfg=cfg, **pieces)
+    axis = fleet_axes(mesh)
+    return al_minimize_sharded(build, data, mesh=mesh, axis_name=axis,
+                               data_specs=specs(axis), init=state0, cfg=cfg)
+
+
+def _tick(mode: str, p: FleetProblem, hyper, state0: EngineState, norms,
+          order, *, mesh=None, steps: int, outer: int = 1,
+          use_kernel: bool, shift: int = 0, reset_mu: bool = False,
+          moment_dtype: str = "float32", sanitize: bool = False,
+          telemetry_every: int = 0, offsets: tuple | None = None):
+    """The CR1/CR2 tick body, solo or on `mesh`: enter the tick
+    (`shift`/`reset_mu`), solve `p` under `hyper` from `state0`, and
+    return (plan, penalties, state[, telemetry][, hour 0, report sums]).
+
+    `norms` are the fleet-global norms, computed here from `p` when None;
+    on a mesh `p` is the padded fleet and `norms` are the true fleet's.
+    Static knobs: the telemetry trace, then (with `offsets`) hour 0 and
+    the report sums (`_tick_report`) ride as extra outputs; without them
+    the plain 3-tuple that the sweep, day scan and ensemble lanes wrap."""
+    eng = _ENGINES[mode]
+    state0 = _enter_tick(state0, shift, reset_mu, eng.mu0)
+    cfg = EngineConfig(inner_steps=steps, outer_steps=outer, mu0=eng.mu0,
+                       moment_dtype=moment_dtype, sanitize=sanitize,
+                       telemetry_every=telemetry_every)
+    if norms is None:
+        norms = eng.norms(p, hyper)
+    D, aux = _minimize(
+        lambda blk: eng.pieces(*blk, use_kernel, cfg), (p, hyper, norms),
+        lambda axis: (_fleet_specs(p, axis),
+                      P(axis) if eng.row_hyper else P(),
+                      _norm_specs(p, axis)),
+        state0, cfg, mesh)
+    out = (D, _penalties(p, D, use_kernel, mesh), aux["state"])
     if telemetry_every:
         out += (aux["telemetry"],)
     return out + _tick_report(p, D, out[1], order, offsets)
 
 
-_CR2_STATIC = ("steps", "outer", "use_kernel", "shift", "reset_mu",
-               "moment_dtype", "sanitize", "telemetry_every", "offsets")
-_cr2_run = jax.jit(_cr2_impl, static_argnames=_CR2_STATIC)
-_cr2_run_donated = jax.jit(_cr2_impl, static_argnames=_CR2_STATIC,
-                           donate_argnums=(2,))
-# The sanitizer twin (see `_cr1_run_checked`).
-_cr2_run_checked = checked_jit(_cr2_impl, static_argnames=_CR2_STATIC)
+def _cr1_impl(p: FleetProblem, lam, state0: EngineState, norms=None,
+              order=None, **static):
+    """A CR1 tick (`_tick`); its compiled module is `jit__cr1_impl`."""
+    return _tick("cr1", p, lam, state0, norms, order, **static)
 
 
-def _cr2_impl_sharded(p: FleetProblem, refs, norms, state0: EngineState,
-                      mesh, steps: int, outer: int, use_kernel: bool,
-                      shift: int = 0, reset_mu: bool = False,
-                      moment_dtype: str = "float32",
-                      telemetry_every: int = 0, order=None,
-                      offsets: tuple | None = None):
-    state0 = _enter_tick(state0, shift, reset_mu, CR2_MU0)
+def _cr2_impl(p: FleetProblem, refs, state0: EngineState, norms=None,
+              order=None, **static):
+    """A CR2 tick (`_tick`); its compiled module is `jit__cr2_impl`."""
+    return _tick("cr2", p, refs, state0, norms, order, **static)
+
+
+def _batch_lane(p: FleetProblem, vals, hypers, states: EngineState, norms,
+                *, keys: tuple, mode: str, mesh=None, steps: int,
+                outer: int, use_kernel: bool, shift: int = 0,
+                reset_mu: bool = False):
+    """Many solves of one fleet in one program: `_tick` under `vmap`, one
+    lane per entry of the leading axis of `hypers`, `states`, `norms`
+    (None: each lane computes its own) and the overlays `vals` of the
+    fleet fields `keys` (a scenario ensemble's; none in a sweep). On a
+    mesh the same runs inside one shard_map, each device solving its
+    rows for every lane with lane norms of the true fleets. Returns the
+    stacked (plans, penalties, states)."""
+    def lanes(pb, vals, hypers, states, norms):
+        def one(v, h, st, nm):
+            ps = dataclasses.replace(pb, **dict(zip(keys, v)))
+            return _tick(mode, ps, h, st, nm, None, steps=steps,
+                         outer=outer, use_kernel=use_kernel, shift=shift,
+                         reset_mu=reset_mu)
+
+        return jax.vmap(one)(vals, hypers, states, norms)
+
+    if mesh is None:
+        return lanes(p, vals, hypers, states, norms)
     axis = fleet_axes(mesh)
-    cfg = _cr2_cfg(steps, outer, moment_dtype,
-                   telemetry_every=telemetry_every)
-
-    def build(blk):
-        pb, refs_b, norms_b = blk
-        objective, eq, project, step_scale = _cr2_pieces(
-            pb, refs_b, use_kernel, norms=norms_b)
-        pieces = dict(objective=objective, project=project, eq_residual=eq,
-                      step_scale=step_scale)
-        if use_kernel:
-            pieces["fused_inner"] = _al_fused_inner(
-                pb, "cr2", cfg, car_norm=norms_b[0], step_scale=step_scale,
-                scale=norms_b[1], refs=refs_b)
-        return pieces
-
-    D, aux = al_minimize_sharded(
-        build, (p, refs, norms), mesh=mesh, axis_name=axis,
-        data_specs=(_fleet_specs(p, axis), P(axis), _norm_specs(p, axis)),
-        init=state0, cfg=cfg)
-    out = (D, _sharded_penalties(p, D, mesh, use_kernel), aux["state"])
-    if telemetry_every:
-        out += (aux["telemetry"],)
-    return out + _tick_report(p, D, out[1], order, offsets)
+    row = P(None, axis)
+    st = EngineState(x=row, lam_eq=row, lam_in=row, mu=P())
+    return _shard(lanes, mesh,
+                  (_fleet_specs(p, axis),
+                   tuple(P() if k == "mci" else row for k in keys),
+                   row if _ENGINES[mode].row_hyper else P(), st,
+                   _norm_specs(p, axis, stacked=True)),
+                  (row, row, st))(p, vals, hypers, states, norms)
 
 
-_CR2_STATIC_SH = ("mesh", "steps", "outer", "use_kernel", "shift",
-                  "reset_mu", "moment_dtype", "telemetry_every", "offsets")
-_cr2_run_sharded = jax.jit(_cr2_impl_sharded, static_argnames=_CR2_STATIC_SH)
-_cr2_run_sharded_donated = jax.jit(_cr2_impl_sharded,
-                                   static_argnames=_CR2_STATIC_SH,
-                                   donate_argnums=(3,))
+class _Jits(NamedTuple):
+    """The jitted twins of one lane."""
+    plain: Callable
+    donated: Callable | None    # reuses the engine state's buffers
+    checked: Callable | None    # the checkify twin that `sanitize` runs
 
 
-@functools.partial(jax.jit, static_argnames=("steps", "outer", "use_kernel"))
-def _cr2_sweep_run(p: FleetProblem, refs_stack, init: EngineState,
-                   steps: int, outer: int, use_kernel: bool):
-    def solve_one(refs, st):
-        norms = _cr2_norms(p, refs)
-        objective, eq, project, step_scale = _cr2_pieces(p, refs,
-                                                         use_kernel,
-                                                         norms=norms)
-        cfg = _cr2_cfg(steps, outer)
-        fused = _al_fused_inner(
-            p, "cr2", cfg, car_norm=norms[0], step_scale=step_scale,
-            scale=norms[1], refs=refs) if use_kernel else None
-        D, aux = al_minimize(objective, project, st.x,
-                             eq_residual=eq, step_scale=step_scale,
-                             init=st, cfg=cfg, fused_inner=fused)
-        return D, fleet_penalties(p, D, use_kernel), aux["state"]
-
-    return jax.vmap(solve_one)(refs_stack, init)
+def _jits(impl, static: tuple, *, donate: int | None = None,
+          checked: bool = False) -> _Jits:
+    """A lane's plain jit, its twin donating positional argument `donate`
+    (the engine state) and, where `sanitize` applies, its checkify twin
+    (`analysis.sanitize.checked_jit`: returns (err, out)), all from one
+    tuple of static names."""
+    return _Jits(
+        jax.jit(impl, static_argnames=static),
+        None if donate is None else jax.jit(
+            impl, static_argnames=static, donate_argnums=(donate,)),
+        checked_jit(impl, static_argnames=static) if checked else None)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("mesh", "steps", "outer", "use_kernel"))
-def _cr2_sweep_sharded(p: FleetProblem, refs_stack, norms_stack, mesh,
-                       steps: int, outer: int, use_kernel: bool):
-    axis = fleet_axes(mesh)
-
-    def body(pb, refs_b, norms_b):
-        def solve_one(refs, norms):
-            objective, eq, project, step_scale = _cr2_pieces(
-                pb, refs, use_kernel, norms=norms)
-            cfg = _cr2_cfg(steps, outer)
-            fused = _al_fused_inner(
-                pb, "cr2", cfg, car_norm=norms[0], step_scale=step_scale,
-                scale=norms[1], refs=refs) if use_kernel else None
-            D, _ = al_minimize(objective, project,
-                               jnp.zeros(pb.usage.shape), eq_residual=eq,
-                               step_scale=step_scale, cfg=cfg,
-                               fused_inner=fused)
-            return D, fleet_penalties(pb, D, use_kernel)
-
-        return jax.vmap(solve_one)(refs_b, norms_b)
-
-    nspec = P() if np.ndim(p.mci) == 1 else P(None, axis)
-    # check_vma=False: `body` may dispatch the fused al_step pallas_call
-    # (use_kernel), which has no shard_map replication rule; every output
-    # is explicitly spec'd above.
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(_fleet_specs(p, axis), P(None, axis),
-                  (nspec, nspec, nspec)),
-        out_specs=(P(None, axis), P(None, axis)),
-        check_vma=False)(p, refs_stack, norms_stack)
+_TICK_STATIC = ("mesh", "steps", "outer", "use_kernel", "shift", "reset_mu",
+                "moment_dtype", "sanitize", "telemetry_every", "offsets")
+#: The tick's twins per engine policy, each named for its policy.
+_TICK = {"cr1": _jits(_cr1_impl, _TICK_STATIC, donate=2, checked=True),
+         "cr2": _jits(_cr2_impl, _TICK_STATIC, donate=2, checked=True)}
+_BATCH = _jits(_batch_lane, ("keys", "mode", "mesh", "steps", "outer",
+                             "use_kernel", "shift", "reset_mu"))
 
 
+def _run_lane(jits: _Jits, ctx: SolveContext, *args, **kw):
+    """`_dispatch` a lane through the twin `ctx` asks for: the checkify
+    twin under `sanitize` (a failed guard raises `SanitizeError`), the
+    donated one under `donate`, else the plain one."""
+    if ctx.sanitize:
+        err, out = _dispatch(jits.checked, *args, sanitize=True, **kw)
+        err.throw()
+        return out
+    return _dispatch(jits.donated if ctx.donate else jits.plain, *args,
+                     **kw)
+
+
+def _stack(trees):
+    """Stack pytrees of like shapes along a new leading axis."""
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+
+
+def _cold(mode: str, W: int, T: int, lanes: int | None = None):
+    """A cold engine state for `W` rows of `T` hours (`lanes` stacked)."""
+    eng = _ENGINES[mode]
+    st = EngineState.cold(jnp.zeros((W, T)), n_eq=W if eng.row_hyper else 0,
+                          mu0=eng.mu0)
+    return st if lanes is None else _stack([st] * lanes)
+
+
+def _mesh_inputs(mode: str, p: FleetProblem, hyper, W_pad: int):
+    """(`hyper`, the norms of the true fleet `p` under it), each padded to
+    the `W_pad` rows of `p`'s device-padded fleet with inert fills."""
+    eng = _ENGINES[mode]
+    norms = eng.norms(p, hyper)
+    if p.is_multiregion:
+        norms = _pad_row_norms(norms, W_pad, eng.fills)
+    if eng.row_hyper:
+        hyper = jnp.concatenate([hyper, jnp.zeros(W_pad - p.W, hyper.dtype)])
+    return hyper, norms
+
+
+def _lane_inputs(mode: str, p: FleetProblem, hypers: list, problems, mesh,
+                 init: EngineState | None = None):
+    """(fleet, true W, stacked hypers, stacked norms, stacked states) for
+    `_batch_lane` lanes of `p` under `hypers`: off-mesh the fleet's jit
+    view, and no norms (each lane computes its own); on a mesh the padded
+    fleet and each lane's hyper and norms of its true fleet in
+    `problems`. The states are `init`, each padded to the fleet's rows,
+    or cold ones."""
+    n = len(hypers)
+    if mesh is None:
+        pj, W, hypers, norms = _jit_view(p), p.W, _stack(hypers), None
+    else:
+        pj, W = pad_fleet(p, fleet_device_count(mesh))
+        hypers, norms = zip(*(_mesh_inputs(mode, q, h, pj.W)
+                              for q, h in zip(problems, hypers)))
+        hypers, norms = _stack(hypers), _stack(norms)
+    init = _cold(mode, pj.W, pj.T, lanes=n) if init is None \
+        else jax.vmap(lambda s: _pad_state(s, pj.W))(init)
+    return pj, W, hypers, norms, init
+
+
+class _EngineLanes:
+    """`solve` and the same-family sweep of an engine policy (CR1, CR2).
+    Both run the one tick body `_tick`: a tick dispatches `_TICK`, a
+    sweep `_BATCH`, solo or on `ctx.mesh`."""
+
+    def _hyper(self, p: FleetProblem):
+        """The engine's traced hyper on the fleet `p` (`_Engine.hyper`)."""
+        eng = _ENGINES[self.name]
+        return eng.hyper(p, getattr(self, eng.knob))
+
+    def solve(self, p: FleetProblem,
+              ctx: SolveContext = SolveContext()) -> FleetSolveResult:
+        mode = self.name
+        use_kernel = resolve_use_kernel(ctx.use_kernel)
+        _require_telemetry_ok(ctx, use_kernel)
+        steps = ctx.resolved_steps(self)
+        tel = _tel_every(ctx)
+        hyper, norms = self._hyper(p), None
+        if ctx.mesh is None:
+            pj = _jit_view(p)
+        else:
+            pj, _ = pad_fleet(p, fleet_device_count(ctx.mesh))
+            hyper, norms = _mesh_inputs(mode, p, hyper, pj.W)
+        warm = _cold(mode, pj.W, pj.T) if ctx.warm is None \
+            else _pad_state(ctx.warm, pj.W)
+        order, offsets = report_layout(p)
+        out = _run_lane(_TICK[mode], ctx, pj, hyper, warm, norms,
+                        order=order, mesh=ctx.mesh, steps=steps,
+                        outer=self.outer, use_kernel=use_kernel,
+                        shift=ctx.shift, reset_mu=ctx.reset_mu,
+                        moment_dtype=ctx.moment_dtype, telemetry_every=tel,
+                        offsets=offsets)
+        return _device_report(p, out, iters=steps * self.outer, tel=tel)
+
+    # -- vmapped sweep lane -------------------------------------------------
+    @classmethod
+    def _sweep_uniform(cls, policies) -> bool:
+        # `outer` is a static engine knob: one compile needs one value.
+        return len({pl.outer for pl in policies}) == 1
+
+    @classmethod
+    def _sweep_family(cls, p: FleetProblem, policies,
+                      ctx: SolveContext) -> list[FleetSolveResult]:
+        mode, outer, N = cls.name, policies[0].outer, len(policies)
+        steps = ctx.resolved_steps(policies[0])
+        pj, W, hypers, norms, init = _lane_inputs(
+            mode, p, [pl._hyper(p) for pl in policies], [p] * N, ctx.mesh,
+            ctx.warm)
+        Ds, pens, states = _BATCH.plain(
+            pj, (), hypers, init, norms, keys=(), mode=mode, mesh=ctx.mesh,
+            steps=steps, outer=outer,
+            use_kernel=resolve_use_kernel(ctx.use_kernel))
+        return [_report(p, np.asarray(D)[:W], np.asarray(pen)[:W],
+                        iters=steps * outer,
+                        state=jax.tree_util.tree_map(lambda a, i=i: a[i],
+                                                     states))
+                for i, (D, pen) in enumerate(zip(np.asarray(Ds),
+                                                 np.asarray(pens)))]
+
+
+# ---------------------------------------------------------------------------
+# CR1 — Efficient DR (unconstrained trade-off objective)
+# ---------------------------------------------------------------------------
 @_register
 @dataclasses.dataclass(frozen=True)
-class CR2:
+class CR1(_EngineLanes):
+    """Efficient DR (paper Eq. 3): maximize λ-weighted penalty/carbon
+    trade-off over the whole fleet — unconstrained but for the box and
+    batch day-preservation, both handled by projection."""
+
+    lam: float = 1.45
+
+    name: ClassVar[str] = "cr1"
+    default_steps: ClassVar[int] = 600
+    mu0: ClassVar[float] = CR1_MU0
+    outer: ClassVar[int] = 1       # one multiplier round: nothing to price
+
+
+# ---------------------------------------------------------------------------
+# CR2 — Fair-Centralized DR (per-workload penalty-equality targets)
+# ---------------------------------------------------------------------------
+@_register
+@dataclasses.dataclass(frozen=True)
+class CR2(_EngineLanes):
     """Fair-Centralized DR (paper Eq. 4): min −carbon s.t.
     C_i(d_i) = C_i(cap_frac·E_i) for every workload — one equality
     multiplier per workload, `outer` AL multiplier rounds."""
@@ -1178,105 +1130,6 @@ class CR2:
     name: ClassVar[str] = "cr2"
     default_steps: ClassVar[int] = 400
     mu0: ClassVar[float] = CR2_MU0
-
-    def solve(self, p: FleetProblem,
-              ctx: SolveContext = SolveContext()) -> FleetSolveResult:
-        use_kernel = resolve_use_kernel(ctx.use_kernel)
-        _require_telemetry_ok(ctx, use_kernel)
-        steps = ctx.resolved_steps(self)
-        tel = _tel_every(ctx)
-        warm = ctx.warm
-        refs = jnp.asarray(cr2_reference_fleet(p, self.cap_frac))
-        order, offsets = report_layout(p)
-        if ctx.mesh is None:
-            if warm is None:
-                warm = EngineState.cold(jnp.zeros(p.usage.shape), n_eq=p.W,
-                                        mu0=CR2_MU0)
-            if ctx.sanitize:
-                err, out = _dispatch(
-                    _cr2_run_checked, _jit_view(p), refs, warm, steps=steps,
-                    outer=self.outer, use_kernel=use_kernel,
-                    shift=ctx.shift, reset_mu=ctx.reset_mu,
-                    moment_dtype=ctx.moment_dtype, sanitize=True,
-                    telemetry_every=tel, order=order, offsets=offsets)
-                err.throw()
-            else:
-                run = _cr2_run_donated if ctx.donate else _cr2_run
-                out = _dispatch(run, _jit_view(p), refs, warm, steps=steps,
-                                outer=self.outer, use_kernel=use_kernel,
-                                shift=ctx.shift, reset_mu=ctx.reset_mu,
-                                moment_dtype=ctx.moment_dtype,
-                                telemetry_every=tel, order=order,
-                                offsets=offsets)
-        else:
-            pp, W = pad_fleet(p, fleet_device_count(ctx.mesh))
-            norms = _cr2_norms(p, refs)
-            if p.is_multiregion:
-                norms = _pad_row_norms(norms, pp.W, CR2_NORM_FILLS)
-            refs_p = jnp.concatenate([refs,
-                                      jnp.zeros(pp.W - W, refs.dtype)])
-            warm = _pad_state(warm, pp.W) if warm is not None \
-                else EngineState.cold(jnp.zeros(pp.usage.shape), n_eq=pp.W,
-                                      mu0=CR2_MU0)
-            run = _cr2_run_sharded_donated if ctx.donate \
-                else _cr2_run_sharded
-            out = _dispatch(run, pp, refs_p, norms, warm, mesh=ctx.mesh,
-                            steps=steps, outer=self.outer,
-                            use_kernel=use_kernel, shift=ctx.shift,
-                            reset_mu=ctx.reset_mu,
-                            moment_dtype=ctx.moment_dtype,
-                            telemetry_every=tel, order=order,
-                            offsets=offsets)
-        return _device_report(p, out, iters=steps * self.outer, tel=tel)
-
-    # -- vmapped sweep lane -------------------------------------------------
-    @classmethod
-    def _sweep_uniform(cls, policies: Sequence["CR2"]) -> bool:
-        # `outer` is a static engine knob: one compile needs one value.
-        return len({pl.outer for pl in policies}) == 1
-
-    @classmethod
-    def _sweep_family(cls, p: FleetProblem, policies: Sequence["CR2"],
-                      ctx: SolveContext) -> list[FleetSolveResult]:
-        use_kernel = resolve_use_kernel(ctx.use_kernel)
-        steps = ctx.steps if ctx.steps is not None else cls.default_steps
-        outer = policies[0].outer
-        N = len(policies)
-        refs = [jnp.asarray(cr2_reference_fleet(p, pl.cap_frac))
-                for pl in policies]
-        if ctx.mesh is None:
-            W = p.W
-            init = ctx.warm if ctx.warm is not None else EngineState(
-                x=jnp.zeros((N,) + p.usage.shape),
-                lam_eq=jnp.zeros((N, p.W)), lam_in=jnp.zeros((N, 0)),
-                mu=jnp.full((N,), CR2_MU0))
-            Ds, pens, states = _cr2_sweep_run(_jit_view(p), jnp.stack(refs),
-                                              init, steps, outer,
-                                              use_kernel)
-        else:
-            pp, W = pad_fleet(p, fleet_device_count(ctx.mesh))
-            # per-lane global norms from the TRUE fleet; per-lane padded
-            # refs (pad residuals are identically zero).
-            norms = [_cr2_norms(p, r) for r in refs]
-            if p.is_multiregion:
-                norms = [_pad_row_norms(n, pp.W, CR2_NORM_FILLS)
-                         for n in norms]
-            norms_stack = tuple(jnp.stack([n[i] for n in norms])
-                                for i in range(3))
-            refs_p = jnp.stack([
-                jnp.concatenate([r, jnp.zeros(pp.W - W, r.dtype)])
-                for r in refs])
-            Ds, pens = _cr2_sweep_sharded(pp, refs_p, norms_stack,
-                                          mesh=ctx.mesh, steps=steps,
-                                          outer=outer,
-                                          use_kernel=use_kernel)
-            states = None
-        return [_report(p, np.asarray(D)[:W], np.asarray(pen)[:W],
-                        iters=steps * outer,
-                        state=None if states is None else
-                        jax.tree_util.tree_map(lambda a, i=i: a[i], states))
-                for i, (D, pen) in enumerate(zip(np.asarray(Ds),
-                                                 np.asarray(pens)))]
 
 
 # ---------------------------------------------------------------------------
@@ -1357,7 +1210,8 @@ def _cr3_cfg(steps: int, outer: int) -> EngineConfig:
 
 
 def _cr3_impl(p: FleetProblem, rho, tax_frac, reg_scale,
-              state0: EngineState, steps: int, outer: int, use_kernel: bool):
+              state0: EngineState, steps: int, outer: int, use_kernel: bool,
+              mesh=None):
     """All W selfish problems in one AL solve. Each workload i minimizes its
     own penalty s.t. the peak-allowance inequality (Eq. 5/8)
 
@@ -1366,26 +1220,13 @@ def _cr3_impl(p: FleetProblem, rho, tax_frac, reg_scale,
     (smooth max as in `policies.cr3_workload_spec`). Objective, residual and
     projection are all row-separable, so this single (W, T) engine call IS
     the vmapped per-workload best response: one clearing round.
+
+    With `mesh` the best response runs sharded: the allowance inequality,
+    its multipliers and the per-row step scale all live with their rows;
+    only ρ/tax/reg_scale are replicated (multi-region: ρ stays a
+    replicated (R,) vector and reg_scale shards with its rows). The Eq.-6
+    fiscal sums live in `_cr3_market`, outside the shard_map.
     """
-    objective, ineq, project, step_scale, day_tangent = _cr3_pieces(
-        p, use_kernel, reg_scale)
-    D, aux = al_minimize(objective, project, state0.x,
-                         hyper=(rho, tax_frac), ineq_residual=ineq,
-                         step_scale=step_scale, grad_transform=day_tangent,
-                         init=state0, cfg=_cr3_cfg(steps, outer))
-    return D, fleet_penalties(p, D, use_kernel), aux["state"]
-
-
-def _cr3_impl_sharded(p: FleetProblem, rho, tax_frac, reg_scale,
-                      state0: EngineState, mesh, steps: int, outer: int,
-                      use_kernel: bool):
-    """Sharded best response: the allowance inequality, its multipliers and
-    the per-row step scale all live with their rows; only ρ/tax/reg_scale
-    are replicated (multi-region: ρ stays a replicated (R,) vector and
-    reg_scale shards with its rows). The Eq.-6 fiscal sums live in
-    `_cr3_market`, outside the shard_map."""
-    axis = fleet_axes(mesh)
-
     def build(blk):
         pb, hyper_b, reg_b = blk
         objective, ineq, project, step_scale, day_tangent = _cr3_pieces(
@@ -1394,12 +1235,12 @@ def _cr3_impl_sharded(p: FleetProblem, rho, tax_frac, reg_scale,
                     ineq_residual=ineq, step_scale=step_scale,
                     grad_transform=day_tangent)
 
-    reg_spec = P() if np.ndim(p.mci) == 1 else P(axis)
-    D, aux = al_minimize_sharded(
-        build, (p, (rho, tax_frac), reg_scale), mesh=mesh, axis_name=axis,
-        data_specs=(_fleet_specs(p, axis), (P(), P()), reg_spec),
-        init=state0, cfg=_cr3_cfg(steps, outer))
-    return D, _sharded_penalties(p, D, mesh, use_kernel), aux["state"]
+    D, aux = _minimize(
+        build, (p, (rho, tax_frac), reg_scale),
+        lambda axis: (_fleet_specs(p, axis), (P(), P()),
+                      P() if np.ndim(p.mci) == 1 else P(axis)),
+        state0, _cr3_cfg(steps, outer), mesh)
+    return D, _penalties(p, D, use_kernel, mesh), aux["state"]
 
 
 def _cr3_rebates(D, rho, mci, order, offsets: tuple):
@@ -1457,11 +1298,8 @@ def _cr3_market(p: FleetProblem, rho, tax_frac, reg_scale,
 
     def best_response(rho_, st):
         r = rho_[0] if multi else rho_[0][0]
-        if mesh is None:
-            return _cr3_impl(p, r, tax_frac, reg_scale, st, steps, outer,
-                             use_kernel)
-        return _cr3_impl_sharded(p, r, tax_frac, reg_scale, st, mesh,
-                                 steps, outer, use_kernel)
+        return _cr3_impl(p, r, tax_frac, reg_scale, st, steps, outer,
+                         use_kernel, mesh)
 
     def cond(c):
         k, paid = c[0], c[5]
@@ -1817,170 +1655,56 @@ def _day_sums(p: FleetProblem, out, n: int, order, offsets):
                                     roll=n - 1),)
 
 
-def _day_cr1_impl(p: FleetProblem, lam, mci_stack, state0: EngineState,
-                  warm_steps: int, first_steps: int, first_shift: int,
-                  first_reset: bool, use_kernel: bool, moment_dtype: str,
-                  sanitize: bool = False, telemetry_every: int = 0,
-                  order=None, offsets: tuple | None = None):
-    def tick_solve(p_t, mci_t, st, steps, shift, reset_mu):
-        p_t = dataclasses.replace(p_t, mci=mci_t)
-        return _cr1_impl(p_t, lam, st, steps, use_kernel, shift, reset_mu,
-                         moment_dtype, sanitize, telemetry_every)
+def _day_lane(p: FleetProblem, hyper, mci_stack, state0: EngineState,
+              norms_stack, order, *, mode: str, mesh=None, warm_steps: int,
+              first_steps: int, first_shift: int, first_reset: bool,
+              outer: int, use_kernel: bool, moment_dtype: str,
+              sanitize: bool = False, telemetry_every: int = 0,
+              offsets: tuple | None = None):
+    """A whole rolling-horizon day of CR1/CR2 ticks in one program:
+    `_day_impl`'s scan over `_tick`, one row of `mci_stack` per tick.
+    `hyper` is the policy's knob (`_Engine.knob`: CR1's λ, CR2's
+    cap_frac), from which each tick makes its window's hyper
+    (`_Engine.window`). Off-mesh each tick computes its norms; on a
+    mesh the scan runs inside one shard_map, each device scanning its
+    rows through the day, with per-tick norms of the true fleet in
+    `norms_stack` (`_day_norm_stacks`)."""
+    window = _ENGINES[mode].window
 
-    return _day_sums(p, _day_impl(p, mci_stack, state0, tick_solve,
-                                  warm_steps, first_steps, first_shift,
-                                  first_reset,
-                                  telemetry=telemetry_every > 0),
-                     mci_stack.shape[0], order, offsets)
-
-
-_DAY_CR1_STATIC = ("warm_steps", "first_steps", "first_shift",
-                   "first_reset", "use_kernel", "moment_dtype",
-                   "sanitize", "telemetry_every", "offsets")
-_day_cr1 = jax.jit(_day_cr1_impl, static_argnames=_DAY_CR1_STATIC)
-_day_cr1_donated = jax.jit(_day_cr1_impl, static_argnames=_DAY_CR1_STATIC,
-                           donate_argnums=(3,))
-# The day scan's sanitizer twin: checkify-functionalized EngineConfig
-# guards on every tick solve of the fused day (see `_cr1_run_checked`).
-_day_cr1_checked = checked_jit(_day_cr1_impl,
-                               static_argnames=_DAY_CR1_STATIC)
-
-
-def _day_cr2_impl(p: FleetProblem, cap_frac, mci_stack,
-                  state0: EngineState, warm_steps: int, first_steps: int,
-                  first_shift: int, first_reset: bool, outer: int,
-                  use_kernel: bool, moment_dtype: str,
-                  sanitize: bool = False, telemetry_every: int = 0,
-                  order=None, offsets: tuple | None = None):
-    E = jnp.asarray(p.entitlement)[:, None]
-
-    def tick_solve(p_t, mci_t, st, steps, shift, reset_mu):
-        p_t = dataclasses.replace(p_t, mci=mci_t)
-        # Per-window fairness targets, recomputed in-scan (the jnp twin
-        # of `cr2_reference_fleet`).
-        d_cap = jnp.maximum(jnp.asarray(p_t.usage) - cap_frac * E, 0.0)
-        refs = fleet_penalties(p_t, d_cap, use_kernel)
-        return _cr2_impl(p_t, refs, st, steps, outer, use_kernel, shift,
-                         reset_mu, moment_dtype, sanitize, telemetry_every)
-
-    return _day_sums(p, _day_impl(p, mci_stack, state0, tick_solve,
-                                  warm_steps, first_steps, first_shift,
-                                  first_reset,
-                                  telemetry=telemetry_every > 0),
-                     mci_stack.shape[0], order, offsets)
-
-
-_DAY_CR2_STATIC = ("warm_steps", "first_steps", "first_shift",
-                   "first_reset", "outer", "use_kernel", "moment_dtype",
-                   "sanitize", "telemetry_every", "offsets")
-_day_cr2 = jax.jit(_day_cr2_impl, static_argnames=_DAY_CR2_STATIC)
-_day_cr2_donated = jax.jit(_day_cr2_impl, static_argnames=_DAY_CR2_STATIC,
-                           donate_argnums=(3,))
-# CR2 day-scan sanitizer twin (see `_day_cr1_checked`).
-_day_cr2_checked = checked_jit(_day_cr2_impl,
-                               static_argnames=_DAY_CR2_STATIC)
-
-
-def _day_cr1_impl_sharded(p: FleetProblem, lam, mci_stack, norms_stack,
-                          state0: EngineState, mesh, warm_steps: int,
-                          first_steps: int, first_shift: int,
-                          first_reset: bool, use_kernel: bool,
-                          moment_dtype: str, order=None,
-                          offsets: tuple | None = None):
-    """The whole-day CR1 scan INSIDE the W-axis shard_map: each device
-    scans its row block through every tick of the day, so a full
-    rolling-horizon day is still one dispatch on a fleet mesh. Per-tick
-    fleet-global norms ride in as a replicated (n, ...) stack computed
-    host-side from the TRUE fleet (the in-scan twin of the solo path's
-    per-tick `_cr1_norms`)."""
-    axis = fleet_axes(mesh)
-
-    def body(pb, lam_b, mci_s, norms_s, st0):
+    def day(pb, hyper, mci_s, norms_s, st0):
         def tick_solve(p_t, x_t, st, steps, shift, reset_mu):
             mci_t, norms_t = x_t
             p_t = dataclasses.replace(p_t, mci=mci_t)
-            return _cr1_impl(p_t, lam_b, st, steps, use_kernel, shift,
-                             reset_mu, moment_dtype, norms=norms_t)
+            h = window(p_t, hyper, use_kernel)
+            return _tick(mode, p_t, h, st, norms_t, None, steps=steps,
+                         outer=outer, use_kernel=use_kernel, shift=shift,
+                         reset_mu=reset_mu, moment_dtype=moment_dtype,
+                         sanitize=sanitize, telemetry_every=telemetry_every)
 
-        return _day_impl(pb, (mci_s, norms_s), st0, tick_solve,
-                         warm_steps, first_steps, first_shift, first_reset)
+        return _day_impl(pb, (mci_s, norms_s), st0, tick_solve, warm_steps,
+                         first_steps, first_shift, first_reset,
+                         telemetry=telemetry_every > 0)
 
-    state_specs = EngineState(x=P(axis), lam_eq=P(axis), lam_in=P(axis),
-                              mu=P())
-    # check_vma=False: the day scan's tick solves may dispatch the fused
-    # al_step pallas_call (use_kernel), which has no shard_map
-    # replication rule; every output is explicitly spec'd above.
-    out = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(_fleet_specs(p, axis), P(), P(),
-                  _norm_specs(p, axis, stacked=True), state_specs),
-        out_specs=(P(None, axis), P(axis), P(axis), state_specs),
-        check_vma=False)(p, lam, mci_stack, norms_stack, state0)
+    if mesh is None:
+        out = day(p, hyper, mci_stack, norms_stack, state0)
+    else:
+        axis = fleet_axes(mesh)
+        st = EngineState(x=P(axis), lam_eq=P(axis), lam_in=P(axis), mu=P())
+        out = _shard(day, mesh,
+                     (_fleet_specs(p, axis), P(), P(),
+                      _norm_specs(p, axis, stacked=True), st),
+                     (P(None, axis), P(axis), P(axis), st))(
+            p, hyper, mci_stack, norms_stack, state0)
     return _day_sums(p, out, mci_stack.shape[0], order, offsets)
 
 
-_DAY_CR1_STATIC_SH = ("mesh", "warm_steps", "first_steps", "first_shift",
-                      "first_reset", "use_kernel", "moment_dtype",
-                      "offsets")
-_day_cr1_sharded = jax.jit(_day_cr1_impl_sharded,
-                           static_argnames=_DAY_CR1_STATIC_SH)
-_day_cr1_sharded_donated = jax.jit(_day_cr1_impl_sharded,
-                                   static_argnames=_DAY_CR1_STATIC_SH,
-                                   donate_argnums=(4,))
+_DAY = _jits(_day_lane, ("mode", "mesh", "warm_steps", "first_steps",
+                         "first_shift", "first_reset", "outer", "use_kernel",
+                         "moment_dtype", "sanitize", "telemetry_every",
+                         "offsets"), donate=3, checked=True)
 
 
-def _day_cr2_impl_sharded(p: FleetProblem, cap_frac, mci_stack,
-                          norms_stack, state0: EngineState, mesh,
-                          warm_steps: int, first_steps: int,
-                          first_shift: int, first_reset: bool, outer: int,
-                          use_kernel: bool, moment_dtype: str, order=None,
-                          offsets: tuple | None = None):
-    """CR2 twin of `_day_cr1_impl_sharded`: fairness refs are recomputed
-    in-scan from the local row block (row-separable), while the fleet-
-    global norms (carbon normalizer, residual scale, step scale) ride in
-    per tick from the TRUE fleet."""
-    axis = fleet_axes(mesh)
-
-    def body(pb, cap_b, mci_s, norms_s, st0):
-        E = jnp.asarray(pb.entitlement)[:, None]
-
-        def tick_solve(p_t, x_t, st, steps, shift, reset_mu):
-            mci_t, norms_t = x_t
-            p_t = dataclasses.replace(p_t, mci=mci_t)
-            d_cap = jnp.maximum(jnp.asarray(p_t.usage) - cap_b * E, 0.0)
-            refs = fleet_penalties(p_t, d_cap, use_kernel)
-            return _cr2_impl(p_t, refs, st, steps, outer, use_kernel,
-                             shift, reset_mu, moment_dtype, norms=norms_t)
-
-        return _day_impl(pb, (mci_s, norms_s), st0, tick_solve,
-                         warm_steps, first_steps, first_shift, first_reset)
-
-    state_specs = EngineState(x=P(axis), lam_eq=P(axis), lam_in=P(axis),
-                              mu=P())
-    # check_vma=False: the day scan's tick solves may dispatch the fused
-    # al_step pallas_call (use_kernel), which has no shard_map
-    # replication rule; every output is explicitly spec'd above.
-    out = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(_fleet_specs(p, axis), P(), P(),
-                  _norm_specs(p, axis, stacked=True), state_specs),
-        out_specs=(P(None, axis), P(axis), P(axis), state_specs),
-        check_vma=False)(p, cap_frac, mci_stack, norms_stack, state0)
-    return _day_sums(p, out, mci_stack.shape[0], order, offsets)
-
-
-_DAY_CR2_STATIC_SH = ("mesh", "warm_steps", "first_steps", "first_shift",
-                      "first_reset", "outer", "use_kernel", "moment_dtype",
-                      "offsets")
-_day_cr2_sharded = jax.jit(_day_cr2_impl_sharded,
-                           static_argnames=_DAY_CR2_STATIC_SH)
-_day_cr2_sharded_donated = jax.jit(_day_cr2_impl_sharded,
-                                   static_argnames=_DAY_CR2_STATIC_SH,
-                                   donate_argnums=(4,))
-
-
-def _day_norm_stacks(problem: FleetProblem, mci_stack, policy,
-                     W_pad: int | None = None):
+def _day_norm_stacks(problem: FleetProblem, mci_stack, policy, W_pad: int):
     """Per-tick norms for the sharded day scan, computed from the TRUE
     (unpadded) fleet exactly as the solo path computes them inside each
     tick: the tick-t window is the day rolled -t. Single-region fleets
@@ -1989,11 +1713,9 @@ def _day_norm_stacks(problem: FleetProblem, mci_stack, policy,
     `cr2_norms`, padded to the device-padded `W_pad` with inert fills so
     the tick axis leads and the row axis shards (`norm_specs(...,
     stacked=True)`)."""
-    n = mci_stack.shape[0]
-    fills = CR1_NORM_FILLS if isinstance(policy, CR1) else CR2_NORM_FILLS
     rolled = problem
     norms = []
-    for t in range(n):
+    for t in range(mci_stack.shape[0]):
         if t:
             rolled = dataclasses.replace(
                 rolled,
@@ -2002,15 +1724,9 @@ def _day_norm_stacks(problem: FleetProblem, mci_stack, policy,
                 upper=None if rolled.upper is None
                 else np.roll(np.asarray(rolled.upper), -1, axis=1))
         p_t = dataclasses.replace(rolled, mci=mci_stack[t])
-        if isinstance(policy, CR1):
-            nm = _cr1_norms(p_t)
-        else:
-            refs = jnp.asarray(cr2_reference_fleet(p_t, policy.cap_frac))
-            nm = _cr2_norms(p_t, refs)
-        if problem.is_multiregion and W_pad is not None:
-            nm = _pad_row_norms(nm, W_pad, fills)
-        norms.append(nm)
-    return tuple(jnp.stack([nm[i] for nm in norms]) for i in range(3))
+        norms.append(_mesh_inputs(policy.name, p_t, policy._hyper(p_t),
+                                  W_pad)[1])
+    return _stack(norms)
 
 
 def solve_day(problem: FleetProblem, policy, mci_stack, *,
@@ -2063,9 +1779,8 @@ def _solve_day(problem: FleetProblem, policy, mci_stack,
                ctx: SolveContext, cold_steps: int | None,
                warm_steps: int | None) -> DayResult:
     if ctx.sanitize:
-        # Day scans have checkify twins (`_day_cr1_checked` /
-        # `_day_cr2_checked`) on the solo lane; the same CR1/CR2 +
-        # no-mesh/donate restrictions as solve() apply.
+        # The day scan's checkify twin runs on the solo lane; the same
+        # CR1/CR2 + no-mesh/donate restrictions as solve() apply.
         _require_sanitizable(policy, ctx)
     if not isinstance(problem, FleetProblem):
         raise TypeError(
@@ -2098,104 +1813,39 @@ def _solve_day(problem: FleetProblem, policy, mci_stack,
     first_steps = cold_steps if cold else warm_steps
     first_shift, first_reset = (0, False) if cold else (ctx.shift or 1,
                                                         True)
-    stack = jnp.asarray(mci_stack)
     order, offsets = report_layout(problem)
     if not isinstance(policy, (CR1, CR2)):
         raise NotImplementedError(
             f"solve_day supports CR1/CR2 (pure scannable engine "
             f"backends); {policy.name} needs host-side control flow — "
             f"use the per-tick solve()/step() loop")
-    if ctx.mesh is not None:
-        pp, W = pad_fleet(problem, fleet_device_count(ctx.mesh))
-        norms_stack = _day_norm_stacks(problem, mci_stack, policy,
-                                       W_pad=pp.W)
-        state0 = _pad_state(ctx.warm, pp.W) if ctx.warm is not None else (
-            EngineState.cold(jnp.zeros(pp.usage.shape))
-            if isinstance(policy, CR1) else
-            EngineState.cold(jnp.zeros(pp.usage.shape), n_eq=pp.W,
-                             mu0=CR2_MU0))
-        if isinstance(policy, CR1):
-            run = _day_cr1_sharded_donated if ctx.donate \
-                else _day_cr1_sharded
-            out = _dispatch(
-                run, pp, policy.lam, stack, norms_stack, state0,
-                mesh=ctx.mesh,
-                warm_steps=warm_steps, first_steps=first_steps,
-                first_shift=first_shift, first_reset=first_reset,
-                use_kernel=use_kernel, moment_dtype=ctx.moment_dtype,
-                order=order, offsets=offsets)
-            mult = 1
-        else:
-            run = _day_cr2_sharded_donated if ctx.donate \
-                else _day_cr2_sharded
-            out = _dispatch(
-                run, pp, policy.cap_frac, stack, norms_stack, state0,
-                mesh=ctx.mesh, warm_steps=warm_steps,
-                first_steps=first_steps, first_shift=first_shift,
-                first_reset=first_reset, outer=policy.outer,
-                use_kernel=use_kernel, moment_dtype=ctx.moment_dtype,
-                order=order, offsets=offsets)
-            mult = policy.outer
+    mode = policy.name
+    if ctx.mesh is None:
+        pj, W, norms = _jit_view(problem), problem.W, None
     else:
-        pj = _jit_view(problem)
-        W = problem.W
-        if isinstance(policy, CR1):
-            state0 = ctx.warm if ctx.warm is not None else EngineState.cold(
-                jnp.zeros(problem.usage.shape))
-            if ctx.sanitize:
-                err, out = _dispatch(
-                    _day_cr1_checked, pj, policy.lam, stack, state0,
-                    warm_steps=warm_steps,
-                    first_steps=first_steps, first_shift=first_shift,
-                    first_reset=first_reset, use_kernel=use_kernel,
-                    moment_dtype=ctx.moment_dtype, sanitize=True,
-                    telemetry_every=tel, order=order, offsets=offsets)
-                err.throw()
-            else:
-                run = _day_cr1_donated if ctx.donate else _day_cr1
-                out = _dispatch(
-                    run, pj, policy.lam, stack, state0,
-                    warm_steps=warm_steps,
-                    first_steps=first_steps, first_shift=first_shift,
-                    first_reset=first_reset, use_kernel=use_kernel,
-                    moment_dtype=ctx.moment_dtype, telemetry_every=tel,
-                    order=order, offsets=offsets)
-            mult = 1
-        else:
-            state0 = ctx.warm if ctx.warm is not None else EngineState.cold(
-                jnp.zeros(problem.usage.shape), n_eq=problem.W,
-                mu0=CR2_MU0)
-            if ctx.sanitize:
-                err, out = _dispatch(
-                    _day_cr2_checked, pj, policy.cap_frac, stack, state0,
-                    warm_steps=warm_steps, first_steps=first_steps,
-                    first_shift=first_shift, first_reset=first_reset,
-                    outer=policy.outer, use_kernel=use_kernel,
-                    moment_dtype=ctx.moment_dtype, sanitize=True,
-                    telemetry_every=tel, order=order, offsets=offsets)
-                err.throw()
-            else:
-                run = _day_cr2_donated if ctx.donate else _day_cr2
-                out = _dispatch(
-                    run, pj, policy.cap_frac, stack, state0,
-                    warm_steps=warm_steps, first_steps=first_steps,
-                    first_shift=first_shift, first_reset=first_reset,
-                    outer=policy.outer, use_kernel=use_kernel,
-                    moment_dtype=ctx.moment_dtype, telemetry_every=tel,
-                    order=order, offsets=offsets)
-            mult = policy.outer
-        if tel:
-            # One ConvergenceTrace per tick: tick 0's trace is separate
-            # (different step budget → different sample count), warm
-            # ticks come back stacked on a leading (n-1) axis.
-            traces = (ConvergenceTrace.from_aux(out[4]),)
-            if out[5] is not None:
-                traces += ConvergenceTrace.split(out[5])
+        pj, W = pad_fleet(problem, fleet_device_count(ctx.mesh))
+        norms = _day_norm_stacks(problem, mci_stack, policy, pj.W)
+    state0 = _cold(mode, pj.W, pj.T) if cold else _pad_state(ctx.warm, pj.W)
+    out = _run_lane(
+        _DAY, ctx, pj, getattr(policy, _ENGINES[mode].knob),
+        jnp.asarray(mci_stack), state0, norms,
+        order=order, mode=mode, mesh=ctx.mesh, warm_steps=warm_steps,
+        first_steps=first_steps, first_shift=first_shift,
+        first_reset=first_reset, outer=policy.outer, use_kernel=use_kernel,
+        moment_dtype=ctx.moment_dtype, telemetry_every=tel, offsets=offsets)
+    if tel:
+        # One ConvergenceTrace per tick: tick 0's trace is separate
+        # (different step budget → different sample count), warm
+        # ticks come back stacked on a leading (n-1) axis.
+        traces = (ConvergenceTrace.from_aux(out[4]),)
+        if out[5] is not None:
+            traces += ConvergenceTrace.split(out[5])
     # (commitments, last plan, its penalties, state, [telemetry,] sums):
     # the commitments and the sums come back, the plan stays on device.
     committed, sums = _fetch(out[0], out[-1])
     committed = committed[:, :W]
-    iters = (first_steps * mult,) + (warm_steps * mult,) * (n - 1)
+    iters = (first_steps * policy.outer,) \
+        + (warm_steps * policy.outer,) * (n - 1)
     # Reporting view: the final tick's forecast (the sums already cover
     # its rolled window).
     last = _report(dataclasses.replace(problem, mci=mci_stack[-1]),

@@ -7,11 +7,11 @@ evaluates a `DRPolicy` across the whole stack:
   * `evaluate_ensemble(problem, policy, scenarios, ctx=...)` — the entry
     point (also exposed as `repro.core.api.ensemble`). For the engine
     policy families whose solve is a single XLA call (CR1/CR2), the S
-    axis rides `jax.vmap` through the same `_cr{1,2}_impl` backends
-    `api.solve` jits — ONE batched XLA call for the whole ensemble, no
-    Python loop over scenarios. With `ctx.mesh`, the scenario vmap nests
-    *inside* the W-axis shard_map exactly like `api.sweep`'s policy-grid
-    vmap does, so fleet-scale ensembles run sharded too. Every other
+    axis rides `jax.vmap` through the same tick body `api.solve` jits
+    (`api._batch_lane`, which `api.sweep` runs too) — ONE batched XLA
+    call for the whole ensemble, no Python loop over scenarios. With
+    `ctx.mesh`, the scenario vmap nests *inside* the W-axis shard_map,
+    so fleet-scale ensembles run sharded too. Every other
     policy (CR3's market, closed-form baselines, warm/donated
     contexts) falls back to an equivalent sequential loop of
     `api.solve` over the materialized scenarios — `evaluate_ensemble` is
@@ -41,29 +41,20 @@ convergence-level equality is.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
-from repro.core.api import (CR1, CR2, SolveContext, _cr1_impl, _cr1_pieces,
-                            _cr2_cfg, _cr2_impl, _cr2_pieces,
+from repro.core.api import (CR1, CR2, SolveContext, _BATCH, _lane_inputs,
                             resolve_policy, solve)
-from repro.core.engine import EngineConfig, EngineState, al_minimize
-from repro.core.fleet_solver import (CR1_MU0, CR2_MU0, PAD_FILLS,
-                                     FleetProblem, _fleet_specs, _jit_view,
-                                     cr2_reference_fleet, fleet_penalties,
-                                     held_report_sums, pad_fleet,
+from repro.core.engine import EngineState
+from repro.core.fleet_solver import (PAD_FILLS, FleetProblem,
+                                     fleet_penalties, held_report_sums,
                                      report_numbers, resolve_use_kernel)
 from repro.core.metrics import jain_index, max_min_ratio
-from repro.core.regional import (CR1_NORM_FILLS, CR2_NORM_FILLS, cr1_norms,
-                                 cr2_norms, norm_specs, pad_row_norms,
-                                 region_totals)
+from repro.core.regional import region_totals
 from repro.core.scenario import ScenarioStack, resolve_scenarios
-from repro.launch.mesh import fleet_axes, fleet_device_count
 
 __all__ = ["EnsembleReport", "EnsembleResult", "compare_policies",
            "comparison_table", "evaluate_ensemble",
@@ -76,124 +67,6 @@ def _overlay_args(stack: ScenarioStack) -> tuple[tuple[str, ...], tuple]:
     over = stack.overlay_fields()
     keys = tuple(over)
     return keys, tuple(jnp.asarray(over[k]) for k in keys)
-
-
-def _cold_states(S: int, shape: tuple[int, int], n_eq: int = 0,
-                 n_in: int = 0, mu0: float = EngineConfig.mu0) -> EngineState:
-    """S stacked cold EngineStates (leading S axis on every leaf)."""
-    return EngineState(x=jnp.zeros((S,) + shape),
-                       lam_eq=jnp.zeros((S, n_eq)),
-                       lam_in=jnp.zeros((S, n_in)),
-                       mu=jnp.full((S,), mu0))
-
-
-_ENS1_STATIC = ("keys", "steps", "use_kernel", "shift", "reset_mu")
-
-
-@functools.partial(jax.jit, static_argnames=_ENS1_STATIC)
-def _cr1_ens_run(p: FleetProblem, vals, keys, lam, states: EngineState,
-                 steps: int, use_kernel: bool, shift: int, reset_mu: bool):
-    """All S scenario solves as one vmapped call through the same
-    `_cr1_impl` backend `api.solve` jits — warm/cold/streaming alike."""
-    def one(vals_s, st):
-        ps = dataclasses.replace(p, **dict(zip(keys, vals_s)))
-        return _cr1_impl(ps, lam, st, steps, use_kernel, shift, reset_mu)
-
-    return jax.vmap(one)(vals, states)
-
-
-_ENS2_STATIC = ("keys", "steps", "outer", "use_kernel", "shift", "reset_mu")
-
-
-@functools.partial(jax.jit, static_argnames=_ENS2_STATIC)
-def _cr2_ens_run(p: FleetProblem, vals, keys, refs, states: EngineState,
-                 steps: int, outer: int, use_kernel: bool, shift: int,
-                 reset_mu: bool):
-    def one(vals_s, refs_s, st):
-        ps = dataclasses.replace(p, **dict(zip(keys, vals_s)))
-        return _cr2_impl(ps, refs_s, st, steps, outer, use_kernel, shift,
-                         reset_mu)
-
-    return jax.vmap(one)(vals, refs, states)
-
-
-def _overlay_specs(keys: tuple[str, ...], axis: str):
-    """shard_map specs for stacked overlays: per-workload fields sharded on
-    their W axis (dim 1, after the scenario axis), the MCI replicated."""
-    return tuple(P() if k == "mci" else P(None, axis) for k in keys)
-
-
-def _ens_state_specs(axis: str) -> EngineState:
-    return EngineState(x=P(None, axis), lam_eq=P(None, axis),
-                       lam_in=P(None, axis), mu=P())
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("keys", "mesh", "steps", "use_kernel"))
-def _cr1_ens_sharded(p: FleetProblem, vals, keys, lam, norms,
-                     states: EngineState, mesh, steps: int,
-                     use_kernel: bool):
-    """The scenario axis vmapped INSIDE the W-axis shard_map (the
-    `api.sweep` sharded-grid pattern): every device solves its row block
-    for all S scenarios in one call. Per-scenario global normalizers come
-    from the TRUE fleets (computed outside, stacked, replicated)."""
-    axis = fleet_axes(mesh)
-
-    def body(pb, vals_b, norms_b, states_b):
-        def one(vals_s, norms_s, st):
-            ps = dataclasses.replace(pb, **dict(zip(keys, vals_s)))
-            objective, project, step_scale = _cr1_pieces(
-                ps, use_kernel, norms=norms_s)
-            D, aux = al_minimize(
-                objective, project, st.x, hyper=lam,
-                step_scale=step_scale, init=st,
-                cfg=EngineConfig(inner_steps=steps, outer_steps=1))
-            return D, fleet_penalties(ps, D, use_kernel), aux["state"]
-
-        return jax.vmap(one)(vals_b, norms_b, states_b)
-
-    specs = _ens_state_specs(axis)
-    # check_vma=False: `fleet_penalties` may dispatch the dr_features
-    # pallas_call (use_kernel), which has no shard_map replication rule;
-    # every output is explicitly spec'd above.
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(_fleet_specs(p, axis), _overlay_specs(keys, axis),
-                  norm_specs(p, axis, stacked=True), specs),
-        out_specs=(P(None, axis), P(None, axis), specs),
-        check_vma=False)(p, vals, norms, states)
-
-
-@functools.partial(jax.jit, static_argnames=("keys", "mesh", "steps",
-                                             "outer", "use_kernel"))
-def _cr2_ens_sharded(p: FleetProblem, vals, keys, refs, norms,
-                     states: EngineState, mesh, steps: int, outer: int,
-                     use_kernel: bool):
-    axis = fleet_axes(mesh)
-
-    def body(pb, vals_b, refs_b, norms_b, states_b):
-        def one(vals_s, refs_s, norms_s, st):
-            ps = dataclasses.replace(pb, **dict(zip(keys, vals_s)))
-            objective, eq, project, step_scale = _cr2_pieces(
-                ps, refs_s, use_kernel, norms=norms_s)
-            D, aux = al_minimize(
-                objective, project, st.x, eq_residual=eq,
-                step_scale=step_scale, init=st,
-                cfg=_cr2_cfg(steps, outer))
-            return D, fleet_penalties(ps, D, use_kernel), aux["state"]
-
-        return jax.vmap(one)(vals_b, refs_b, norms_b, states_b)
-
-    specs = _ens_state_specs(axis)
-    # check_vma=False: `fleet_penalties` may dispatch the dr_features
-    # pallas_call (use_kernel), which has no shard_map replication rule;
-    # every output is explicitly spec'd above.
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(_fleet_specs(p, axis), _overlay_specs(keys, axis),
-                  P(None, axis), norm_specs(p, axis, stacked=True), specs),
-        out_specs=(P(None, axis), P(None, axis), specs),
-        check_vma=False)(p, vals, refs, norms, states)
 
 
 def _pad_overlays(keys: tuple[str, ...], vals: tuple, W: int, W_pad: int):
@@ -212,17 +85,15 @@ def _pad_overlays(keys: tuple[str, ...], vals: tuple, W: int, W_pad: int):
     return tuple(out)
 
 
-def _cr2_refs(policy, p: FleetProblem, stack: ScenarioStack) -> list:
-    """Per-scenario CR2 fairness targets. The reference depends on
-    usage/entitlement/jobs (the capped penalty under an equal power cap)
-    but never on the MCI, so MCI-only ensembles — every streaming tick —
-    compute it once and share it across all S lanes."""
-    over = stack.overlay_fields()
-    if not {"usage", "entitlement", "jobs"} & set(over):
-        return [jnp.asarray(cr2_reference_fleet(p, policy.cap_frac))] \
-            * stack.S
-    return [jnp.asarray(cr2_reference_fleet(ps, policy.cap_frac))
-            for ps in stack.problems(p)]
+def _scenario_hypers(policy, p: FleetProblem, stack: ScenarioStack) -> list:
+    """Per-scenario engine hypers (`CR1._hyper`/`CR2._hyper`). CR2's
+    fairness references depend on usage/entitlement/jobs (the capped
+    penalty under an equal power cap) but never on the MCI, so MCI-only
+    ensembles — every streaming tick — compute them once and share them
+    across all S lanes."""
+    if not {"usage", "entitlement", "jobs"} & set(stack.overlay_fields()):
+        return [policy._hyper(p)] * stack.S
+    return [policy._hyper(ps) for ps in stack.problems(p)]
 
 
 def _run_batched(policy, p: FleetProblem, stack: ScenarioStack, *,
@@ -230,63 +101,23 @@ def _run_batched(policy, p: FleetProblem, stack: ScenarioStack, *,
                  init: EngineState | None = None, shift: int = 0,
                  reset_mu: bool = False):
     """One batched XLA call solving all S scenarios under `policy`
-    (CR1/CR2). Returns (D (S, W, T) np, pens (S, W) np, states stacked).
+    (CR1/CR2) through `api._batch_lane`: the tick body vmapped over the
+    scenario overlays, inside the W-axis shard_map with `mesh` (per-
+    scenario norms from the TRUE fleets, overlays padded with inert
+    rows). Returns (D (S, W, T) np, pens (S, W) np, states stacked).
 
-    `init` (stacked `EngineState`, e.g. the previous streaming tick's)
-    warm-starts every lane; `shift`/`reset_mu` fold the rolling-horizon
-    tick entry into the same call. The mesh lane is cold-only (the
-    streaming ensemble runs single-device)."""
-    S = stack.S
+    `init` (stacked `EngineState`, e.g. the previous streaming tick's;
+    padded here to a mesh's rows) warm-starts every lane; `shift`/
+    `reset_mu` fold the rolling-horizon tick entry into the same call."""
     keys, vals = _overlay_args(stack)
-    if mesh is None:
-        pj = _jit_view(p)
-        if type(policy) is CR1:
-            if init is None:
-                init = _cold_states(S, p.usage.shape, mu0=CR1_MU0)
-            D, pens, states = _cr1_ens_run(
-                pj, vals, keys, policy.lam, init, steps=steps,
-                use_kernel=use_kernel, shift=shift, reset_mu=reset_mu)
-        else:
-            refs = jnp.stack(_cr2_refs(policy, p, stack))
-            if init is None:
-                init = _cold_states(S, p.usage.shape, n_eq=p.W,
-                                    mu0=CR2_MU0)
-            D, pens, states = _cr2_ens_run(
-                pj, vals, keys, refs, init, steps=steps,
-                outer=policy.outer, use_kernel=use_kernel, shift=shift,
-                reset_mu=reset_mu)
-        return np.asarray(D), np.asarray(pens), states
-    if init is not None or shift or reset_mu:
-        raise ValueError(
-            "the sharded ensemble lane is cold-only (no warm/shift/"
-            "reset_mu); run the streaming ensemble without a mesh")
-    pp, W = pad_fleet(p, fleet_device_count(mesh))
-    vals_p = _pad_overlays(keys, vals, W, pp.W)
-    if type(policy) is CR1:
-        norms = [cr1_norms(ps) for ps in stack.problems(p)]
-        if p.is_multiregion:
-            norms = [pad_row_norms(n, pp.W, CR1_NORM_FILLS) for n in norms]
-        norms_stack = tuple(jnp.stack([n[i] for n in norms])
-                            for i in range(3))
-        states = _cold_states(S, pp.usage.shape, mu0=CR1_MU0)
-        D, pens, states = _cr1_ens_sharded(
-            pp, vals_p, keys, policy.lam, norms_stack, states, mesh=mesh,
-            steps=steps, use_kernel=use_kernel)
-    else:
-        refs = _cr2_refs(policy, p, stack)
-        norms = [cr2_norms(ps, r)
-                 for ps, r in zip(stack.problems(p), refs)]
-        if p.is_multiregion:
-            norms = [pad_row_norms(n, pp.W, CR2_NORM_FILLS) for n in norms]
-        norms_stack = tuple(jnp.stack([n[i] for n in norms])
-                            for i in range(3))
-        refs_p = jnp.stack([
-            jnp.concatenate([r, jnp.zeros(pp.W - W, r.dtype)])
-            for r in refs])
-        states = _cold_states(S, pp.usage.shape, n_eq=pp.W, mu0=CR2_MU0)
-        D, pens, states = _cr2_ens_sharded(
-            pp, vals_p, keys, refs_p, norms_stack, states, mesh=mesh,
-            steps=steps, outer=policy.outer, use_kernel=use_kernel)
+    mode = policy.name
+    pj, W, hypers, norms, init = _lane_inputs(
+        mode, p, _scenario_hypers(policy, p, stack),
+        None if mesh is None else list(stack.problems(p)), mesh, init)
+    D, pens, states = _BATCH.plain(
+        pj, _pad_overlays(keys, vals, W, pj.W), hypers, init, norms,
+        keys=keys, mode=mode, mesh=mesh, steps=steps, outer=policy.outer,
+        use_kernel=use_kernel, shift=shift, reset_mu=reset_mu)
     return np.asarray(D)[:, :W], np.asarray(pens)[:, :W], states
 
 
@@ -725,7 +556,7 @@ def run_streaming_ensemble(problem: FleetProblem, policy, streams, *,
             steps=steps, use_kernel=use_kernel, init=states,
             shift=0 if t == 0 else 1, reset_mu=t > 0)
         committed[:, :, t] = D[:, :, 0]
-        total_steps += steps * (policy.outer if type(policy) is CR2 else 1)
+        total_steps += steps * policy.outer
         if multi:
             real_t = np.asarray(
                 [[st.realized(t) for st in g] for g in groups])  # (S, R)

@@ -94,6 +94,48 @@ def test_jit_host_leak_only_in_reachable_functions():
     assert vs == []
 
 
+@pytest.mark.parametrize("wiring", ["factory", "table"])
+def test_jit_host_leak_through_factory_and_table(wiring):
+    # `impl` is jitted by a module factory that jits its parameter, and
+    # reaches `leak` by a top-level table lookup: both are jit roots or
+    # edges, so the float() inside `leak` is flagged either way.
+    body = {
+        "factory": """
+            def jits(fn, static):
+                return jax.jit(fn, static_argnames=static)
+
+            def impl(x):
+                return leak(x)
+
+            RUN = jits(impl, ())
+        """,
+        "table": """
+            PIECES = {"a": leak}
+
+            def impl(x):
+                return PIECES["a"](x)
+
+            RUN = jax.jit(impl)
+        """,
+    }[wiring]
+    vs = _lint("import jax\n\n\ndef leak(x):\n    return float(x)\n"
+               + textwrap.dedent(body))
+    assert _rules(vs) == ["jit-host-leak"]
+
+
+def test_engine_lanes_are_jit_reachable():
+    """The CR1/CR2 lanes are jitted through `api._jits` and reach their
+    pieces through `api._ENGINES`: the lint walks the whole hot path."""
+    from repro.analysis.rules import Module, _jit_reachable
+    path = SRC / "core" / "api.py"
+    reach = {f.name for f in _jit_reachable(
+        Module.parse(str(path), path.read_text()))}
+    want = {"_cr1_impl", "_cr2_impl", "_tick", "_cr1_pieces", "_cr2_pieces",
+            "_al_fused_inner", "_tick_report", "_batch_lane", "_day_lane",
+            "_day_impl", "_day_sums", "_coupled_impl", "_cr3_market"}
+    assert want <= reach, sorted(want - reach)
+
+
 def test_donation_twin_missing_sibling():
     vs = _lint("""
         import jax
@@ -405,6 +447,32 @@ def test_sanitize_day_scan_parity_and_nan(fp, policy):
     with pytest.raises(SanitizeError, match="non-finite"):
         solve_day(fp, policy, poisoned, cold_steps=40, warm_steps=10,
                   ctx=SolveContext(sanitize=True))
+
+
+@pytest.mark.parametrize("lane", ["tick", "day"])
+@pytest.mark.parametrize("policy", [CR1(lam=1.4), CR2(cap_frac=0.12)],
+                         ids=["cr1", "cr2"])
+def test_lane_twins_donate_the_engine_state(fp, policy, lane):
+    """The twins that `api._jits` builds, checked where drlint's
+    `donation-twin` cannot see them: the donated twin consumes the warm
+    engine state it is given (a traced argument), and its plain sibling
+    leaves that state readable."""
+    from repro.core.api import solve_day
+
+    def run(warm, donate):
+        ctx = SolveContext(steps=8, warm=warm, donate=donate)
+        if lane == "tick":
+            solve(fp, policy, ctx=dataclasses.replace(ctx, shift=1,
+                                                      reset_mu=True))
+        else:
+            solve_day(fp, policy, np.stack([np.asarray(fp.mci)] * 2),
+                      ctx=ctx)
+
+    warm = solve(fp, policy, ctx=SolveContext(steps=8)).state
+    run(warm, donate=False)
+    assert not warm.x.is_deleted()
+    run(warm, donate=True)
+    assert warm.x.is_deleted()
 
 
 def test_sanitize_refuses_unsupported_combos(fp):

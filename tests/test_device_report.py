@@ -116,10 +116,10 @@ def test_solo_lane_reports_from_its_own_program(fleet, request):
     penalties that program computed."""
     p = request.getfixturevalue(fleet)
     order, offsets = report_layout(p)
-    out = api._cr1_run(_jit_view(p), CR1().lam,
-                       EngineState.cold(jnp.zeros(p.usage.shape)),
-                       steps=8, use_kernel=False, order=order,
-                       offsets=offsets)
+    out = api._TICK["cr1"].plain(_jit_view(p), CR1().lam,
+                                 EngineState.cold(jnp.zeros(p.usage.shape)),
+                                 steps=8, use_kernel=False, order=order,
+                                 offsets=offsets)
     res = api._device_report(p, out, iters=8, tel=0)
     D, pens = jax.device_get(out[:2])
     _check(res, p, D, pens)
@@ -134,9 +134,9 @@ def test_sweep_lane_reports_each_policy(one_region):
     init = EngineState(x=jnp.zeros((2,) + p.usage.shape),
                        lam_eq=jnp.zeros((2, 0)), lam_in=jnp.zeros((2, 0)),
                        mu=jnp.full((2,), CR1.mu0))
-    Ds, pens, _ = jax.device_get(api._cr1_sweep_run(
-        _jit_view(p), jnp.asarray([1.0, 2.0], jnp.float32), init, 8,
-        False))
+    Ds, pens, _ = jax.device_get(api._BATCH.plain(
+        _jit_view(p), (), jnp.asarray([1.0, 2.0], jnp.float32), init, None,
+        keys=(), mode="cr1", steps=8, outer=1, use_kernel=False))
     for r, D, pen in zip(res, Ds, pens):
         np.testing.assert_array_equal(r.D, D)
         _check(r, p, D, pen)

@@ -1,5 +1,7 @@
 """Ensemble evaluation (`repro.core.ensemble`): batched-vs-loop parity,
 the risk-report layer, and the batched rolling-horizon ensemble."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -267,3 +269,25 @@ def test_streaming_ensemble_multiregion_validates_groups(regional):
     short = [g[:1] for g in full]              # one stream, two regions
     with pytest.raises(ValueError, match="per region"):
         run_streaming_ensemble(regional, CR1(), short, n_ticks=2)
+
+
+@pytest.mark.parametrize("policy", [CR1(), CR2(outer=1)], ids=["cr1", "cr2"])
+def test_sharded_ensemble_lane_runs_the_fused_inner_loop(policy, monkeypatch):
+    """With the kernel on, the ensemble lane on a mesh builds the fused
+    al_step inner loop, as every other CR1/CR2 lane does, and plans as
+    the solo ensemble lane does."""
+    from repro.core import api
+    from repro.launch.mesh import make_fleet_mesh
+
+    built = []
+    fused = api._al_fused_inner
+    monkeypatch.setattr(api, "_al_fused_inner", lambda *a, **k: (
+        built.append(a[1]), fused(*a, **k))[1])
+    p = synthetic_fleet(8, seed=0)
+    stack = resolve_scenarios(DuckPerturb(n_scenarios=2, seed=1), p)
+    ctx = SolveContext(steps=3, use_kernel=True)
+    mesh = evaluate_ensemble(p, policy, stack, ctx=dataclasses.replace(
+        ctx, mesh=make_fleet_mesh(1)))
+    assert mesh.batched and built == [policy.name]
+    solo = evaluate_ensemble(p, policy, stack, ctx=ctx)
+    np.testing.assert_allclose(mesh.D, solo.D, rtol=0, atol=1e-5)

@@ -252,6 +252,37 @@ print("OK")
 """)
 
 
+def test_sharded_ensemble_lane_takes_warm_states():
+    """The mesh ensemble lane refines from a stacked warm state of the
+    true rows (W=13, padded to the mesh's 16) under the tick entry's
+    shift and mu reset, as the streaming ensemble's solo lane does: its
+    plans match the warm solo lane's."""
+    run_in_subprocess("""
+import numpy as np
+from repro.core.api import CR1, CR2
+from repro.core.ensemble import _run_batched
+from repro.core.fleet_solver import synthetic_fleet
+from repro.core.scenario import DuckPerturb, resolve_scenarios
+from repro.launch.mesh import make_fleet_mesh
+
+mesh = make_fleet_mesh()
+p = synthetic_fleet(13)
+stack = resolve_scenarios(DuckPerturb(n_scenarios=2, seed=1), p)
+for pol, steps in ((CR1(lam=1.45), 300), (CR2(cap_frac=0.8, outer=2), 200)):
+    _, _, st = _run_batched(pol, p, stack, steps=steps, use_kernel=False)
+    assert st.x.shape == (2, 13, 48)
+    kw = dict(steps=steps // 4, use_kernel=False, init=st, shift=1,
+              reset_mu=True)
+    D1, pen1, _ = _run_batched(pol, p, stack, **kw)
+    D8, pen8, st8 = _run_batched(pol, p, stack, mesh=mesh, **kw)
+    assert D8.shape == (2, 13, 48) and st8.x.shape == (2, 16, 48)
+    scale = np.abs(p.usage).max()
+    assert np.abs(D8 - D1).max() < 1e-3 * scale, pol.name
+    assert np.abs(pen8 - pen1).max() < 1e-3 * max(np.abs(pen1).max(), 1.0)
+print("OK")
+""")
+
+
 def test_sharded_2d_mesh_multiregion_parity():
     """Acceptance (ISSUE 7): a 2-D (REGION_AXIS, FLEET_AXIS) mesh from
     `make_fleet_mesh(regions=2)` — the W axis sharded over BOTH axes —
@@ -376,6 +407,43 @@ for c, r8 in zip(caps, sharded):
     r1 = solve(p, CR2(cap_frac=c, outer=2), ctx=SolveContext(steps=200))
     assert abs(r8.carbon_reduction_pct - r1.carbon_reduction_pct) < 0.01, c
     assert abs(r8.total_penalty_pct - r1.total_penalty_pct) < 0.01, c
+print("OK")
+""")
+
+
+def test_sharded_sweep_takes_and_returns_warm_states():
+    """The mesh sweep returns each lane's state, as the solo sweep does,
+    and refines from a stacked warm state: from its own (device-padded)
+    states or the solo sweep's (W=13, padded here), the warm mesh sweep
+    matches the warm solo sweep to <0.01 pp, for CR1 and CR2."""
+    run_in_subprocess("""
+from repro.core.api import CR1, CR2, SolveContext, stack_states, sweep
+from repro.core.fleet_solver import synthetic_fleet
+from repro.launch.mesh import make_fleet_mesh
+
+mesh = make_fleet_mesh()
+p = synthetic_fleet(13)
+for pols, steps in (([CR1(lam=1.0), CR1(lam=2.2)], 300),
+                    ([CR2(cap_frac=0.74, outer=2),
+                      CR2(cap_frac=0.8, outer=2)], 200)):
+    cold8 = sweep(p, pols, ctx=SolveContext(steps=steps, mesh=mesh))
+    cold1 = sweep(p, pols, ctx=SolveContext(steps=steps))
+    assert all(r.state is not None for r in cold8)
+    assert cold8[0].state.x.shape == (16, 48)
+    ref = sweep(p, pols, ctx=SolveContext(
+        steps=steps // 4, warm=stack_states([r.state for r in cold1])))
+    for start in (cold8, cold1):
+        got = sweep(p, pols, ctx=SolveContext(
+            steps=steps // 4, mesh=mesh,
+            warm=stack_states([r.state for r in start])))
+        for pl, a, b in zip(pols, got, ref):
+            assert a.D.shape == (13, 48) and a.state.x.shape == (16, 48)
+            lam = getattr(pl, "lam", 0.0)
+            gap = abs((lam * a.total_penalty_pct - a.carbon_reduction_pct)
+                      - (lam * b.total_penalty_pct - b.carbon_reduction_pct))
+            assert gap < 0.01, (pl, gap)
+            if pl.name == "cr2":
+                assert abs(a.total_penalty_pct - b.total_penalty_pct) < 0.01
 print("OK")
 """)
 

@@ -222,10 +222,9 @@ def test_run_scanned_one_dispatch_with_ledger(fp, tmp_path, monkeypatch):
     solver.run_scanned(4)   # day 2: warm continuation compiles (new
     #                         static combo: first_shift=1, reset_mu)
     calls = []
-    orig = api._day_cr1
-    monkeypatch.setattr(
-        api, "_day_cr1",
-        lambda *a, **k: (calls.append(1), orig(*a, **k))[1])
+    orig = api._DAY.plain
+    monkeypatch.setattr(api, "_DAY", api._DAY._replace(
+        plain=lambda *a, **k: (calls.append(1), orig(*a, **k))[1]))
     with recompile_guard(0, label="scanned day with ledger"):
         solver.run_scanned(4)   # day 3: provably compile-free
     assert len(calls) == 1

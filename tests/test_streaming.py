@@ -340,10 +340,9 @@ def test_run_scanned_is_one_dispatch(monkeypatch):
     p = synthetic_fleet(4, seed=0)
     stream = ForecastStream.caiso(n_ticks=4, horizon=p.T, seed=1)
     calls = []
-    orig = api._day_cr1
-    monkeypatch.setattr(
-        api, "_day_cr1",
-        lambda *a, **k: (calls.append(1), orig(*a, **k))[1])
+    orig = api._DAY.plain
+    monkeypatch.setattr(api, "_DAY", api._DAY._replace(
+        plain=lambda *a, **k: (calls.append(1), orig(*a, **k))[1]))
     rep = RollingHorizonSolver(p, stream, policy="cr1", cold_steps=60,
                                warm_steps=15).run_scanned(4)
     assert len(calls) == 1
@@ -492,10 +491,10 @@ def test_plan_read_after_the_tick_is_the_lanes_plan():
     p, rhs = _tick_stream()
     out = rhs.step()
     p0 = rhs._window_problem(0, rhs._forecast(0))
-    lane = api._cr1_run(_jit_view(p0), CR1().lam,
-                        EngineState.cold(jnp.zeros(p0.usage.shape)),
-                        steps=40, use_kernel=False, order=None,
-                        offsets=(0, p.W))
+    lane = api._TICK["cr1"].plain(
+        _jit_view(p0), CR1().lam,
+        EngineState.cold(jnp.zeros(p0.usage.shape)), steps=40,
+        use_kernel=False, order=None, offsets=(0, p.W))
     spans.clear()
     D = out.plan.D
     (pull,) = spans.recorded()

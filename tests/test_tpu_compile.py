@@ -133,14 +133,14 @@ def _fleet_at_scale(sharding, fleet=None):
 def test_cr1_solve_compiles_for_v5e(one_chip, monkeypatch):
     """The whole jitted CR1 solve on the kernel path, as the chip runs it
     by default, with the report's device sums and hour 0 it returns."""
-    from repro.core.api import CR1, _cr1_run
+    from repro.core.api import _TICK, CR1
 
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
     fleet, state = _fleet_at_scale(one_chip)
-    compiled = _cr1_run.lower(fleet, _shape(one_chip, ()), state,
-                              steps=CR1.default_steps,
-                              use_kernel=True, order=None,
-                              offsets=(0, W)).compile()
+    compiled = _TICK["cr1"].plain.lower(fleet, _shape(one_chip, ()), state,
+                                        steps=CR1.default_steps,
+                                        use_kernel=True, order=None,
+                                        offsets=(0, W)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
 
@@ -196,7 +196,7 @@ def test_sharded_cr1_solve_compiles_for_v5e_2x2(topo, monkeypatch):
     """The CR1 solve sharded over four chips: every Pallas call must sit
     inside a shard_map (the compiler cannot partition a kernel)."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
-    from repro.core.api import CR1, _cr1_run_sharded
+    from repro.core.api import _TICK, CR1
     from repro.launch.mesh import FLEET_AXIS
 
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
@@ -204,8 +204,8 @@ def test_sharded_cr1_solve_compiles_for_v5e_2x2(topo, monkeypatch):
     rep = NamedSharding(mesh, PartitionSpec())
     fleet, state = _fleet_at_scale(rep)
     norms = (_shape(rep, ()),) * 3
-    compiled = _cr1_run_sharded.lower(
-        fleet, _shape(rep, ()), norms, state, mesh=mesh,
+    compiled = _TICK["cr1"].plain.lower(
+        fleet, _shape(rep, ()), state, norms, mesh=mesh,
         steps=CR1.default_steps, use_kernel=True, order=None,
         offsets=(0, W)).compile()
     assert "tpu_custom_call" in compiled.as_text()
