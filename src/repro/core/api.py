@@ -26,8 +26,9 @@ policies — Efficient (CR1), Fair-Centralized (CR2), Fair-Decentralized
   * `solve(problem, policy, ctx=SolveContext(...))` is the single entry
     point. `SolveContext` bundles everything orthogonal to policy
     semantics: device `mesh` (W-axis sharding), `donate`d buffers, the
-    fused streaming tick (`shift`/`reset_mu`), `warm` starts, kernel
-    dispatch, and the inner-`steps` budget (None = the policy's default).
+    fused streaming tick (`shift`/`reset_mu`, and the hour `window` that
+    a device-resident `fleet` slides to), `warm` starts, kernel dispatch,
+    and the inner-`steps` budget (None = the policy's default).
     Every policy returns the same `FleetSolveResult`; policy-specific
     outputs (CR3's clearing ρ, fiscal balance) ride `result.extras`.
 
@@ -123,6 +124,13 @@ class SolveContext:
         invalid afterwards.
       shift: roll the warm plan this many hours inside the solve's own
         XLA call (the rolling-horizon window slide).
+      window: the hour offset of a rolling-horizon window, passed into
+        the solve as traced data: the problem's usage, jobs and cap are
+        the whole periodic trace, and the solve's own program slides
+        them `window` hours earlier along time (`np.roll(a, -window,
+        axis=1)`), so every offset shares one program. None = the
+        problem is the window. Solo `solve()` lanes of a policy with
+        `slides` only (CR1, CR3).
       reset_mu: restart the AL μ schedule at the policy's μ0 inside the
         same call (the per-tick reset; multipliers keep their prices).
       warm: a previous result's `.state` to warm-start from (cold start
@@ -168,10 +176,15 @@ class SolveContext:
         bitwise the off path's. Incompatible with `use_kernel` (the
         fused Pallas inner loop is opaque — raises); under a sweep it
         forces the per-policy loop lane.
+      fleet: the problem's fleet kept on the device (`Resident`), sent in
+        place of its host arrays with the problem's `mci` installed: a
+        solve that takes it uploads none of the fleet. Same lanes as
+        `window`.
     """
     mesh: Any = None
     donate: bool = False
     shift: int = 0
+    window: int | None = None
     reset_mu: bool = False
     warm: EngineState | None = None
     use_kernel: bool | None = None
@@ -180,9 +193,65 @@ class SolveContext:
     coupled_migration: bool = False
     sanitize: bool = False
     telemetry: TelemetryConfig | None = None
+    fleet: "Resident | None" = None
 
     def resolved_steps(self, policy: "DRPolicy") -> int:
         return self.steps if self.steps is not None else policy.default_steps
+
+
+class Resident:
+    """A fleet kept on the device between solves (`SolveContext.fleet`):
+    its jit view less the mci, in the dtypes a lane computes in (float32
+    where x64 is off). Nothing goes up when it is made: the first solve
+    that sends it puts it on the device inside that solve's
+    `dr.solve.dispatch` span, which counts its bytes (`h2d_bytes`) and one
+    `fleet_uploads`; later solves send none of it."""
+
+    def __init__(self, p: FleetProblem):
+        self._fleet = dataclasses.replace(_jit_view(p), mci=None)
+        self._placed = False
+
+    def send(self, mci, sp) -> FleetProblem:
+        """The device fleet with `mci` installed, placed under the open
+        span `sp` if this is its first send."""
+        if not self._placed:
+            sp.count("h2d_bytes", _host_bytes(self._fleet))
+            sp.count("fleet_uploads", 1)
+            self._fleet = jax.device_put(self._fleet)
+            self._placed = True
+        return dataclasses.replace(self._fleet, mci=mci)
+
+
+class _Sent(NamedTuple):
+    """A resident fleet as a lane's argument: `_dispatch` sends it."""
+    fleet: Resident
+    mci: Any
+
+
+def _sent(p: FleetProblem, ctx: SolveContext):
+    """What a solo lane sends for the fleet `p`: `p`'s jit view, or the
+    resident `ctx.fleet` with `p`'s mci installed."""
+    return _jit_view(p) if ctx.fleet is None else _Sent(ctx.fleet, p.mci)
+
+
+def _offset(ctx: SolveContext):
+    """`ctx.window` as the int32 a lane traces (None: nothing slides)."""
+    return None if ctx.window is None else np.asarray(ctx.window, np.int32)
+
+
+def _require_window_ok(policy, ctx: SolveContext) -> None:
+    """`window` and `fleet` serve the solo lanes that slide a window in
+    their own program (a policy with `slides`, no mesh, no coupled
+    migration; `policy` None for every other lane). Fail loudly rather
+    than solve hours the caller did not ask for."""
+    if ctx.window is None and ctx.fleet is None:
+        return
+    if (not getattr(policy, "slides", False) or ctx.mesh is not None
+            or ctx.coupled_migration):
+        raise NotImplementedError(
+            "SolveContext(window=/fleet=) runs on the solo solve() lanes "
+            "of CR1 and CR3; slide the problem on the host "
+            "(np.roll along time) for this lane")
 
 
 @runtime_checkable
@@ -307,8 +376,11 @@ def _dispatch(run, *args, **kw):
     static ones and a host `order` array by keyword — under a
     `dr.solve.dispatch` span: argument conversion, upload and enqueue,
     until the call returns. Counts the dispatch and the host bytes it
-    uploads (`h2d_bytes`)."""
+    uploads (`h2d_bytes`); a resident fleet (`_Sent`) is sent here, so
+    its one upload counts under this span."""
     with span("dr.solve.dispatch") as sp:
+        args = tuple(a.fleet.send(a.mci, sp) if isinstance(a, _Sent) else a
+                     for a in args)
         sp.count("dispatches", 1)
         sp.count("h2d_bytes", _host_bytes(
             (args, [v for v in kw.values() if isinstance(v, np.ndarray)])))
@@ -380,6 +452,7 @@ def solve(problem: FleetProblem, policy, *,
     policy = resolve_policy(policy)
     if ctx.sanitize:
         _require_sanitizable(policy, ctx)
+    _require_window_ok(policy, ctx)
     with span("dr.solve", meta={"policy": policy.name,
                                 "lane": "solo" if ctx.mesh is None
                                 else "mesh"}):
@@ -419,6 +492,7 @@ def sweep(problem: FleetProblem, policies: Sequence, *,
             "vmapped sweep lanes have no checkify twins (and a silent "
             "fallback would skip the guards you asked for); sanitize "
             "policies one at a time through solve()")
+    _require_window_ok(None, ctx)
     problem = _single_region_view(problem)
     pols = [resolve_policy(pl) for pl in policies]
     if not pols:
@@ -681,6 +755,8 @@ def ensemble(problem: FleetProblem, policy, scenarios, *,
             "SolveContext(sanitize=True) is a solo-solve debug lane — the "
             "vmapped ensemble lanes have no checkify twins; sanitize "
             "single scenarios through solve()")
+    if ctx is not None:
+        _require_window_ok(None, ctx)
     from repro.core.ensemble import evaluate_ensemble
     return evaluate_ensemble(problem, policy, scenarios, ctx=ctx,
                              batched=batched)
@@ -871,8 +947,21 @@ def _minimize(build, data, specs, state0: EngineState, cfg: EngineConfig,
                                data_specs=specs(axis), init=state0, cfg=cfg)
 
 
+def _slide(p: FleetProblem, window) -> FleetProblem:
+    """`p` with its usage, jobs and cap slid `window` hours earlier along
+    time, in the program: hour 0 of the result is hour `window` of `p`,
+    as `np.roll(a, -window, axis=1)` gives it. `window` is traced, so
+    every offset shares one program."""
+    def roll(a):
+        return None if a is None else jnp.roll(jnp.asarray(a), -window,
+                                               axis=1)
+
+    return dataclasses.replace(p, usage=roll(p.usage), jobs=roll(p.jobs),
+                               upper=roll(p.upper))
+
+
 def _tick(mode: str, p: FleetProblem, hyper, state0: EngineState, norms,
-          order, *, mesh=None, steps: int, outer: int = 1,
+          order, window=None, *, mesh=None, steps: int, outer: int = 1,
           use_kernel: bool, shift: int = 0, reset_mu: bool = False,
           moment_dtype: str = "float32", sanitize: bool = False,
           telemetry_every: int = 0, offsets: tuple | None = None):
@@ -882,10 +971,14 @@ def _tick(mode: str, p: FleetProblem, hyper, state0: EngineState, norms,
 
     `norms` are the fleet-global norms, computed here from `p` when None;
     on a mesh `p` is the padded fleet and `norms` are the true fleet's.
+    With a traced hour offset `window` (solo), `p` is the whole fleet,
+    slid here first (`_slide`).
     Static knobs: the telemetry trace, then (with `offsets`) hour 0 and
     the report sums (`_tick_report`) ride as extra outputs; without them
     the plain 3-tuple that the sweep, day scan and ensemble lanes wrap."""
     eng = _ENGINES[mode]
+    if window is not None:
+        p = _slide(p, window)
     state0 = _enter_tick(state0, shift, reset_mu, eng.mu0)
     cfg = EngineConfig(inner_steps=steps, outer_steps=outer, mu0=eng.mu0,
                        moment_dtype=moment_dtype, sanitize=sanitize,
@@ -905,15 +998,15 @@ def _tick(mode: str, p: FleetProblem, hyper, state0: EngineState, norms,
 
 
 def _cr1_impl(p: FleetProblem, lam, state0: EngineState, norms=None,
-              order=None, **static):
+              order=None, window=None, **static):
     """A CR1 tick (`_tick`); its compiled module is `jit__cr1_impl`."""
-    return _tick("cr1", p, lam, state0, norms, order, **static)
+    return _tick("cr1", p, lam, state0, norms, order, window, **static)
 
 
 def _cr2_impl(p: FleetProblem, refs, state0: EngineState, norms=None,
-              order=None, **static):
+              order=None, window=None, **static):
     """A CR2 tick (`_tick`); its compiled module is `jit__cr2_impl`."""
-    return _tick("cr2", p, refs, state0, norms, order, **static)
+    return _tick("cr2", p, refs, state0, norms, order, window, **static)
 
 
 def _batch_lane(p: FleetProblem, vals, hypers, states: EngineState, norms,
@@ -1053,17 +1146,19 @@ class _EngineLanes:
         _require_telemetry_ok(ctx, use_kernel)
         steps = ctx.resolved_steps(self)
         tel = _tel_every(ctx)
-        hyper, norms = self._hyper(p), None
+        hyper, W, norms = self._hyper(p), p.W, None
         if ctx.mesh is None:
-            pj = _jit_view(p)
+            pj = _sent(p, ctx)
         else:
             pj, _ = pad_fleet(p, fleet_device_count(ctx.mesh))
-            hyper, norms = _mesh_inputs(mode, p, hyper, pj.W)
-        warm = _cold(mode, pj.W, pj.T) if ctx.warm is None \
-            else _pad_state(ctx.warm, pj.W)
+            W = pj.W
+            hyper, norms = _mesh_inputs(mode, p, hyper, W)
+        warm = _cold(mode, W, p.T) if ctx.warm is None \
+            else _pad_state(ctx.warm, W)
         order, offsets = report_layout(p)
         out = _run_lane(_TICK[mode], ctx, pj, hyper, warm, norms,
-                        order=order, mesh=ctx.mesh, steps=steps,
+                        order=order, window=_offset(ctx),
+                        mesh=ctx.mesh, steps=steps,
                         outer=self.outer, use_kernel=use_kernel,
                         shift=ctx.shift, reset_mu=ctx.reset_mu,
                         moment_dtype=ctx.moment_dtype, telemetry_every=tel,
@@ -1112,6 +1207,10 @@ class CR1(_EngineLanes):
     default_steps: ClassVar[int] = 600
     mu0: ClassVar[float] = CR1_MU0
     outer: ClassVar[int] = 1       # one multiplier round: nothing to price
+    #: The solo lane takes `SolveContext.window` and `.fleet` (λ is the
+    #: same in every window). CR2 does not: its fairness references are
+    #: made from the window on the host, in float64.
+    slides: ClassVar[bool] = True
 
 
 # ---------------------------------------------------------------------------
@@ -1263,7 +1362,7 @@ def _cr3_price_cut(c9, paid, live):
 
 
 def _cr3_market(p: FleetProblem, rho, tax_frac, reg_scale,
-                state0: EngineState, iters, fiscal, order, *,
+                state0: EngineState, iters, fiscal, order, window=None, *,
                 offsets: tuple, steps: int, outer: int, use_kernel: bool,
                 shift: int = 0, reset_mu: bool = False, mesh=None):
     """One CR3 decision in one XLA program: the selfish best responses
@@ -1287,10 +1386,13 @@ def _cr3_market(p: FleetProblem, rho, tax_frac, reg_scale,
     (mci_r (R, T), collected_r + 1e-9, 0.9·collected_r) as pairs computed
     in float64 on the host. The rebates are `_cr3_rebates` over
     `order`/`offsets`, global sums under `mesh` (pad rows count nowhere).
+    A traced hour offset `window` slides `p` first (`_slide`).
 
     Returns (D, penalties, state, rho, rounds, the plan's report sums
     (`_plan_sums`))."""
     mci, bar, c9 = fiscal
+    if window is not None:
+        p = _slide(p, window)
     state = _enter_tick(state0, shift, reset_mu, CR3_MU0)
     multi = np.ndim(p.mci) == 2
     W = p.usage.shape[0]
@@ -1444,6 +1546,8 @@ class CR3:
     name: ClassVar[str] = "cr3"
     default_steps: ClassVar[int] = 600
     mu0: ClassVar[float] = CR3_MU0
+    #: The solo lane takes `SolveContext.window` and `.fleet`.
+    slides: ClassVar[bool] = True
 
     def solve(self, p: FleetProblem,
               ctx: SolveContext = SolveContext()) -> FleetSolveResult:
@@ -1451,19 +1555,18 @@ class CR3:
         steps = ctx.resolved_steps(self)
         R = p.R if p.is_multiregion else 1
         if ctx.mesh is None:
-            pj, W = _jit_view(p), p.W
-            state = ctx.warm if ctx.warm is not None else EngineState.cold(
-                jnp.zeros(p.usage.shape), n_in=p.W, mu0=CR3_MU0)
+            pj, W, W_pad = _sent(p, ctx), p.W, p.W
         else:
             pj, W = pad_fleet(p, fleet_device_count(ctx.mesh))
-            state = _pad_state(ctx.warm, pj.W) if ctx.warm is not None \
-                else EngineState.cold(jnp.zeros(pj.usage.shape), n_in=pj.W,
-                                      mu0=CR3_MU0)
+            W_pad = pj.W
+        state = _pad_state(ctx.warm, W_pad) if ctx.warm is not None \
+            else EngineState.cold(jnp.zeros((W_pad, p.T)), n_in=W_pad,
+                                  mu0=CR3_MU0)
         if p.is_multiregion:
             reg_scale = _cr3_reg_scale(p)
-            if pj.W > W:
+            if W_pad > W:
                 reg_scale = jnp.concatenate(
-                    [reg_scale, jnp.ones((pj.W - W, 1), reg_scale.dtype)])
+                    [reg_scale, jnp.ones((W_pad - W, 1), reg_scale.dtype)])
         else:
             reg_scale = 1e-3 / (W * p.T)
         order, offsets = report_layout(p)
@@ -1472,9 +1575,9 @@ class CR3:
         D, _pens, state, rho, rounds, sums = _dispatch(
             twin, pj, _df.split(np.full(R, float(self.rho))), self.tax_frac,
             reg_scale, state, self.clearing_iters, _cr3_fiscal(p, collected),
-            order, offsets=offsets, steps=steps, outer=self.outer,
-            use_kernel=use_kernel, shift=ctx.shift, reset_mu=ctx.reset_mu,
-            mesh=ctx.mesh)
+            order, _offset(ctx), offsets=offsets, steps=steps,
+            outer=self.outer, use_kernel=use_kernel, shift=ctx.shift,
+            reset_mu=ctx.reset_mu, mesh=ctx.mesh)
         D, rho, rounds, sums = _fetch(D, rho, rounds, sums)
         D, rho = D[:W], rho[0].astype(float) + rho[1]
         balanced, deficit = _cr3_balance(p, D, rho, collected, order,
@@ -1782,6 +1885,7 @@ def _solve_day(problem: FleetProblem, policy, mci_stack,
         # The day scan's checkify twin runs on the solo lane; the same
         # CR1/CR2 + no-mesh/donate restrictions as solve() apply.
         _require_sanitizable(policy, ctx)
+    _require_window_ok(None, ctx)
     if not isinstance(problem, FleetProblem):
         raise TypeError(
             f"solve_day() takes a FleetProblem; got "

@@ -11,7 +11,10 @@ the window slides forward. This module is that control loop:
   * `RollingHorizonSolver` holds a `FleetProblem` template plus a
     `DRPolicy` (`repro.core.api`) and, per tick:
       1. slides the usage/jobs window one hour and swaps in the fresh
-         `(T,)` forecast,
+         `(T,)` forecast — for CR1 and CR3 off a mesh inside the solve's
+         own program, from a fleet kept on the device
+         (`SolveContext.window`/`.fleet`), so a tick sends only its
+         forecast and hour offset,
       2. warm-starts `api.solve(problem, policy, ctx=...)` from the
          previous tick's `EngineState`, shifted one hour along time
          (`EngineState.shifted`) — multipliers carry over as-is since
@@ -48,8 +51,8 @@ from typing import Callable, Sequence
 
 import numpy as np
 
-from repro.core.api import (DRPolicy, SolveContext, configured_policy,
-                            solve)
+from repro.core.api import (DRPolicy, Resident, SolveContext,
+                            configured_policy, solve)
 from repro.core.carbon import ForecastStream
 from repro.core.engine import EngineState
 from repro.core.fleet_solver import (FleetProblem, FleetSolveResult,
@@ -131,7 +134,8 @@ class RollingHorizonSolver:
 
     Args:
       problem: fleet template; `usage`/`jobs` are treated as periodic
-        traces that slide with the window (`np.roll` along time). A
+        traces that slide with the window (`np.roll` along time; in the
+        solve's program where the lane can, see `_window`). A
         multi-region problem (`problem.is_multiregion`) is supported:
         pass one `ForecastStream` per region (see `stream`).
       stream: revised-forecast source; `stream.horizon` must equal
@@ -273,6 +277,11 @@ class RollingHorizonSolver:
                               "cold_steps": cold_steps,
                               "warm_steps": warm_steps})
         self.telemetry = telemetry
+        # A lane that slides the window in its own program keeps the
+        # fleet on the device (sent once, by the first tick) and takes
+        # the tick's hour offset; the others get the window slid here.
+        self._resident = Resident(problem) if (
+            mesh is None and getattr(self.policy, "slides", False)) else None
         self._seen_traces: set[tuple] = set()
         self._state: EngineState | None = None
         self._prev_forecast: np.ndarray | None = None
@@ -304,9 +313,10 @@ class RollingHorizonSolver:
 
     def _window_problem(self, tick: int, mci: np.ndarray) -> FleetProblem:
         """Slide usage/jobs (and any operational cap) to hours
-        [tick, tick+T) and install `mci`. The migration topology is
-        stripped: only hour 0 of each plan is committed, so the per-tick
-        spatial post-stage would price hours that never run."""
+        [tick, tick+T) on the host and install `mci`. The migration
+        topology is stripped: only hour 0 of each plan is committed, so
+        the per-tick spatial post-stage would price hours that never
+        run."""
         p = self.problem
         return dataclasses.replace(
             p, mci=np.asarray(mci), topology=None,
@@ -315,12 +325,28 @@ class RollingHorizonSolver:
             upper=None if p.upper is None
             else np.roll(p.upper, -tick, axis=1))
 
-    def _solve(self, p: FleetProblem, warm: EngineState | None,
-               steps: int, shift: int, reset_mu: bool) -> FleetSolveResult:
+    def _window(self, tick: int,
+                mci: np.ndarray) -> tuple[FleetProblem, int | None]:
+        """What tick `tick` solves: (problem, hour offset). A resident
+        lane gets the fleet as it is with `mci` installed and the offset
+        `tick mod T`, which its program applies (`SolveContext.window`);
+        any other lane the window slid here (`_window_problem`) and
+        None."""
+        if self._resident is None:
+            return self._window_problem(tick, mci), None
+        return (dataclasses.replace(self.problem, mci=np.asarray(mci),
+                                    topology=None),
+                tick % self.problem.T)
+
+    def _solve(self, window: tuple[FleetProblem, int | None],
+               warm: EngineState | None, steps: int, shift: int,
+               reset_mu: bool) -> FleetSolveResult:
+        p, offset = window
         ctx = SolveContext(mesh=self.mesh, donate=self.donate, shift=shift,
-                           reset_mu=reset_mu, warm=warm,
+                           window=offset, reset_mu=reset_mu, warm=warm,
                            use_kernel=self.use_kernel, steps=steps,
-                           telemetry=self.telemetry)
+                           telemetry=self.telemetry,
+                           fleet=None if offset is None else self._resident)
         plan = solve(p, self.policy, ctx=ctx)
         if "rho" in plan.extras:
             self.last_rho = plan.extras["rho"]
@@ -426,7 +452,7 @@ class RollingHorizonSolver:
                 mci_hat = self._forecast(tick)
                 rev = _rel_revision(self._prev_forecast, mci_hat)
             with span("dr.tick.window"):
-                p_t = self._window_problem(tick, mci_hat)
+                window = self._window(tick, mci_hat)
             # Warm ticks shift the plan one hour and restart the mu
             # schedule at the policy's mu0 — without the reset, mu
             # compounds by mu_growth^outer per tick and CR2/CR3's walls
@@ -439,7 +465,7 @@ class RollingHorizonSolver:
             tk.meta["steps"] = steps
             with self._traceguard(("tick", steps, warm is not None)), \
                     self._measure() as stats:
-                plan = self._solve(p_t, warm, steps,
+                plan = self._solve(window, warm, steps,
                                    shift=0 if warm is None else 1,
                                    reset_mu=warm is not None)
             with span("dr.tick.commit"):

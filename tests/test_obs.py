@@ -423,6 +423,14 @@ def _host_bytes(p) -> int:
             + np.asarray(p.is_batch).size + 4)
 
 
+def _tick_bytes(p, tick: int) -> int:
+    """What a streaming CR1 tick uploads: the fleet goes up once, on tick
+    0 (`_host_bytes`); every tick sends its forecast and λ, and the hour
+    offset as an int32."""
+    sent = _host_bytes(p) if tick == 0 else np.asarray(p.mci).size * 4 + 4
+    return sent + 4
+
+
 @pytest.fixture(scope="module")
 def cr1_stream(fp, tmp_path_factory):
     """A 3-tick CR1 stream with the ledger on, and the spans it left."""
@@ -457,11 +465,12 @@ def test_cr1_stream_opens_one_tick_span_per_tick(cr1_stream):
 
 def test_cr1_stream_counts_bytes_as_the_arrays_nbytes(fp, cr1_stream):
     report, recs, _ = cr1_stream
-    up = [r.counts["h2d_bytes"] for r in recs
-          if r.name == "dr.solve.dispatch"]
-    # every tick uploads the whole windowed fleet; the warm state is
-    # already on the device
-    assert up == [_host_bytes(fp)] * 3
+    sends = [r for r in recs if r.name == "dr.solve.dispatch"]
+    # the fleet goes up with tick 0 and stays on the device, where each
+    # tick's program slides it; the warm state is already there
+    assert [r.counts["h2d_bytes"] for r in sends] == [
+        _tick_bytes(fp, t) for t in range(3)]
+    assert [r.counts.get("fleet_uploads", 0) for r in sends] == [1, 0, 0]
     pulls = [r.counts["d2h_bytes"] for r in recs
              if r.name == "dr.solve.pull"]
     committed = report.ticks[-1].committed
@@ -507,7 +516,7 @@ def test_tick_event_carries_counted_dispatches_phases_and_bytes(
     spans_by_tick = {r.tick: r for r in recs if r.name == "dr.tick"}
     for ev in ticks:
         assert ev["dispatches"] == 1
-        assert ev["h2d_bytes"] == _host_bytes(fp)
+        assert ev["h2d_bytes"] == _tick_bytes(fp, ev["tick"])
         # hour 0 and the report sums, not the plan
         assert ev["d2h_bytes"] == fp.W * 4 + _sums_bytes(1, fp.T)
         assert set(ev["phases_ms"]) == set(TICK_PHASES + SOLVE_PHASES)

@@ -517,3 +517,103 @@ def test_donated_tick_leaves_the_previous_plan_readable():
         assert first.plan.state.x.is_deleted() is donate
         plans[donate] = first.plan.D
     np.testing.assert_array_equal(plans[True], plans[False])
+
+
+# ---------------------------------------------------------------------------
+# The window slides in the solve's program, from a fleet kept on the device
+# ---------------------------------------------------------------------------
+T_SLIDE = 24            # one day: T + 3 ticks wrap the offset past T
+CAMBIUM8 = ["CA", "WA", "AZ", "CO", "TX", "IL", "NY", "FL"]
+
+
+def _slide_case(kind: str):
+    """(fleet, its forecast streams, policy) of a parity case, W <= 512."""
+    n = T_SLIDE + 3
+    if kind == "cr1_one_region":
+        p = synthetic_fleet(40, hours=T_SLIDE, seed=2)
+        # an operational cap, so the slide covers `upper` too
+        p = dataclasses.replace(p, upper=0.3 * np.asarray(p.usage))
+        return p, ForecastStream.caiso(n_ticks=n, horizon=T_SLIDE,
+                                       seed=5), CR1(lam=1.45)
+    states = CAMBIUM8 if kind == "cr1_eight_regions" else CAMBIUM8[:3]
+    p = synthetic_regional_fleet(8 * len(states), states, hours=T_SLIDE,
+                                 seed=3)
+    streams = ForecastStream.regional(np.tile(np.asarray(p.mci), 3),
+                                      horizon=T_SLIDE, seed=6)
+    policy = CR1(lam=1.45) if kind == "cr1_eight_regions" else api.CR3(
+        rho=0.05, tax_frac=0.2, outer=2, clearing_iters=2)
+    return p, streams, policy
+
+
+def _slide_solver(p, streams, policy, **kw):
+    return RollingHorizonSolver(p, streams, policy=policy, cold_steps=30,
+                                warm_steps=8, use_kernel=False, **kw)
+
+
+@pytest.mark.filterwarnings("ignore:.*fiscal clearing:RuntimeWarning")
+@pytest.mark.parametrize("kind", ["cr1_one_region", "cr1_eight_regions",
+                                  "cr3_three_regions"])
+def test_window_slid_in_the_program_is_the_host_rolled_window(kind):
+    """Every tick of a resident run, over T + 3 ticks so the offset wraps
+    past T, commits the hour 0 and reports the numbers that a solve of
+    the window rolled on the host gives, bit for bit (CPU, no kernel)."""
+    p, streams, policy = _slide_case(kind)
+    rhs = _slide_solver(p, streams, policy)
+    assert rhs._resident is not None
+    got = []
+    rhs.run(T_SLIDE + 3, on_tick=lambda tk: got.append(tk))
+    state = None
+    for tk in got:
+        p_t = rhs._window_problem(tk.tick, rhs._forecast(tk.tick))
+        ref = solve(p_t, policy, ctx=SolveContext(
+            warm=state, shift=0 if state is None else 1,
+            reset_mu=state is not None, steps=30 if state is None else 8,
+            use_kernel=False))
+        state = ref.state
+        np.testing.assert_array_equal(tk.committed, ref.hour0)
+        plan = tk.plan
+        for name in ("carbon_reduction_pct", "total_penalty_pct",
+                     "preservation_violation", "carbon_base", "iters"):
+            assert getattr(plan, name) == getattr(ref, name), (tk.tick, name)
+        assert plan.extras.keys() == ref.extras.keys()
+        for key, val in ref.extras.items():
+            np.testing.assert_array_equal(plan.extras[key], val)
+        np.testing.assert_array_equal(np.asarray(plan.state.x),
+                                      np.asarray(ref.state.x))
+
+
+def test_window_offsets_share_one_program_and_the_fleet_goes_up_once():
+    """Past T ticks, no warm tick compiles after the first one
+    (`guard_recompiles`), each tick is one dispatch, and the fleet goes
+    up on tick 0 only (`fleet_uploads`); later ticks send the forecast,
+    λ and the offset."""
+    p, stream, policy = _slide_case("cr1_one_region")
+    rhs = _slide_solver(p, stream, policy, guard_recompiles=True)
+    spans.clear()
+    rhs.run(T_SLIDE + 3)
+    sends = [r for r in spans.recorded() if r.name == "dr.solve.dispatch"]
+    assert [r.tick for r in sends] == list(range(T_SLIDE + 3))
+    assert [r.counts["dispatches"] for r in sends] == [1] * (T_SLIDE + 3)
+    assert [r.counts.get("fleet_uploads", 0) for r in sends] \
+        == [1] + [0] * (T_SLIDE + 2)
+    # the forecast (T,) and λ as float32, the offset as int32
+    assert {r.counts["h2d_bytes"] for r in sends[1:]} == {4 * T_SLIDE + 8}
+    assert sends[0].counts["h2d_bytes"] > 4 * p.W * T_SLIDE
+
+
+def test_window_and_fleet_are_refused_where_no_lane_slides():
+    """A lane that cannot slide in its program refuses the offset rather
+    than solving the unslid hours; the streaming solver keeps those on
+    the host roll."""
+    p = synthetic_fleet(4, seed=0)
+    with pytest.raises(NotImplementedError, match="window"):
+        api.sweep(p, [CR1(lam=1.0), CR1(lam=2.0)],
+                  ctx=SolveContext(window=3))
+    stream = ForecastStream.caiso(n_ticks=2, horizon=p.T)
+    for policy in ("b1", "cr2"):
+        solver = RollingHorizonSolver(p, stream, policy=policy)
+        assert solver._resident is None
+        with pytest.raises(NotImplementedError, match="window"):
+            solve(p, policy, ctx=SolveContext(window=3))
+    assert RollingHorizonSolver(p, stream, policy="cr1")._resident \
+        is not None
