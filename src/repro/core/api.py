@@ -83,7 +83,8 @@ from repro.core.fleet_solver import (CR1_MU0, CR2_MU0, CR3_MU0,
                                      _fleet_specs, _jit_view, _pad_state,
                                      _projection, _report,
                                      _single_region_view,
-                                     cr2_reference_fleet, fleet_penalties,
+                                     cr2_cap, cr2_reference_fleet,
+                                     fleet_penalties,
                                      pad_fleet, report_layout, report_sums,
                                      resolve_use_kernel)
 from repro.core.regional import (CR1_NORM_FILLS, CR2_NORM_FILLS,
@@ -128,9 +129,10 @@ class SolveContext:
         the solve as traced data: the problem's usage, jobs and cap are
         the whole periodic trace, and the solve's own program slides
         them `window` hours earlier along time (`np.roll(a, -window,
-        axis=1)`), so every offset shares one program. None = the
-        problem is the window. Solo `solve()` lanes of a policy with
-        `slides` only (CR1, CR3).
+        axis=1)`), so every offset shares one program; CR2 then makes
+        its fairness references from the slid window in that program.
+        None = the problem is the window. Solo `solve()` lanes of a
+        policy with `slides` only (CR1, CR2, CR3).
       reset_mu: restart the AL μ schedule at the policy's μ0 inside the
         same call (the per-tick reset; multipliers keep their prices).
       warm: a previous result's `.state` to warm-start from (cold start
@@ -205,10 +207,14 @@ class Resident:
     where x64 is off). Nothing goes up when it is made: the first solve
     that sends it puts it on the device inside that solve's
     `dr.solve.dispatch` span, which counts its bytes (`h2d_bytes`) and one
-    `fleet_uploads`; later solves send none of it."""
+    `fleet_uploads`; later solves send none of it. CR2's equal-cap
+    curtailment of the fleet (`curtailment`) goes up the same way, with
+    the first solve that asks for it at a `cap_frac`."""
 
     def __init__(self, p: FleetProblem):
         self._fleet = dataclasses.replace(_jit_view(p), mci=None)
+        self._host = p
+        self._cap = (None, None)
         self._placed = False
 
     def send(self, mci, sp) -> FleetProblem:
@@ -221,17 +227,29 @@ class Resident:
             self._placed = True
         return dataclasses.replace(self._fleet, mci=mci)
 
+    def curtailment(self, cap_frac, sp):
+        """`cr2_cap` of the fleet at `cap_frac` on the device, placed under
+        the open span `sp` (counted in its `h2d_bytes`) when `cap_frac` is
+        new: only a lane that asks for it (a sliding CR2 tick) holds it."""
+        if self._cap[0] != cap_frac:
+            d_cap = cr2_cap(self._host, cap_frac)
+            sp.count("h2d_bytes", _host_bytes(d_cap))
+            self._cap = (cap_frac, jax.device_put(d_cap))
+        return self._cap[1]
+
 
 class _Sent(NamedTuple):
-    """A resident fleet as a lane's argument: `_dispatch` sends it."""
-    fleet: Resident
-    mci: Any
+    """A lane argument made from a resident fleet: `_dispatch` calls
+    `put` with its open span, so what goes up is counted there."""
+    put: Callable
 
 
 def _sent(p: FleetProblem, ctx: SolveContext):
     """What a solo lane sends for the fleet `p`: `p`'s jit view, or the
     resident `ctx.fleet` with `p`'s mci installed."""
-    return _jit_view(p) if ctx.fleet is None else _Sent(ctx.fleet, p.mci)
+    if ctx.fleet is None:
+        return _jit_view(p)
+    return _Sent(functools.partial(ctx.fleet.send, p.mci))
 
 
 def _offset(ctx: SolveContext):
@@ -250,7 +268,7 @@ def _require_window_ok(policy, ctx: SolveContext) -> None:
             or ctx.coupled_migration):
         raise NotImplementedError(
             "SolveContext(window=/fleet=) runs on the solo solve() lanes "
-            "of CR1 and CR3; slide the problem on the host "
+            "of CR1, CR2 and CR3; slide the problem on the host "
             "(np.roll along time) for this lane")
 
 
@@ -376,11 +394,10 @@ def _dispatch(run, *args, **kw):
     static ones and a host `order` array by keyword — under a
     `dr.solve.dispatch` span: argument conversion, upload and enqueue,
     until the call returns. Counts the dispatch and the host bytes it
-    uploads (`h2d_bytes`); a resident fleet (`_Sent`) is sent here, so
-    its one upload counts under this span."""
+    uploads (`h2d_bytes`); what a resident fleet sends (`_Sent`) is put
+    here, so its one upload counts under this span."""
     with span("dr.solve.dispatch") as sp:
-        args = tuple(a.fleet.send(a.mci, sp) if isinstance(a, _Sent) else a
-                     for a in args)
+        args = tuple(a.put(sp) if isinstance(a, _Sent) else a for a in args)
         sp.count("dispatches", 1)
         sp.count("h2d_bytes", _host_bytes(
             (args, [v for v in kw.values() if isinstance(v, np.ndarray)])))
@@ -875,11 +892,25 @@ def _cr2_pieces(p: FleetProblem, refs, norms, use_kernel: bool,
     return pieces
 
 
-def _cr2_window_refs(p: FleetProblem, cap_frac, use_kernel: bool):
+def _cr2_sent(policy, p: FleetProblem, ctx: SolveContext):
+    """What a sliding CR2 tick sends: the whole fleet's `cr2_cap` at its
+    `cap_frac`, kept on the device by the resident `ctx.fleet` (placed by
+    `_dispatch`) or made from the host problem `p`."""
+    if ctx.fleet is None:
+        return cr2_cap(p, policy.cap_frac)
+    return _Sent(functools.partial(ctx.fleet.curtailment, policy.cap_frac))
+
+
+def _cr2_window_refs(p: FleetProblem, cap, use_kernel: bool, window=None):
     """CR2's fairness references of the window `p`, in the program: the
-    jnp twin of `cr2_reference_fleet`."""
+    jnp twin of `cr2_reference_fleet`. A day tick sends `cap_frac` and
+    caps here, in float32. A sliding tick sends the whole fleet's
+    `cr2_cap`, made on the host in float64, and slides it `window` hours
+    as `p` was, so its references are the host's of the same window."""
+    if window is not None:
+        return fleet_penalties(p, _slide_hours(cap, window), use_kernel)
     E = jnp.asarray(p.entitlement)[:, None]
-    d_cap = jnp.maximum(jnp.asarray(p.usage) - cap_frac * E, 0.0)
+    d_cap = jnp.maximum(jnp.asarray(p.usage) - cap * E, 0.0)
     return fleet_penalties(p, d_cap, use_kernel)
 
 
@@ -889,7 +920,8 @@ class _Engine(NamedTuple):
     norms: Callable     # (true fleet, hyper) -> its fleet-global norms
     knob: str           # the policy field that the hyper is made from
     hyper: Callable     # (fleet, knob) -> the hyper, made on the host
-    window: Callable    # (window, knob, use_kernel) -> a day tick's hyper
+    window: Callable    # (window, knob | sent, use_kernel[, offset]) -> it
+    sent: Callable      # (policy, fleet, ctx) -> what a sliding tick sends
     mu0: float
     fills: tuple        # inert pad-row values of its multi-region norms
     row_hyper: bool     # the hyper holds one value per row
@@ -897,14 +929,17 @@ class _Engine(NamedTuple):
 
 #: Engine policy name -> its lane inputs. The traced hyper is CR1's λ or
 #: CR2's (W,) fairness references, each row's penalty under an equal cap
-#: at `cap_frac`.
+#: at `cap_frac`; a day tick is sent the knob, and a tick that slides in
+#: its program what `sent` gives (λ, or CR2's whole-fleet `cr2_cap`), and
+#: each makes the hyper there.
 _ENGINES = {
     "cr1": _Engine(_cr1_pieces, lambda p, lam: _cr1_norms(p), "lam",
-                   lambda p, lam: lam, lambda p, lam, use_kernel: lam,
+                   lambda p, lam: lam, lambda p, lam, *_: lam,
+                   lambda pol, p, ctx: pol.lam,
                    CR1_MU0, CR1_NORM_FILLS, row_hyper=False),
     "cr2": _Engine(_cr2_pieces, _cr2_norms, "cap_frac",
                    lambda p, c: jnp.asarray(cr2_reference_fleet(p, c)),
-                   _cr2_window_refs, CR2_MU0, CR2_NORM_FILLS,
+                   _cr2_window_refs, _cr2_sent, CR2_MU0, CR2_NORM_FILLS,
                    row_hyper=True),
 }
 
@@ -947,17 +982,19 @@ def _minimize(build, data, specs, state0: EngineState, cfg: EngineConfig,
                                data_specs=specs(axis), init=state0, cfg=cfg)
 
 
+def _slide_hours(a, window):
+    """The (W, T) array `a` slid `window` hours earlier along time, in the
+    program (`np.roll(a, -window, axis=1)`); None stays None."""
+    return None if a is None else jnp.roll(jnp.asarray(a), -window, axis=1)
+
+
 def _slide(p: FleetProblem, window) -> FleetProblem:
     """`p` with its usage, jobs and cap slid `window` hours earlier along
-    time, in the program: hour 0 of the result is hour `window` of `p`,
-    as `np.roll(a, -window, axis=1)` gives it. `window` is traced, so
-    every offset shares one program."""
-    def roll(a):
-        return None if a is None else jnp.roll(jnp.asarray(a), -window,
-                                               axis=1)
-
-    return dataclasses.replace(p, usage=roll(p.usage), jobs=roll(p.jobs),
-                               upper=roll(p.upper))
+    time, in the program: hour 0 of the result is hour `window` of `p`.
+    `window` is traced, so every offset shares one program."""
+    return dataclasses.replace(p, usage=_slide_hours(p.usage, window),
+                               jobs=_slide_hours(p.jobs, window),
+                               upper=_slide_hours(p.upper, window))
 
 
 def _tick(mode: str, p: FleetProblem, hyper, state0: EngineState, norms,
@@ -972,13 +1009,17 @@ def _tick(mode: str, p: FleetProblem, hyper, state0: EngineState, norms,
     `norms` are the fleet-global norms, computed here from `p` when None;
     on a mesh `p` is the padded fleet and `norms` are the true fleet's.
     With a traced hour offset `window` (solo), `p` is the whole fleet,
-    slid here first (`_slide`).
+    slid here first (`_slide`), and `hyper` is what the policy's sliding
+    tick sends (`_Engine.sent`), from which the slid window's hyper is
+    made here (`_Engine.window`: CR2's fairness references), so its norms
+    come from that window too.
     Static knobs: the telemetry trace, then (with `offsets`) hour 0 and
     the report sums (`_tick_report`) ride as extra outputs; without them
     the plain 3-tuple that the sweep, day scan and ensemble lanes wrap."""
     eng = _ENGINES[mode]
     if window is not None:
         p = _slide(p, window)
+        hyper = eng.window(p, hyper, use_kernel, window)
     state0 = _enter_tick(state0, shift, reset_mu, eng.mu0)
     cfg = EngineConfig(inner_steps=steps, outer_steps=outer, mu0=eng.mu0,
                        moment_dtype=moment_dtype, sanitize=sanitize,
@@ -1146,7 +1187,10 @@ class _EngineLanes:
         _require_telemetry_ok(ctx, use_kernel)
         steps = ctx.resolved_steps(self)
         tel = _tel_every(ctx)
-        hyper, W, norms = self._hyper(p), p.W, None
+        # A sliding lane makes the window's hyper in its program.
+        hyper = self._hyper(p) if ctx.window is None \
+            else _ENGINES[mode].sent(self, p, ctx)
+        W, norms = p.W, None
         if ctx.mesh is None:
             pj = _sent(p, ctx)
         else:
@@ -1163,6 +1207,7 @@ class _EngineLanes:
                         shift=ctx.shift, reset_mu=ctx.reset_mu,
                         moment_dtype=ctx.moment_dtype, telemetry_every=tel,
                         offsets=offsets)
+        _count(al_rounds=self.outer, al_steps=steps * self.outer)
         return _device_report(p, out, iters=steps * self.outer, tel=tel)
 
     # -- vmapped sweep lane -------------------------------------------------
@@ -1208,8 +1253,7 @@ class CR1(_EngineLanes):
     mu0: ClassVar[float] = CR1_MU0
     outer: ClassVar[int] = 1       # one multiplier round: nothing to price
     #: The solo lane takes `SolveContext.window` and `.fleet` (λ is the
-    #: same in every window). CR2 does not: its fairness references are
-    #: made from the window on the host, in float64.
+    #: same in every window).
     slides: ClassVar[bool] = True
 
 
@@ -1229,6 +1273,11 @@ class CR2(_EngineLanes):
     name: ClassVar[str] = "cr2"
     default_steps: ClassVar[int] = 400
     mu0: ClassVar[float] = CR2_MU0
+    #: The solo lane takes `SolveContext.window` and `.fleet`: the fleet
+    #: holds its equal-cap curtailment (`cr2_cap`), and the tick's program
+    #: slides it and makes the window's fairness references
+    #: (`_cr2_window_refs`) and norms.
+    slides: ClassVar[bool] = True
 
 
 # ---------------------------------------------------------------------------

@@ -423,12 +423,18 @@ def fleet_penalties(p: FleetProblem, D: Array,
     return jnp.asarray(p.k) * raw
 
 
+def cr2_cap(p: FleetProblem, cap_frac: float) -> np.ndarray:
+    """The (W, T) curtailment max(usage − cap_frac·E, 0) that an equal
+    power cap at cap_frac·E imposes, in float64 on the host: a difference
+    of near-equal numbers, which float32 would round tens of ulps off."""
+    L = cap_frac * np.asarray(p.entitlement)[:, None]
+    return np.maximum(np.asarray(p.usage) - L, 0.0)
+
+
 def cr2_reference_fleet(p: FleetProblem, cap_frac: float) -> np.ndarray:
     """C_i under a hypothetical equal power cap at cap_frac·E (vectorized
     version of policies.cr2_reference_losses) — CR2's fairness targets."""
-    L = cap_frac * np.asarray(p.entitlement)[:, None]
-    d_cap = np.maximum(np.asarray(p.usage) - L, 0.0)
-    return np.asarray(fleet_penalties(p, jnp.asarray(d_cap)))
+    return np.asarray(fleet_penalties(p, jnp.asarray(cr2_cap(p, cap_frac))))
 
 
 # ---------------------------------------------------------------------------

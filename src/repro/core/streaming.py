@@ -11,8 +11,8 @@ the window slides forward. This module is that control loop:
   * `RollingHorizonSolver` holds a `FleetProblem` template plus a
     `DRPolicy` (`repro.core.api`) and, per tick:
       1. slides the usage/jobs window one hour and swaps in the fresh
-         `(T,)` forecast — for CR1 and CR3 off a mesh inside the solve's
-         own program, from a fleet kept on the device
+         `(T,)` forecast — for CR1, CR2 and CR3 off a mesh inside the
+         solve's own program, from a fleet kept on the device
          (`SolveContext.window`/`.fleet`), so a tick sends only its
          forecast and hour offset,
       2. warm-starts `api.solve(problem, policy, ctx=...)` from the

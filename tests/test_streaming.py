@@ -2,6 +2,7 @@
 warm-vs-cold re-solve quality, and the online control loop."""
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -10,7 +11,8 @@ from repro.core import api
 from repro.core.api import CR1, CR2, SolveContext, solve
 from repro.core.carbon import ForecastStream, caiso_2021
 from repro.core.engine import EngineConfig, EngineState, al_minimize
-from repro.core.fleet_solver import (_jit_view, synthetic_fleet,
+from repro.core.fleet_solver import (_jit_view, cr2_cap, cr2_reference_fleet,
+                                     synthetic_fleet,
                                      synthetic_regional_fleet)
 from repro.core.streaming import RollingHorizonSolver
 from repro.obs import spans
@@ -540,8 +542,10 @@ def _slide_case(kind: str):
                                  seed=3)
     streams = ForecastStream.regional(np.tile(np.asarray(p.mci), 3),
                                       horizon=T_SLIDE, seed=6)
-    policy = CR1(lam=1.45) if kind == "cr1_eight_regions" else api.CR3(
-        rho=0.05, tax_frac=0.2, outer=2, clearing_iters=2)
+    policy = {"cr1_eight_regions": CR1(lam=1.45),
+              "cr2_three_regions": CR2(cap_frac=0.78, outer=2),
+              "cr3_three_regions": api.CR3(rho=0.05, tax_frac=0.2, outer=2,
+                                           clearing_iters=2)}[kind]
     return p, streams, policy
 
 
@@ -582,12 +586,12 @@ def test_window_slid_in_the_program_is_the_host_rolled_window(kind):
                                       np.asarray(ref.state.x))
 
 
-def test_window_offsets_share_one_program_and_the_fleet_goes_up_once():
-    """Past T ticks, no warm tick compiles after the first one
-    (`guard_recompiles`), each tick is one dispatch, and the fleet goes
-    up on tick 0 only (`fleet_uploads`); later ticks send the forecast,
-    λ and the offset."""
-    p, stream, policy = _slide_case("cr1_one_region")
+def _one_program_run(policy, knob_bytes: int):
+    """A resident run past T ticks: no warm tick compiles after the first
+    one (`guard_recompiles`), each tick is one dispatch, and the fleet
+    goes up on tick 0 only (`fleet_uploads`); later ticks send the
+    forecast, the knob (`knob_bytes`) and the offset."""
+    p, stream, _ = _slide_case("cr1_one_region")
     rhs = _slide_solver(p, stream, policy, guard_recompiles=True)
     spans.clear()
     rhs.run(T_SLIDE + 3)
@@ -596,9 +600,98 @@ def test_window_offsets_share_one_program_and_the_fleet_goes_up_once():
     assert [r.counts["dispatches"] for r in sends] == [1] * (T_SLIDE + 3)
     assert [r.counts.get("fleet_uploads", 0) for r in sends] \
         == [1] + [0] * (T_SLIDE + 2)
-    # the forecast (T,) and λ as float32, the offset as int32
-    assert {r.counts["h2d_bytes"] for r in sends[1:]} == {4 * T_SLIDE + 8}
+    # the forecast (T,) as float32, the knob, the offset as int32
+    assert {r.counts["h2d_bytes"] for r in sends[1:]} \
+        == {4 * T_SLIDE + knob_bytes + 4}
     assert sends[0].counts["h2d_bytes"] > 4 * p.W * T_SLIDE
+
+
+def test_window_offsets_share_one_program_and_the_fleet_goes_up_once():
+    """CR1's ticks send λ as float32 (`_one_program_run`)."""
+    _one_program_run(CR1(lam=1.45), 4)
+
+
+def test_cr2_window_offsets_share_one_program_and_the_fleet_goes_up_once():
+    """CR2's ticks send no knob: the fleet's equal-cap curtailment at its
+    cap_frac (`cr2_cap`) goes up with the fleet, on tick 0
+    (`_one_program_run`)."""
+    _one_program_run(CR2(outer=2), 0)
+
+
+def test_resident_cr2_ticks_match_host_slid_ticks():
+    """CR2 on the resident fleet, four ticks of a three-region fleet
+    whose offsets wrap past T: every tick commits what a solve of the
+    window rolled on the host commits (its fairness references made on
+    the host), and the plans report the same numbers, to the float32
+    rounding of the references. (A CR2 tick magnifies a difference of
+    its references' last bits: over T ticks such a gap grows to the
+    commitments' size, so the run is kept short.)"""
+    p, streams, policy = _slide_case("cr2_three_regions")
+    rhs = _slide_solver(p, streams, policy)
+    assert rhs._resident is not None
+    rhs._tick = T_SLIDE - 2
+    got = []
+    rhs.run(4, on_tick=lambda tk: got.append(tk))
+    assert [tk.tick % T_SLIDE for tk in got] == [T_SLIDE - 2, T_SLIDE - 1,
+                                                 0, 1]
+    state = None
+    for tk in got:
+        p_t = rhs._window_problem(tk.tick, rhs._forecast(tk.tick))
+        ref = solve(p_t, policy, ctx=SolveContext(
+            warm=state, shift=0 if state is None else 1,
+            reset_mu=state is not None, steps=30 if state is None else 8,
+            use_kernel=False))
+        state = ref.state
+        scale = np.abs(ref.hour0).max()
+        np.testing.assert_allclose(tk.committed, ref.hour0, rtol=0,
+                                   atol=1e-5 * scale, err_msg=tk.tick)
+        for name in ("carbon_reduction_pct", "total_penalty_pct"):
+            assert abs(getattr(tk.plan, name) - getattr(ref, name)) < 1e-5, \
+                (tk.tick, name)
+        assert tk.plan.iters == ref.iters
+
+
+def test_window_references_are_the_host_references_of_every_offset():
+    """The fairness references a sliding CR2 tick makes in its program,
+    for every hour offset 0..T-1, are `cr2_reference_fleet` of the window
+    rolled on the host to float32 rounding: the tick slides the whole
+    fleet's curtailment usage - cap_frac·E, made on the host in float64
+    (`cr2_cap`). Made in float32 (the day scan's way) it is off by more
+    than that."""
+    p, _, _ = _slide_case("cr2_three_regions")
+    cap = 0.78
+
+    @jax.jit
+    def refs(pj, d_cap, window):
+        slid = api._slide(pj, window)
+        return (api._cr2_window_refs(slid, d_cap, False, window),
+                api._cr2_window_refs(slid, cap, False))
+
+    ulp = 2.0 ** -23
+    for w in range(T_SLIDE):
+        host = cr2_reference_fleet(dataclasses.replace(
+            p, usage=np.roll(p.usage, -w, axis=1),
+            jobs=np.roll(p.jobs, -w, axis=1)), cap)
+        exact, f32 = (np.asarray(a) for a in
+                      refs(_jit_view(p), cr2_cap(p, cap), np.int32(w)))
+        np.testing.assert_allclose(exact, host, rtol=4 * ulp, atol=0,
+                                   err_msg=w)
+        assert not np.allclose(f32, host, rtol=4 * ulp, atol=0), w
+
+
+@pytest.mark.parametrize("policy, rounds", [(CR1(), 1), (CR2(outer=3), 3)])
+def test_engine_ticks_count_their_al_rounds_and_steps(policy, rounds):
+    """Each CR1/CR2 tick counts on `dr.solve` the AL multiplier rounds it
+    ran (`al_rounds`) and its projected-Adam steps (`al_steps`: steps ×
+    rounds), cold and warm."""
+    p, stream, _ = _slide_case("cr1_one_region")
+    rhs = _slide_solver(p, stream, policy)
+    spans.clear()
+    rhs.run(3)
+    solves = [r for r in spans.recorded() if r.name == "dr.solve"]
+    assert [r.counts["al_rounds"] for r in solves] == [rounds] * 3
+    assert [r.counts["al_steps"] for r in solves] \
+        == [30 * rounds, 8 * rounds, 8 * rounds]
 
 
 def test_window_and_fleet_are_refused_where_no_lane_slides():
@@ -610,10 +703,20 @@ def test_window_and_fleet_are_refused_where_no_lane_slides():
         api.sweep(p, [CR1(lam=1.0), CR1(lam=2.0)],
                   ctx=SolveContext(window=3))
     stream = ForecastStream.caiso(n_ticks=2, horizon=p.T)
-    for policy in ("b1", "cr2"):
-        solver = RollingHorizonSolver(p, stream, policy=policy)
-        assert solver._resident is None
-        with pytest.raises(NotImplementedError, match="window"):
-            solve(p, policy, ctx=SolveContext(window=3))
-    assert RollingHorizonSolver(p, stream, policy="cr1")._resident \
-        is not None
+    solver = RollingHorizonSolver(p, stream, policy="b1")
+    assert solver._resident is None
+    with pytest.raises(NotImplementedError, match="window"):
+        solve(p, "b1", ctx=SolveContext(window=3))
+    # CR2 slides: the solver keeps its fleet resident, and its solo lane
+    # takes the offset, solving the hours it names
+    for policy in ("cr1", "cr2"):
+        assert RollingHorizonSolver(p, stream, policy=policy)._resident \
+            is not None
+    slid = solve(p, "cr2", ctx=SolveContext(window=3, steps=20,
+                                            use_kernel=False))
+    rolled = solve(dataclasses.replace(
+        p, usage=np.roll(p.usage, -3, axis=1),
+        jobs=np.roll(p.jobs, -3, axis=1)), "cr2",
+        ctx=SolveContext(steps=20, use_kernel=False))
+    np.testing.assert_allclose(slid.hour0, rolled.hour0, rtol=0,
+                               atol=1e-4 * np.abs(rolled.hour0).max())

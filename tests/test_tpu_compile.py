@@ -145,6 +145,33 @@ def test_cr1_solve_compiles_for_v5e(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
 
 
+def test_resident_cr2_tick_compiles_for_v5e(one_chip, monkeypatch):
+    """A warm CR2 tick on the kernel path as the `cr2_ticks` cell runs
+    it: the resident 8-region fleet slid by a traced hour offset, the
+    fairness references made from the fleet's equal-cap curtailment slid
+    with it, 6 AL rounds, hour 0 and the report sums."""
+    from repro.core.api import _TICK
+    from repro.core.engine import EngineState
+    from repro.core.fleet_solver import synthetic_regional_fleet
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    states = ["CA", "WA", "AZ", "CO", "TX", "IL", "NY", "FL"]
+    fleet, _ = _fleet_at_scale(one_chip, synthetic_regional_fleet(
+        16, states, hours=T, seed=0))
+    state = EngineState(x=_shape(one_chip, (W, T)),
+                        lam_eq=_shape(one_chip, (W,)),
+                        lam_in=_shape(one_chip, (0,)),
+                        mu=_shape(one_chip, ()))
+    d_cap = _shape(one_chip, (W, T))
+    R = len(states)
+    compiled = _TICK["cr2"].plain.lower(
+        fleet, d_cap, state, None, None, _shape(one_chip, (), jnp.int32),
+        steps=100, outer=6, use_kernel=True, shift=1, reset_mu=True,
+        offsets=tuple(range(0, W + 1, W // R))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
 def _compile_cr3_market(sharding, mesh=None):
     """A whole CR3 tick on the kernel path, compiled for `sharding`: the
     best responses and the clearing loop of 8 regional markets of W/8
